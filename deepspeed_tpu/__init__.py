@@ -17,8 +17,6 @@ training, auto-resume, hang watchdog, fault injection —
 docs/resilience.md).
 """
 
-from deepspeed_tpu import compat as _compat  # noqa: F401  (installs jax shims)
-
 __version__ = "0.1.0"
 __version_major__, __version_minor__, __version_patch__ = (
     int(x) for x in __version__.split("."))

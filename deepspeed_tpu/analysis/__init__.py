@@ -61,7 +61,8 @@ __all__ = [
     "MemoryPlanError", "ShardSpecError", "ConcurrencyLintError", "MODES",
     "concurrency", "lockwatch", "analyze_jaxpr",
     "analyze_step", "analyze_engine", "analyze_engine_train_batch",
-    "analyze_engine_train_many", "trace_train_batch", "train_batch_args",
+    "analyze_engine_train_many", "trace_train_batch", "lower_train_batch",
+    "train_batch_args",
     "train_many_args", "step_args",
     "check_shard_specs",
     "validate_specs_or_raise", "dispatch_report",
@@ -266,6 +267,13 @@ def trace_train_batch(engine, batch, fn=None):
     ``_train_batch_fn``."""
     return jax.make_jaxpr(fn or engine._train_batch_fn)(
         *train_batch_args(engine, batch))
+
+
+def lower_train_batch(engine, batch):
+    """``jax.stages.Lowered`` of the engine's built fused train_batch
+    program for its CURRENT state — ``.as_text()`` is what XLA is handed
+    (chip_smoke.py looks for the Pallas ``tpu_custom_call`` in it)."""
+    return engine._train_batch_fn.lower(*train_batch_args(engine, batch))
 
 
 def analyze_engine_train_batch(engine, batch) -> Report:

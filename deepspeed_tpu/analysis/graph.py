@@ -2,8 +2,8 @@
 
 The passes never import jax internals beyond what this module wraps:
 
-* :func:`subjaxprs` — version-tolerant discovery of nested jaxprs inside an
-  equation (``scan``/``cond``/``pjit``/``shard_map``/``remat``/custom-vjp all
+* :func:`subjaxprs` — discovery of nested jaxprs inside an
+  equation (``scan``/``cond``/``jit``/``shard_map``/``remat``/custom-vjp all
   carry them under different param names; we scan every param value for
   jaxpr-shaped objects instead of hard-coding the names).
 * :func:`walk` — flat recursive iteration over every equation with its
@@ -19,24 +19,12 @@ from __future__ import annotations
 
 from typing import Iterator, List, Optional, Sequence, Tuple
 
-import jax
-
-try:  # the stable-ish internal home across 0.4.x
-    from jax._src import source_info_util as _srcinfo
-except Exception:  # pragma: no cover - future jax moved it
-    _srcinfo = None
-
-try:
-    from jax._src import core as _core
-except Exception:  # pragma: no cover
-    _core = jax.core
-
-Var = getattr(_core, "Var", None)
-Literal = getattr(_core, "Literal", None)
+from jax._src import source_info_util as _srcinfo
+from jax._src.core import Var
 
 
 def is_var(x) -> bool:
-    return Var is not None and isinstance(x, Var)
+    return isinstance(x, Var)
 
 
 def _as_open_jaxpr(obj):
@@ -92,12 +80,7 @@ def walk(jaxpr, path: str = "") -> Iterator[Tuple[object, str]]:
 def source_of(eqn) -> str:
     """Best-effort "file:line (function)" for an equation."""
     si = getattr(eqn, "source_info", None)
-    if si is None or _srcinfo is None:
-        return ""
-    try:
-        return _srcinfo.summarize(si)
-    except Exception:  # pragma: no cover - defensive across jax versions
-        return ""
+    return "" if si is None else _srcinfo.summarize(si)
 
 
 def aval_of(atom):

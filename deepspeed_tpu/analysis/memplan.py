@@ -18,7 +18,7 @@ The walk (:func:`peak_of`) is a liveness simulation over one jaxpr level:
   stacked ``ys`` — the *scan residuals*, including everything remat
   decides to save — allocate up front for the whole trip count, so remat
   on/off changes the prediction exactly the way it changes the program;
-* call-like primitives (``pjit``/``remat2``/``cond``/custom-vjp) peak at
+* call-like primitives (``jit``/``remat2``/``cond``/custom-vjp) peak at
   ``max(outer live + inner peak, outer live + own outputs)`` — inner
   scratch and the call's results never coexist;
 * jaxpr outputs matching a *donated* input's shape/dtype are free (XLA
@@ -71,7 +71,7 @@ ELEMENTWISE_PRIMS = frozenset({
 
 #: sub-jaxpr carriers whose scratch and outputs never coexist
 CALL_PRIMS = frozenset({
-    "pjit", "remat2", "remat", "custom_vjp_call_jaxpr", "custom_jvp_call",
+    "jit", "remat2", "remat", "custom_vjp_call_jaxpr", "custom_jvp_call",
     "custom_vjp_call", "closed_call", "core_call", "xla_call", "cond",
     "switch", "while",
 })

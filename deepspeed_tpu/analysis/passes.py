@@ -465,9 +465,9 @@ def check_transfers(jaxpr, report: R.Report) -> None:
                 f"production runs",
                 path=path, source=G.source_of(eqn), pass_name="transfers")
 
-        # donation: a pjit level records donated_invars; large inputs whose
+        # donation: a jit level records donated_invars; large inputs whose
         # aval matches an output and are not donated double-buffer in HBM
-        if name == "pjit" and "donated_invars" in eqn.params:
+        if name == "jit" and "donated_invars" in eqn.params:
             donated = eqn.params["donated_invars"]
             sub = G._as_open_jaxpr(eqn.params.get("jaxpr"))
             if sub is None:

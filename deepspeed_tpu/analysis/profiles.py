@@ -18,12 +18,17 @@ side so the next chip session can fit a goodput factor).
 Naming: ``<generation>-<devices>`` (``v4-8`` = a v4 slice of 8 devices),
 matching the TPU pod-slice convention.  ``resolve`` accepts the bare
 generation (``v4``) and defaults the device count to the current mesh.
+
+This is also the ONE place a ``device_kind`` string is interpreted
+(:data:`DEVICE_KIND_PROFILES`): the planner, ``bench.py``'s MFU
+denominator and ``models/layers.py``'s attention thresholds all come here,
+and a TPU kind with no row is an error, never a default.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,12 +47,15 @@ class BackendProfile:
     #: nominal DCN bandwidth per host, GiB/s — the rate the ``data`` axis
     #: drops to when a mesh spans hosts over data-center network.
     dcn_gibps: float
-    #: peak dense bf16 TFLOP/s per device — declared-capability seed for
-    #: the future backend probe (ROADMAP 3).  Nothing reads it yet:
-    #: bench.py keeps its own device-kind-keyed ``_PEAK_BF16_TFLOPS``
-    #: table for MFU (it covers generations, e.g. v6e, that have no
-    #: planner profile); keep the two in sync when adding a generation.
+    #: published peak dense bf16 TFLOP/s per device — ``bench.py``'s MFU
+    #: denominator
     peak_bf16_tflops: float
+    #: streaming-attention auto-dispatch thresholds swept on this
+    #: generation, ``(fwd_min, bwd_min)`` tokens per mask kind
+    #: (``models/layers.py stream_auto_min``); None = never swept here,
+    #: the conservative defaults in ``layers`` apply
+    stream_attn_min_causal: Optional[Tuple[int, int]] = None
+    stream_attn_min_noncausal: Optional[Tuple[int, int]] = None
     #: XLA-CPU lowering quirk: sub-fp32 (fp16/bf16) dot operands are
     #: materialized as fp32 copies because the host has no native
     #: half-precision GEMM.  The memory model must count those copies on
@@ -97,7 +105,12 @@ PROFILES: Dict[str, BackendProfile] = {
         peak_bf16_tflops=275.0),
     "v5e-8": BackendProfile(
         name="v5e-8", hbm_gib=14.75, ici_gibps=45.0, dcn_gibps=6.25,
-        peak_bf16_tflops=197.0),
+        peak_bf16_tflops=197.0,
+        # non-causal: XLA wins at 128, the kernel at 512 (the removed
+        # BENCH_r04/r05 sweeps; not re-measured on the current code).
+        # fwd == bwd until a direction-split sweep lands
+        stream_attn_min_causal=(512, 512),
+        stream_attn_min_noncausal=(512, 512)),
     "v5p-8": BackendProfile(
         name="v5p-8", hbm_gib=93.75, ici_gibps=150.0, dcn_gibps=6.25,
         peak_bf16_tflops=459.0),
@@ -135,23 +148,40 @@ def resolve(name: str) -> BackendProfile:
         f"unknown backend profile {name!r}; known: {sorted(PROFILES)}")
 
 
+#: ``jax.devices()[0].device_kind`` → profile name.  A v5e reports
+#: ``"TPU v5 lite"`` (jax 0.9.0 / libtpu 0.0.34, chip_smoke.py prints it)
+DEVICE_KIND_PROFILES: Dict[str, str] = {
+    "TPU v4": "v4-8",
+    "TPU v5 lite": "v5e-8",
+    "TPU v5e": "v5e-8",
+    "TPU v5p": "v5p-8",
+}
+
+
+def for_device_kind(kind: str) -> BackendProfile:
+    """Profile of the chip that reports ``kind``; an unknown kind raises
+    rather than borrow another chip's numbers."""
+    try:
+        return PROFILES[DEVICE_KIND_PROFILES[kind]]
+    except KeyError:
+        raise KeyError(
+            f"no backend profile for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_PROFILES)} — add a row to "
+            f"DEVICE_KIND_PROFILES / PROFILES in "
+            f"deepspeed_tpu/analysis/profiles.py") from None
+
+
 def default_profile() -> Optional[BackendProfile]:
-    """Profile of the backend jax is actually running on (None when the
-    platform has no entry — the caller should then require an explicit
-    ``--profile``).  On CPU this turns on the fp32-dot-copy quirk that
-    makes predicted peaks comparable to ``compiled.memory_analysis()``."""
+    """Profile of the backend jax is actually running on.  On CPU this
+    turns on the fp32-dot-copy quirk that makes predicted peaks comparable
+    to ``compiled.memory_analysis()``; on TPU it is the attached chip's
+    row (an unknown TPU kind raises); None on any other platform — the
+    caller should then require an explicit ``--profile``."""
     import jax
 
     platform = jax.default_backend()
     if platform == "cpu":
         return PROFILES["cpu-8"]
     if platform == "tpu":
-        kind = ""
-        try:
-            kind = jax.devices()[0].device_kind.lower()
-        except Exception:  # pragma: no cover - device probing is best-effort
-            pass
-        for gen in ("v5p", "v5e", "v4"):
-            if gen in kind.replace(" ", ""):
-                return PROFILES[f"{gen}-8"]
+        return for_device_kind(jax.devices()[0].device_kind)
     return None

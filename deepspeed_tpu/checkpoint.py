@@ -1056,8 +1056,7 @@ def find_latest_valid_tag(load_dir: str, exclude=()) -> Optional[str]:
 # PR 4 made auto-resume the normal operating mode, which put RESTORE on the
 # critical path of every restart — and the serial read path (leaf-at-a-time
 # np.concatenate over memmap views, then per-leaf device placement) was the
-# slow side: CKPT_BENCH.md measured 621 s restore vs 45 s async-save stall
-# at 1.5B.  The pipeline below mirrors the async writer in the other
+# slow side.  The pipeline below mirrors the async writer in the other
 # direction: a reader pool streams chunk records from the container (ZeRO-3
 # shard records read concurrently per shard file), each leaf is assembled
 # as its chunks land, and device placement (`_put_global`) of leaf i

@@ -2124,7 +2124,13 @@ class DeepSpeedTpuEngine:
                     flat_full = comm.allgather_params(
                         new_master.astype(jnp.float32), DATA_AXIS,
                         world_size=world, partition_group_size=pps)
-                params = zero_mod.unflatten_tree(flat_full, meta, dtype=cdt)
+                # fence the gathered buffer: left free to rewrite the
+                # all-gather together with the per-leaf slices that consume
+                # it, the TPU compiler (libtpu 0.0.34) took 930 s over
+                # BERT-large's boundary; fenced, seconds.  The gather's
+                # output is a real buffer either way.
+                params = zero_mod.unflatten_tree(
+                    jax.lax.optimization_barrier(flat_full), meta, dtype=cdt)
                 if zero_2d:
                     new_master = new_master[None]
                     new_opt = optim_mod.OptimizerState(

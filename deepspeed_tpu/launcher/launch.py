@@ -5,8 +5,10 @@ Analog of /root/reference/deepspeed/pt/deepspeed_launch.py:56-119, with the
 process model changed for TPU: the reference spawns one subprocess per local
 GPU with ``--local_rank=i`` and CUDA_VISIBLE_DEVICES; a TPU host runs ONE
 process that drives all local chips, so the global rank mapping is
-slot-granular only for CPU/virtual fleets.  Env contract exported to the
-child (consumed by ``parallel.topology.init_distributed``):
+slot-granular only for CPU/virtual fleets: several slots on this node
+are refused unless ``JAX_PLATFORMS=cpu`` (a chip belongs to one process;
+the second would fail or hang).  Env contract exported to the child
+(consumed by ``parallel.topology.init_distributed``):
 
     DSTPU_COORDINATOR     = master_addr:master_port   (≈ MASTER_ADDR/PORT)
     DSTPU_NUM_PROCESSES   = total process count       (≈ WORLD_SIZE)
@@ -156,6 +158,16 @@ def main(args=None):
     mapping = global_rank_mapping(world_info)
     local_ranks = mapping[node_host]
     world_size = sum(len(v) for v in mapping.values())
+    if len(local_ranks) > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # decided from the environment, not by asking jax: this parent
+        # must not open the devices its workers need
+        logger.error(
+            "node %s: %d slots are %d processes on one host, but a TPU "
+            "chip belongs to one process (one process drives every local "
+            "chip).  Use 1 slot per host, or JAX_PLATFORMS=cpu for a "
+            "virtual-device fleet", node_host, len(local_ranks),
+            len(local_ranks))
+        return 2
 
     attempt = 0
     while True:

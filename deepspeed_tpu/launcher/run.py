@@ -5,8 +5,12 @@ TPU-native analog of the reference CLI
 
 * hostfile in MPI syntax ``worker-0 slots=4`` (reference fetch_hostfile
   :88-113) — on TPU **1 slot = 1 host process** (process-per-host, not
-  per-chip; each process drives all local chips through jax.distributed)
-  but multi-slot hosts are honored for CPU/virtual-device fleets.
+  per-chip; each process drives all local chips through jax.distributed).
+  Several slots on one host are several processes, which only a
+  CPU/virtual-device fleet (``JAX_PLATFORMS=cpu``) can run: a chip belongs
+  to one process, so ``launcher.launch`` refuses them anywhere else.
+* this module and ``launcher.launch`` never initialise a jax backend: a
+  parent that held the chips would starve the workers it spawns.
 * include/exclude filter DSL ``-i "worker-0@worker-2:0,2"`` (reference
   parse_inclusion_exclusion :116-205): ``@`` separates nodes, ``:`` splits
   host from a comma-separated slot list, no list = all slots.
@@ -52,9 +56,10 @@ def parse_args(args=None):
                         help="Exclude filter, same DSL as --include")
     parser.add_argument("--num_nodes", type=int, default=-1,
                         help="Limit to first N nodes of the resource pool")
-    parser.add_argument("--num_gpus", "--num_chips", type=int, default=-1,
-                        dest="num_gpus",
-                        help="Limit slots per node (parity alias: num_chips)")
+    parser.add_argument("--num_gpus", type=int, default=-1,
+                        help="Limit slots (= processes) per node; more "
+                             "than one needs JAX_PLATFORMS=cpu — one "
+                             "process drives every chip of a TPU host")
     parser.add_argument("--master_port", type=int, default=29500,
                         help="Coordinator port for jax.distributed")
     parser.add_argument("--master_addr", type=str, default="",
@@ -239,7 +244,7 @@ def main(args=None):
 
     if resource_pool is None:
         # local-only fallback (reference :233-240): one process by default,
-        # --num_gpus/--num_chips N requests N local slots
+        # --num_gpus N requests N local slots
         n_slots = args.num_gpus if args.num_gpus > 0 else 1
         active = OrderedDict({"localhost": list(range(n_slots))})
         if args.include or args.exclude:

@@ -62,23 +62,14 @@ from deepspeed_tpu.parallel.topology import MODEL_AXIS, SEQ_AXIS
 #   4. DSTPU_STREAM_ATTN_MIN (applies everywhere; a causal-measured value
 #      here would force the kernel on non-causal shapes where XLA wins —
 #      prefer the causal-scoped pin)
-#   5. the per-device-kind table below
-#   6. the v5e-measured defaults
+#   5. the attached chip's row in analysis/profiles.py
+#      (BackendProfile.stream_attn_min_*; extend as sweeps run on new
+#      generations: BENCH_ATTN_SWEEP=1 BENCH_SEQ=<n> python bench.py)
+#   6. the defaults below, for a chip whose row carries no sweep
 # `ops.pallas_attention.calibrate_stream_threshold()` measures the
 # crossover on the attached chip and prints the env pin to persist.
 STREAM_AUTO_MIN = 1024            # non-causal default (conservative)
 STREAM_AUTO_MIN_CAUSAL = 512      # causal default (v5e end-to-end sweep)
-#: measured per device kind: {"causal": (fwd_min, bwd_min), "noncausal":
-#: (fwd_min, bwd_min)}; extend as sweeps run on new generations
-#: (BENCH_ATTN_SWEEP=1 BENCH_SEQ=<n> python bench.py)
-#: v5e non-causal: XLA wins at 128 (0.92x r4 sweep) but the kernel wins
-#: 1.17x at 512 (BERT-large seq512 84.8 vs 72.3 samples/s/chip, r5) —
-#: threshold 512 is measured at both ends.  fwd == bwd until a
-#: direction-split sweep lands; the mechanism is in place for it.
-STREAM_AUTO_MIN_BY_KIND = {
-    "TPU v5 lite": {"causal": (512, 512), "noncausal": (512, 512)},
-    "TPU v5e": {"causal": (512, 512), "noncausal": (512, 512)},
-}
 
 #: whole-tile kernel auto-dispatch BELOW the streaming threshold, causal
 #: only: the committed causal seq-128 sweep row (bench_attn_sweep.json,
@@ -126,15 +117,13 @@ def stream_auto_min(causal: bool = False, direction: str = "fwd") -> int:
                 f"{name}=0 is not a valid token count (use "
                 f"DSTPU_FUSED_ATTN=0 to disable kernels)")
         return v
-    default = STREAM_AUTO_MIN_CAUSAL if causal else STREAM_AUTO_MIN
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return default
-    entry = STREAM_AUTO_MIN_BY_KIND.get(kind)
-    if entry is None:
-        return default
-    pair = entry["causal" if causal else "noncausal"]
+    from deepspeed_tpu.analysis import profiles
+    prof = profiles.default_profile()     # an unknown TPU kind raises
+    pair = None if prof is None else (
+        prof.stream_attn_min_causal if causal
+        else prof.stream_attn_min_noncausal)
+    if pair is None:
+        return STREAM_AUTO_MIN_CAUSAL if causal else STREAM_AUTO_MIN
     return pair[0] if direction == "fwd" else pair[1]
 
 

@@ -231,18 +231,23 @@ fused_attention.defvjp(_fused_fwd, _fused_bwd)
 # dK and dV together — the score recompute (QK^T, exp, dP) runs once per
 # tile pair instead of once in a dK/dV kernel and again in a dQ kernel,
 # and q/k/v/do tiles are DMA'd once instead of twice.  dQ accumulates in a
-# full-sequence fp32 VMEM scratch (gb·T·d·4 bytes; gated by
-# ``_fused_bwd_fits`` — oversized shapes fall back to the classic two-pass
-# split, also selectable via DSTPU_STREAM_BWD=fused|split|auto).
+# full-sequence fp32 VMEM scratch; ``_fused_bwd_fits`` decides from the
+# shape whether that fits Mosaic's scoped VMEM — shapes that do not take
+# the classic two-pass split (DSTPU_STREAM_BWD=fused|split pins either).
 # delta = rowsum(dO ∘ O) is precomputed on the XLA side either way.
 # Layout: [G, T, d] with G = batch * heads folded on the XLA side.
 
 STREAM_TILE = 512      # preferred tile rows per program
 STREAM_TILE_MIN = 256  # fallback when T is not a multiple of 512
-#: fp32 VMEM budget for the fused backward's full-sequence dQ accumulator;
-#: several score tiles + the dK/dV scratch are live next to it, so keep a
-#: healthy margin under the ~16 MB VMEM
-STREAM_DQ_SCRATCH_BUDGET = 4 * 1024 * 1024
+#: Mosaic's scoped-VMEM limit per kernel on v5e (libtpu 0.0.34 names it in
+#: its RESOURCE_EXHAUSTED message)
+VMEM_SCOPED_LIMIT = 16 * 1024 * 1024
+#: VMEM the fused backward needs BESIDE its dQ-resident buffers (the
+#: double-buffered q/k/v/do/dk/dv tile blocks, the dK/dV scratch, matmul
+#: temporaries), by input itemsize.  Upper bounds on what Mosaic reported
+#: when compiling for v5e at tile 512, gb 2: 4.0 MiB for bf16 at d=64 and
+#: d=128, 7.0 MiB for fp32 at d=64, 11.7 MiB for fp32 at d=128.
+_FUSED_BWD_WORKING_SET = {2: 8 * 1024 * 1024, 4: 12 * 1024 * 1024}
 
 
 def _stream_tile(T: int) -> int:
@@ -414,8 +419,9 @@ def _stream_bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dq_blk = jax.lax.dot_general(
             dsc, k, (((2,), (1,)), bdims),
             preferred_element_type=jnp.float32)
-        idx = (slice(None), pl.ds(i * qt, qt), slice(None))
-        pl.store(dq_scr, idx, pl.load(dq_scr, idx) + dq_blk)
+        # Mosaic wants the dynamic sublane offset proven tile-aligned
+        rows = pl.ds(pl.multiple_of(i * qt, qt), qt)
+        dq_scr[:, rows, :] += dq_blk
 
     if causal:
         pl.when(j * kt <= (i + 1) * qt - 1)(update)
@@ -539,14 +545,21 @@ def _stream_bwd_mode() -> str:
     return mode
 
 
-def _fused_bwd_fits(gb: int, T: int, d: int) -> bool:
-    return gb * T * d * 4 <= STREAM_DQ_SCRATCH_BUDGET
+def _fused_bwd_fits(gb: int, T: int, d: int, itemsize: int) -> bool:
+    """Whether the fused backward's VMEM need stays under Mosaic's scoped
+    limit.  Resident for the whole grid are the fp32 dQ accumulator and
+    the (gb, T, d) dQ out block, which Pallas double-buffers; VMEM tiles
+    pad the lane (last) dim to 128, so d=64 costs what d=128 does."""
+    lanes = -(-d // 128) * 128
+    resident = gb * T * lanes * (4 + 2 * itemsize)
+    return (resident + _FUSED_BWD_WORKING_SET[itemsize]
+            <= VMEM_SCOPED_LIMIT)
 
 
 def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret):
     """Streaming backward on folded [G, T, d] operands → (dq, dk, dv),
-    same layout.  Fused single pass by default; the two-kernel split
-    remains as the escape hatch / large-shape fallback."""
+    same layout.  Fused single pass where ``_fused_bwd_fits`` says its
+    dQ-resident buffers fit VMEM, the two-kernel split otherwise."""
     G, T, d = qg.shape
     gb = _stream_gb(G)
     qt = kt = _stream_tile(T)
@@ -560,7 +573,8 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret):
     q_spec_o = pl.BlockSpec((gb, qt, d), lambda g_, j, i: (g_, i, 0))
     row_spec_o = pl.BlockSpec((gb, 1, qt), lambda g_, j, i: (g_, 0, i))
     mode = _stream_bwd_mode()
-    if mode == "fused" or (mode == "auto" and _fused_bwd_fits(gb, T, d)):
+    if mode == "fused" or (mode == "auto" and _fused_bwd_fits(
+            gb, T, d, qg.dtype.itemsize)):
         dq_spec = pl.BlockSpec((gb, T, d), lambda g_, j, i: (g_, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_stream_bwd_fused_kernel, causal=causal,
@@ -780,8 +794,8 @@ def calibrate_stream_threshold(seq_lens=(256, 512, 1024, 2048),
     and return the smallest winning sequence length.
 
     The shipped auto-dispatch threshold encodes the v5e sweep
-    (models/layers.py STREAM_AUTO_MIN); other chip generations shift the
-    crossover.  This times fwd+bwd of both paths at each length and
+    (analysis/profiles.py ``stream_attn_min_*``); other chip generations
+    shift the crossover.  This times fwd+bwd of both paths at each length and
     returns the first where the kernel is >= 5% faster (falling back to
     the table default when none wins).  Persist the result with::
 
@@ -846,12 +860,12 @@ def calibrate_stream_threshold(seq_lens=(256, 512, 1024, 2048),
             threshold = T
     if threshold is None:
         # deliberately IGNORE any existing env pin here: this measurement
-        # just showed the kernel losing, so fall back to the table/default
-        # (the calibration loss is causal, so read the causal column)
-        kind = jax.devices()[0].device_kind
-        entry = _L.STREAM_AUTO_MIN_BY_KIND.get(kind)
-        threshold = (min(entry["causal"]) if entry
-                     else _L.STREAM_AUTO_MIN_CAUSAL)
+        # just showed the kernel losing, so fall back to the chip's
+        # profile row / default (the calibration loss is causal, so read
+        # the causal column)
+        from deepspeed_tpu.analysis import profiles
+        pair = profiles.default_profile().stream_attn_min_causal
+        threshold = min(pair) if pair else _L.STREAM_AUTO_MIN_CAUSAL
         if verbose:
             print(f"kernel never won >=1.05x; keeping {threshold}")
     elif verbose:

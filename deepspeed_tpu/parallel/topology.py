@@ -192,21 +192,16 @@ def init_distributed(coordinator_address: Optional[str] = None,
         # wins the auto-selection.  (Backend auto-detection cannot be
         # queried here: touching it would initialize XLA before the
         # rendezvous below.)
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            # gloo multiplexes every collective of a pair over one TCP
-            # connection; concurrent in-flight collectives from the CPU
-            # backend's async dispatch interleave frames on it and die
-            # with "op.preamble.length <= op.nbytes".  Serialize dispatch
-            # on multi-process CPU — a correctness switch for CI rigs,
-            # where CPU throughput is irrelevant.
-            jax.config.update("jax_cpu_enable_async_dispatch", False)
-            logger.info("init_distributed: gloo CPU collectives enabled "
-                        "(async dispatch off)")
-        except Exception as e:  # option renamed/absent on this jax
-            logger.warning(
-                "init_distributed: could not select gloo CPU collectives "
-                "(%s) — multi-process CPU collectives may be unavailable", e)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        # gloo multiplexes every collective of a pair over one TCP
+        # connection; concurrent in-flight collectives from the CPU
+        # backend's async dispatch interleave frames on it and die
+        # with "op.preamble.length <= op.nbytes".  Serialize dispatch
+        # on multi-process CPU — a correctness switch for CI rigs,
+        # where CPU throughput is irrelevant.
+        jax.config.update("jax_cpu_enable_async_dispatch", False)
+        logger.info("init_distributed: gloo CPU collectives enabled "
+                    "(async dispatch off)")
 
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

@@ -7,8 +7,13 @@ enables jax's persistent compilation cache (``jax_compilation_cache_dir``)
 at build time — before any step function traces — so a restarted process
 deserializes the prior attempt's executables instead of re-running XLA.
 
-Wiring (docs/resilience.md "Time to resume"):
+Wiring (docs/resilience.md "Time to resume"), first match wins:
 
+* env ``JAX_COMPILATION_CACHE_DIR`` — jax's own variable.  Where the
+  machine sets it, that directory IS the cache: jax reads it at import,
+  :func:`enable` sets no other directory in code, and the two sources
+  below yield to it with one log line.  The hit/miss listener and the
+  size/time floors still install;
 * config ``compile_cache: {dir, min_entry_size_bytes}`` (or the
   bare-string shorthand ``"compile_cache": "/path"``) — the engine calls
   :func:`enable_from_config` in ``__init__``;
@@ -16,7 +21,7 @@ Wiring (docs/resilience.md "Time to resume"):
   no ``dir`` (and how the launcher hands the directory to relaunched
   workers: :func:`enable` exports it, ``launcher.launch`` re-exports it
   into every spawned/restarted process, and the ``dst`` fan-out allowlist
-  already forwards ``DSTPU_*`` to remote hosts);
+  already forwards ``DSTPU_*`` and ``JAX_*`` to remote hosts);
 * observability — cache hits/misses count into
   ``resilience.COUNTERS.compile_cache_hits`` / ``compile_cache_misses``
   via ``jax.monitoring``, exported as ``Train/Resilience/*`` scalars, so
@@ -39,6 +44,17 @@ logger = logging.getLogger(__name__)
 #: env spelling of the cache directory — exported by :func:`enable` so
 #: launcher-relaunched workers (``--max_restarts``) land in the same cache
 ENV_DIR = "DSTPU_COMPILE_CACHE_DIR"
+#: jax's own spelling; when set it outranks every other source
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: where ``chip_smoke.py`` and ``bench.py`` keep the cache when the machine
+#: names none: one fixed, git-ignored directory in the checkout (the path
+#: is part of jax's cache key, so a temp name, pid or timestamp never hits)
+CHECKOUT_DIR_NAME = ".jax_cache"
+
+
+def checkout_dir(root: str) -> str:
+    """The fixed in-checkout cache directory under ``root``."""
+    return os.path.join(os.path.abspath(root), CHECKOUT_DIR_NAME)
 
 _listener_installed = False
 _enabled_dir: Optional[str] = None
@@ -52,12 +68,8 @@ def _reset_jax_cache() -> None:
     is configured), so any compile that ran before :func:`enable` — or
     after :func:`disable` — would freeze the old state forever without
     this reset."""
-    try:
-        from jax._src.compilation_cache import reset_cache
-    except ImportError:     # pragma: no cover - future jax relocations
-        from jax.experimental.compilation_cache.compilation_cache import (
-            reset_cache)
-    reset_cache()
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def _install_hit_listener() -> None:
@@ -92,17 +104,27 @@ def enable(cache_dir: str, min_entry_size_bytes: int = 0) -> str:
     Must run before the programs it should serve compile (the engine calls
     it during ``__init__``; every step function traces lazily after).
     Exports :data:`ENV_DIR` so child/relaunched processes inherit the same
-    directory.  Returns the enabled directory."""
+    directory.  Where :data:`JAX_ENV_DIR` is set, that directory is
+    enabled instead and ``jax_compilation_cache_dir`` is left as jax read
+    it.  Returns the enabled directory."""
     global _enabled_dir
     import jax
 
     cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+    jax_dir = os.environ.get(JAX_ENV_DIR)
+    if jax_dir:
+        jax_dir = os.path.abspath(os.path.expanduser(jax_dir))
+        if cache_dir != jax_dir:
+            logger.info("compile_cache: %s=%s outranks %s", JAX_ENV_DIR,
+                        jax_dir, cache_dir)
+        cache_dir = jax_dir
     os.makedirs(cache_dir, exist_ok=True)
     if _enabled_dir is not None and _enabled_dir != cache_dir:
         logger.warning(
             "compile_cache: re-pointing the persistent compilation cache "
             "from %s to %s (process-wide setting)", _enabled_dir, cache_dir)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not jax_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       int(min_entry_size_bytes))
     # jax's default only caches programs that took >= 1 s to compile; the
@@ -123,11 +145,14 @@ def enable(cache_dir: str, min_entry_size_bytes: int = 0) -> str:
 
 def disable() -> None:
     """Turn the persistent cache off again (tests; the hit listener stays
-    registered but sees no further cache events)."""
+    registered but sees no further cache events).  A directory the
+    machine set through :data:`JAX_ENV_DIR` is not this module's to
+    unset."""
     global _enabled_dir
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", None)
+    if not os.environ.get(JAX_ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", None)
     _reset_jax_cache()
     os.environ.pop(ENV_DIR, None)
     _enabled_dir = None
@@ -138,14 +163,15 @@ def enabled_dir() -> Optional[str]:
 
 
 def resolve_dir(config) -> Optional[str]:
-    """The directory an engine build should enable: the config's
+    """The directory an engine build asks :func:`enable` for: the config's
     ``compile_cache.dir`` if set, else the :data:`ENV_DIR` environment
     fallback (how a relaunched worker whose config was an in-process dict
-    still lands in the same cache)."""
-    cfg_dir = getattr(config, "compile_cache_dir", None)
-    if cfg_dir:
-        return cfg_dir
-    return os.environ.get(ENV_DIR) or None
+    still lands in the same cache), else :data:`JAX_ENV_DIR` — so the
+    listener and floors install on a machine that names only that.
+    :func:`enable` applies :data:`JAX_ENV_DIR`'s precedence."""
+    return (getattr(config, "compile_cache_dir", None)
+            or os.environ.get(ENV_DIR) or os.environ.get(JAX_ENV_DIR)
+            or None)
 
 
 def enable_from_config(config) -> Optional[str]:

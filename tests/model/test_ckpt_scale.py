@@ -12,10 +12,9 @@ ZeRO-3 on the virtual 8-device mesh: persistent state is ~21 GB host-side
 * the parallel streaming restore (reader pool + readahead window,
   PR 5) beats the serial fallback on the same files — both restores
   are timed here and the speedup asserted, since restore sits on the
-  preemption-resume critical path (CKPT_BENCH.md "fast resume" rows).
+  preemption-resume critical path.
 
 Heavy (tens of GB of disk traffic): gated behind DSTPU_CKPT_SCALE=1.
-Measured numbers from this rig are committed in CKPT_BENCH.md.
 """
 
 import gc
@@ -75,7 +74,7 @@ def test_1_5b_zero3_save_restore_timing(tmp_path):
     # a chip the shared device→host transfer dominates both and async
     # wins, but on a CPU backend with storage faster than single-thread
     # memcpy (this rig: ~650 MB/s write vs ~285 MB/s copy) the copy can
-    # exceed the write.  All three are printed for CKPT_BENCH.md.
+    # exceed the write.  All three are printed below.
     assert async_stall < sync_total + drain, (async_stall, drain,
                                               sync_total)
 
@@ -124,7 +123,7 @@ def test_1_5b_zero3_save_restore_timing(tmp_path):
     # pool's threads only add contention (bench_resume_335m.json measured
     # a 1.23x inversion at 4 GB) — the pool's win case is cold/IO-bound
     # reads and multi-core hosts.  Both restores are the SAME plan and
-    # bitwise identical; the committed numbers live in CKPT_BENCH.md.
+    # bitwise identical.
     assert restore_parallel < restore_serial * 1.25, (restore_parallel,
                                                       restore_serial)
     print(f"1.5B zero3 ckpt ({state_gb:.1f} GB state): async stall "

@@ -10,7 +10,10 @@ are gone (= a relaunch) must get its step programs back from the
 persistent cache instead of recompiling (resilience counters prove it).
 """
 
+import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +31,7 @@ from deepspeed_tpu.zero import LazyParts
 from simple_model import SimpleModel, random_dataset
 
 HIDDEN = 16
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def base_config(restore_threads, readahead_mb=256.0, **over):
@@ -303,6 +307,59 @@ def test_compile_cache_engine_wiring(tmp_path):
             _cfg({})) == d
     finally:
         compile_cache.disable()
+
+
+def test_jax_compilation_cache_dir_outranks_config(tmp_path):
+    """Where the machine sets JAX_COMPILATION_CACHE_DIR, that directory is
+    the cache and the code sets no other: an engine whose config names
+    another directory still compiles into jax's.  A fresh process, because
+    jax reads the variable when it is imported."""
+    jax_dir, cfg_dir = tmp_path / "from_env", tmp_path / "from_config"
+    script = tmp_path / "build.py"
+    script.write_text(
+        "import json, os, sys\n"
+        f"sys.path[:0] = [{REPO!r}, {os.path.join(REPO, 'tests')!r}]\n"
+        "import jax, numpy as np\n"
+        "import deepspeed_tpu\n"
+        "from simple_model import SimpleModel\n"
+        "model = SimpleModel(8)\n"
+        "engine, _, _, _ = deepspeed_tpu.initialize(\n"
+        "    model=model, model_parameters=model.init_params(\n"
+        "        jax.random.PRNGKey(0)),\n"
+        "    config={'train_batch_size': 8, 'optimizer': {'type': 'Adam',\n"
+        "            'params': {'lr': 1e-3}},\n"
+        f"            'compile_cache': {str(cfg_dir)!r}}})\n"
+        "x = np.ones((8, 8), np.float32); y = np.zeros((8,), np.int32)\n"
+        "float(engine.train_batch((x, y)))\n"
+        "print(json.dumps({'jax': jax.config.jax_compilation_cache_dir,\n"
+        "                  'engine': engine.compile_cache_dir,\n"
+        "                  **engine.resilience_counters()}))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(jax_dir))
+    env.pop(compile_cache.ENV_DIR, None)
+    proc = subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["jax"] == out["engine"] == str(jax_dir)
+    assert out["compile_cache_misses"] > 0       # the listener installed
+    assert any(n.endswith("-cache") for n in os.listdir(jax_dir))
+    assert not cfg_dir.exists()
+
+
+def test_checkout_cache_dir_is_fixed_and_ignored():
+    """With no JAX_COMPILATION_CACHE_DIR, chip_smoke.py and bench.py keep
+    the cache at one fixed git-ignored path in the checkout (the test rig
+    itself runs with the variable removed — tests/conftest.py)."""
+    assert compile_cache.JAX_ENV_DIR not in os.environ
+    assert compile_cache.checkout_dir(REPO) == os.path.join(REPO,
+                                                            ".jax_cache")
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    for name in ("chip_smoke.py", "bench.py"):
+        with open(os.path.join(REPO, name)) as f:
+            src = f.read()
+        assert "compile_cache.checkout_dir(" in src, name
 
 
 def test_launcher_propagates_compile_cache_dir(tmp_path):

@@ -638,3 +638,18 @@ def test_same_bucket_shapes_clean():
     rep = analysis.analyze_jaxpr(jx, mesh_axes=["data"])
     assert not [f for f in rep.errors
                 if f.code == "collective.divergent-order"], rep.format()
+
+
+def test_donation_lint_sees_jit_equations():
+    """jax 0.9 names the jit primitive ``jit`` (it was ``pjit``): the
+    donation lint must find the jit level, flag a large undonated input
+    whose shape matches an output, and stay quiet once it is donated."""
+    x = jnp.ones((1024, 1024), jnp.float32)          # 4 MiB
+
+    def findings(fn):
+        rep = analysis.analyze_step(lambda a: fn(a), (x,))
+        return [f for f in rep.infos if f.code == "transfer.donation"]
+
+    assert "jit" in str(jax.make_jaxpr(jax.jit(lambda a: a + 1.0))(x))
+    assert len(findings(jax.jit(lambda a: a + 1.0))) == 1
+    assert findings(jax.jit(lambda a: a + 1.0, donate_argnums=0)) == []

@@ -477,6 +477,58 @@ def test_default_profile_on_cpu_has_dot_copy_quirk():
     assert prof is not None and prof.lowp_dot_f32_copies
 
 
+def test_device_kind_resolves_through_one_table(monkeypatch):
+    """The string a chip reports picks its profile — a v5e says "TPU v5
+    lite" — and a TPU kind with no row raises instead of borrowing another
+    chip's HBM, peak or thresholds."""
+    assert profiles.for_device_kind("TPU v5 lite").name == "v5e-8"
+    assert profiles.for_device_kind("TPU v5e").name == "v5e-8"
+    assert profiles.for_device_kind("TPU v4").name == "v4-8"
+    with pytest.raises(KeyError, match="TPU v9 mega"):
+        profiles.for_device_kind("TPU v9 mega")
+
+    class FakeChip:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeChip()])
+    prof = profiles.default_profile()
+    assert prof.name == "v5e-8" and prof.peak_bf16_tflops == 197.0
+    assert not prof.persistent_cache_donation_unsafe
+    # the attention thresholds come from the same row
+    from deepspeed_tpu.models import layers
+    for name in ("DSTPU_STREAM_ATTN_MIN", "DSTPU_STREAM_ATTN_MIN_FWD",
+                 "DSTPU_STREAM_ATTN_MIN_BWD", "DSTPU_FUSED_ATTN"):
+        monkeypatch.delenv(name, raising=False)
+    assert layers.attention_plan(512, 16, 64, False) == ("stream", "stream")
+    assert layers.attention_plan(128, 16, 64, False) == ("xla", "xla")
+
+    FakeChip.device_kind = "TPU v9 mega"
+    with pytest.raises(KeyError, match="no backend profile"):
+        profiles.default_profile()
+    with pytest.raises(KeyError, match="no backend profile"):
+        layers.attention_plan(512, 16, 64, False)
+
+
+def test_memplan_walks_jit_equations_as_calls():
+    """jax 0.9 names the jit primitive ``jit``: memplan must treat it as a
+    call (scratch and outputs never coexist), not as an opaque op."""
+    inner = jax.jit(lambda x: jnp.tanh(x @ x) @ x)
+    closed = jax.make_jaxpr(lambda x: inner(x) + 1.0)(
+        jnp.ones((256, 256), jnp.float32))
+    names = [e.primitive.name for e in closed.jaxpr.eqns]
+    assert "jit" in names and "pjit" not in names
+    assert "jit" in memplan.CALL_PRIMS
+    plan = memplan.analyze_program(lambda x: inner(x) + 1.0,
+                                   (jnp.ones((256, 256), jnp.float32),),
+                                   profile=CPU)
+    buf = 256 * 256 * 4
+    # arg + the call's output + one inner temporary; walking the call as
+    # an opaque op would stack inner scratch on top of its outputs
+    assert 3 * buf <= plan.peak_bytes <= 4 * buf
+
+
 # ======================================================================
 # CLI: --plan / --json (the CI artifact format)
 # ======================================================================
