@@ -323,6 +323,15 @@ def leg2_seq512(sz, device, on_tpu):
             "peak_bytes_in_use": peak_bytes(device)}
 
 
+def free_engines():
+    """Collect the engines no leg holds any more.  The step program that
+    ``observability.scopes`` remembers for a trace reader holds its engine:
+    let go of it first."""
+    from deepspeed_tpu.observability import scopes
+    scopes.forget_step()
+    gc.collect()
+
+
 def leg3_data_parallel(sz):
     """ZeRO-1 over every chip from this one process, against a one-chip
     split-API run of the same rows from the same seed."""
@@ -336,7 +345,7 @@ def leg3_data_parallel(sz):
                                           sz["steps"])
     check_finite("leg 3 one-chip reference", reference_losses)
     del engine
-    gc.collect()
+    free_engines()
 
     model, engine = build_engine(sz["size"], 128, micro, 1, None,
                                  optimizer=ADAM, zero_stage=1)
@@ -439,12 +448,12 @@ def main(argv=None):
     log(f"leg 1: BERT-{sz['size']} seq 128, micro-batch {sz['micro128']} x "
         f"gas {sz['gas']}, one device")
     report["leg1"] = leg1_seq128(sz, dev)
-    gc.collect()        # the leg's engine: free its HBM before the next
+    free_engines()      # the leg's engine: free its HBM before the next
 
     log(f"leg 2: BERT-{sz['size']} seq 512, micro-batch {sz['micro512']} x "
         f"gas 2, one device")
     report["leg2"] = leg2_seq512(sz, dev, on_tpu)
-    gc.collect()        # the leg's engine: free its HBM before the next
+    free_engines()      # the leg's engine: free its HBM before the next
 
     if jax.device_count() >= 4:
         log(f"leg 3: BERT-{sz['size']} seq 128, Adam, ZeRO-1 over "

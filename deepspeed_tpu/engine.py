@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deepspeed_tpu import analysis as graph_lint
 from deepspeed_tpu import constants as C
 from deepspeed_tpu.observability import fences as obs_fences
+from deepspeed_tpu.observability import scopes as obs_scopes
 from deepspeed_tpu.observability.flightrec import RECORDER as _flightrec
 from deepspeed_tpu.observability.tracing import annotate as _annotate
 from deepspeed_tpu import lr_schedules as schedules_mod
@@ -1481,7 +1482,6 @@ class DeepSpeedTpuEngine:
         ``rows=True`` wraps the result in the [1, part] per-row layout
         (default: when MP/PP state axes exist)."""
         cfg = self.config
-        flat = zero_mod.flatten_tree(grads, self.flat_meta)
         knobs = dict(
             fp32_allreduce=cfg.fp32_allreduce,
             prescale_gradients=cfg.prescale_gradients,
@@ -1489,12 +1489,17 @@ class DeepSpeedTpuEngine:
             partition_group_size=self.zero_pps,
             across_subgroups=across_subgroups)
         bounds = self._comm_buckets()
-        if bounds is not None:
-            gpart = comm.reduce_scatter_grads_bucketed(
-                flat, DATA_AXIS, self.dp_world_size, bounds, **knobs)
-        else:
-            gpart = comm.reduce_scatter_grads(
-                flat, DATA_AXIS, self.dp_world_size, **knobs)
+        # the flatten belongs to the boundary wherever this is called from
+        # (stage 2 calls it per micro-step, outside step_local)
+        with obs_scopes.scope("boundary"):
+            flat = zero_mod.flatten_tree(grads, self.flat_meta)
+            with obs_scopes.scope("boundary/reduce"):
+                if bounds is not None:
+                    gpart = comm.reduce_scatter_grads_bucketed(
+                        flat, DATA_AXIS, self.dp_world_size, bounds, **knobs)
+                else:
+                    gpart = comm.reduce_scatter_grads(
+                        flat, DATA_AXIS, self.dp_world_size, **knobs)
         if rows is None:
             rows = bool(self._zero_state_axes)
         return gpart[None] if rows else gpart
@@ -1981,6 +1986,7 @@ class DeepSpeedTpuEngine:
         bucket_elems = (self.comm_bucket_elems if self.overlap_comm
                         else None)
 
+        @obs_scopes.scoped("boundary")
         def step_local(master, opt_state, grads, ls_state, hypers,
                        normw, gids):
             # hypers arrive as ONE stacked [4, G] array (lr/b1/b2/wd rows,
@@ -2010,54 +2016,61 @@ class DeepSpeedTpuEngine:
                            if opt_state.v is not None else None))
                 else:
                     master_1d, opt_in = master, opt_state
-                if stage2:
-                    # grads arrive reduced+scattered within each sub-group
-                    # (per-micro, inside the accumulation loop); finish
-                    # the single deferred cross-sub-group psum here
-                    gpart = grads[0] if zero_2d else grads
-                    gpart = comm.finish_subgroup_reduce(
-                        gpart, DATA_AXIS, world, pps)
-                else:
-                    gpart = self._scatter_grads_local(grads, rows=False)
-                overflow = comm.overflow_any(
-                    jnp.logical_not(jnp.all(jnp.isfinite(gpart))), DATA_AXIS)
-                if zero_2d:
-                    # every stage/model shard must take the same skip
-                    # decision (reference MP-group MAX-reduce,
-                    # deepspeed_utils.py:62-75, generalized to the pipe axis)
-                    for ax, _ in state_axes:
-                        overflow = comm.overflow_any(overflow, ax)
-                    # norm with replicated-leaf dedup: normw weights each
-                    # element 1 (sharded) or 1/size per replicating axis, so
-                    # the state-axes psum counts every parameter exactly
-                    # once (reference deepspeed_utils.py:100-158).  With
-                    # sub-groups (pps < dp) partitions replicate across the
-                    # dp/pps blocks — sum within ONE sub-group only.
-                    sq = jnp.sum(normw * gpart.astype(jnp.float32) ** 2)
-                    if pps == world:
-                        sq = jax.lax.psum(sq, DATA_AXIS)
+                with obs_scopes.scope("boundary/reduce"):
+                    if stage2:
+                        # grads arrive reduced+scattered within each sub-group
+                        # (per-micro, inside the accumulation loop); finish
+                        # the single deferred cross-sub-group psum here
+                        gpart = grads[0] if zero_2d else grads
+                        gpart = comm.finish_subgroup_reduce(
+                            gpart, DATA_AXIS, world, pps)
                     else:
+                        gpart = self._scatter_grads_local(grads, rows=False)
+                    overflow = comm.overflow_any(
+                        jnp.logical_not(jnp.all(jnp.isfinite(gpart))),
+                        DATA_AXIS)
+                    if zero_2d:
+                        # every stage/model shard must take the same skip
+                        # decision (reference MP-group MAX-reduce,
+                        # deepspeed_utils.py:62-75, generalized to the pipe
+                        # axis)
+                        for ax, _ in state_axes:
+                            overflow = comm.overflow_any(overflow, ax)
+                        # norm with replicated-leaf dedup: normw weights each
+                        # element 1 (sharded) or 1/size per replicating axis,
+                        # so the state-axes psum counts every parameter exactly
+                        # once (reference deepspeed_utils.py:100-158).  With
+                        # sub-groups (pps < dp) partitions replicate across the
+                        # dp/pps blocks — sum within ONE sub-group only.
+                        sq = jnp.sum(normw * gpart.astype(jnp.float32) ** 2)
+                        if pps == world:
+                            sq = jax.lax.psum(sq, DATA_AXIS)
+                        else:
+                            within, _ = comm.subgroup_index_groups(world, pps)
+                            sq = jax.lax.psum(sq, DATA_AXIS,
+                                              axis_index_groups=within)
+                        for ax, _ in state_axes:
+                            sq = jax.lax.psum(sq, ax)
+                    elif pps == world:
+                        sq = jax.lax.psum(
+                            jnp.sum(gpart.astype(jnp.float32) ** 2), DATA_AXIS)
+                    else:
+                        # sub-partitions replicate across the dp/pps
+                        # sub-groups; sum within ONE sub-group to count each
+                        # element once
                         within, _ = comm.subgroup_index_groups(world, pps)
-                        sq = jax.lax.psum(sq, DATA_AXIS,
-                                          axis_index_groups=within)
-                    for ax, _ in state_axes:
-                        sq = jax.lax.psum(sq, ax)
-                elif pps == world:
-                    sq = jax.lax.psum(
-                        jnp.sum(gpart.astype(jnp.float32) ** 2), DATA_AXIS)
-                else:
-                    # sub-partitions replicate across the dp/pps sub-groups;
-                    # sum within ONE sub-group to count each element once
-                    within, _ = comm.subgroup_index_groups(world, pps)
-                    sq = jax.lax.psum(
-                        jnp.sum(gpart.astype(jnp.float32) ** 2), DATA_AXIS,
-                        axis_index_groups=within)
-                total_norm = jnp.sqrt(sq)
-                combined = prec.combined_unscale_and_clip_factor(
-                    total_norm, ls_state, clip) if fp16 else (
-                    prec.combined_unscale_and_clip_factor(
-                        total_norm, prec.static_loss_scale_state(1.0), clip)
-                    if clip > 0 else 1.0)
+                        sq = jax.lax.psum(
+                            jnp.sum(gpart.astype(jnp.float32) ** 2), DATA_AXIS,
+                            axis_index_groups=within)
+                    total_norm = jnp.sqrt(sq)
+                with obs_scopes.scope("boundary/update"):
+                    combined = prec.combined_unscale_and_clip_factor(
+                        total_norm, ls_state, clip) if fp16 else (
+                        prec.combined_unscale_and_clip_factor(
+                            total_norm, prec.static_loss_scale_state(1.0),
+                            clip)
+                        if clip > 0 else 1.0)
+                @obs_scopes.scoped("boundary/update")
                 def upd_seg(mseg, gseg, oin, lr_, b1_, b2_, wd_):
                     """Shard-local update + skip-on-overflow on one flat
                     segment (the whole partition, or one overlap bucket —
@@ -2100,37 +2113,44 @@ class DeepSpeedTpuEngine:
                             hy_seg(lr, s, e), hy_seg(b1, s, e),
                             hy_seg(b2, s, e), hy_seg(wd, s, e))
                         segs.append((nm, new_o))
-                        # weight all-gather, per bucket (reference
-                        # zero_optimizer.py:397-432)
-                        blocks.append(comm.allgather_partition_bucket(
-                            nm.astype(jnp.float32), DATA_AXIS,
-                            world_size=world, partition_group_size=pps))
+                        with obs_scopes.scope("boundary/gather"):
+                            # weight all-gather, per bucket (reference
+                            # zero_optimizer.py:397-432)
+                            blocks.append(comm.allgather_partition_bucket(
+                                nm.astype(jnp.float32), DATA_AXIS,
+                                world_size=world, partition_group_size=pps))
                         new_step = new_o.step
-                    new_master = jnp.concatenate([nm for nm, _ in segs])
-                    cat = lambda pick: {"flat": jnp.concatenate(
-                        [pick(o) for _, o in segs])}
-                    new_opt = optim_mod.OptimizerState(
-                        step=new_step,
-                        m=(None if opt_in.m is None
-                           else cat(lambda o: o.m["flat"])),
-                        v=(None if opt_in.v is None
-                           else cat(lambda o: o.v["flat"])))
-                    flat_full = jnp.reshape(
-                        jnp.concatenate(blocks, axis=1), (-1,))
+                    with obs_scopes.scope("boundary/update"):
+                        new_master = jnp.concatenate([nm for nm, _ in segs])
+                        cat = lambda pick: {"flat": jnp.concatenate(
+                            [pick(o) for _, o in segs])}
+                        new_opt = optim_mod.OptimizerState(
+                            step=new_step,
+                            m=(None if opt_in.m is None
+                               else cat(lambda o: o.m["flat"])),
+                            v=(None if opt_in.v is None
+                               else cat(lambda o: o.v["flat"])))
+                    with obs_scopes.scope("boundary/gather"):
+                        flat_full = jnp.reshape(
+                            jnp.concatenate(blocks, axis=1), (-1,))
                 else:
                     new_master, new_opt = upd_seg(master_1d, gpart, opt_in,
                                                   lr, b1, b2, wd)
-                    # weight all-gather (reference zero_optimizer.py:397-432)
-                    flat_full = comm.allgather_params(
-                        new_master.astype(jnp.float32), DATA_AXIS,
-                        world_size=world, partition_group_size=pps)
-                # fence the gathered buffer: left free to rewrite the
-                # all-gather together with the per-leaf slices that consume
-                # it, the TPU compiler (libtpu 0.0.34) took 930 s over
-                # BERT-large's boundary; fenced, seconds.  The gather's
-                # output is a real buffer either way.
-                params = zero_mod.unflatten_tree(
-                    jax.lax.optimization_barrier(flat_full), meta, dtype=cdt)
+                    with obs_scopes.scope("boundary/gather"):
+                        # weight all-gather (reference
+                        # zero_optimizer.py:397-432)
+                        flat_full = comm.allgather_params(
+                            new_master.astype(jnp.float32), DATA_AXIS,
+                            world_size=world, partition_group_size=pps)
+                with obs_scopes.scope("boundary/gather"):
+                    # fence the gathered buffer: left free to rewrite the
+                    # all-gather together with the per-leaf slices that consume
+                    # it, the TPU compiler (libtpu 0.0.34) took 930 s over
+                    # BERT-large's boundary; fenced, seconds.  The gather's
+                    # output is a real buffer either way.
+                    params = zero_mod.unflatten_tree(
+                        jax.lax.optimization_barrier(flat_full), meta,
+                        dtype=cdt)
                 if zero_2d:
                     new_master = new_master[None]
                     new_opt = optim_mod.OptimizerState(
@@ -2144,110 +2164,123 @@ class DeepSpeedTpuEngine:
                 # tiled psum_scatter over 'data') — finish their averaging
                 # with 1/world; replicated leaves are plain local grads and
                 # psum with the full knob semantics
-                knobs = dict(
-                    fp32_allreduce=cfg.fp32_allreduce,
-                    prescale_gradients=cfg.prescale_gradients,
-                    gradient_predivide_factor=cfg.gradient_predivide_factor)
+                with obs_scopes.scope("boundary/reduce"):
+                    knobs = dict(
+                        fp32_allreduce=cfg.fp32_allreduce,
+                        prescale_gradients=cfg.prescale_gradients,
+                        gradient_predivide_factor=(
+                            cfg.gradient_predivide_factor))
 
-                def reduce_leaf(g, d):
-                    if g is None:
-                        return None
-                    if d >= 0:
-                        return g / world
-                    return comm.allreduce_grads(g, DATA_AXIS, world,
-                                                bucket_elems=bucket_elems,
-                                                **knobs)
-
-                grads = jax.tree_util.tree_map(
-                    reduce_leaf, grads, z3_dims,
-                    is_leaf=lambda x: x is None)
-                # norm/overflow: partitioned shards are disjoint over DP
-                # (weight 1, psum over data); replicated leaves identical
-                # over DP (1/dp); model/pipe dedup per the leaf spec —
-                # every shard takes the same skip/clip decision (reference
-                # deepspeed_utils.py:62-75, 100-158)
-                sq, finite = zero3_mod.local_sqnorm_and_finite(
-                    grads, z3_dims, param_specs, world, state_axes)
-                overflow = comm.overflow_any(jnp.logical_not(finite),
-                                             DATA_AXIS)
-                sq = jax.lax.psum(sq, DATA_AXIS)
-                for ax, _ in state_axes:
-                    overflow = comm.overflow_any(overflow, ax)
-                    sq = jax.lax.psum(sq, ax)
-                total_norm = jnp.sqrt(sq)
-                combined = prec.combined_unscale_and_clip_factor(
-                    total_norm, ls_state, clip) if fp16 else (
-                    prec.combined_unscale_and_clip_factor(
-                        total_norm, prec.static_loss_scale_state(1.0), clip)
-                    if clip > 0 else 1.0)
-                # elementwise Adam-family update directly on the local
-                # (master, moment, grad) shards — the partitioning is
-                # invisible to the optimizer
-                new_master, new_opt = opt.update(
-                    master, grads, opt_state,
-                    lr=lr, beta1=b1, beta2=b2, weight_decay=wd,
-                    combined_scale=combined)
-                if skip_bad:
-                    new_master = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(overflow, old, new),
-                        new_master, master)
-                    new_opt = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(overflow, old, new),
-                        new_opt, opt_state)
-                # NO weight all-gather: params persist partitioned; the
-                # next step's layer gathers re-materialise them on use
-                params = jax.tree_util.tree_map(
-                    lambda m: m.astype(cdt), new_master)
-            else:
-                knobs = dict(
-                    fp32_allreduce=cfg.fp32_allreduce,
-                    prescale_gradients=cfg.prescale_gradients,
-                    gradient_predivide_factor=cfg.gradient_predivide_factor)
-                if sparse_flags is None:
-                    grads = comm.allreduce_grads(grads, DATA_AXIS, world,
-                                                 bucket_elems=bucket_elems,
-                                                 **knobs)
-                else:
-                    # marked leaves (embeddings) reduce as gathered
-                    # (indices, values) with a dense-psum fallback
-                    # (reference sparse_allreduce,
-                    # deepspeed_light.py:884-940)
-                    from deepspeed_tpu import sparse as sparse_mod
-
-                    def reduce_one(g, flag):
+                    def reduce_leaf(g, d):
                         if g is None:
                             return None
-                        if flag:
-                            return sparse_mod.sparse_psum(
-                                g, DATA_AXIS, world,
-                                cfg.sparse_gradients_max_rows, **knobs)
+                        if d >= 0:
+                            return g / world
                         return comm.allreduce_grads(g, DATA_AXIS, world,
                                                     bucket_elems=bucket_elems,
                                                     **knobs)
 
                     grads = jax.tree_util.tree_map(
-                        reduce_one, grads, sparse_flags,
+                        reduce_leaf, grads, z3_dims,
                         is_leaf=lambda x: x is None)
-                overflow, sq = self._global_overflow_and_sqnorm(grads)
-                total_norm = jnp.sqrt(sq)
-                combined = prec.combined_unscale_and_clip_factor(
-                    total_norm, ls_state, clip) if fp16 else (
-                    prec.combined_unscale_and_clip_factor(
-                        total_norm, prec.static_loss_scale_state(1.0), clip)
-                    if clip > 0 else 1.0)
-                new_master, new_opt = opt.update(
-                    master, grads, opt_state,
-                    lr=lr, beta1=b1, beta2=b2, weight_decay=wd,
-                    combined_scale=combined)
-                if skip_bad:
-                    new_master = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(overflow, old, new),
-                        new_master, master)
-                    new_opt = jax.tree_util.tree_map(
-                        lambda new, old: jnp.where(overflow, old, new),
-                        new_opt, opt_state)
-                params = jax.tree_util.tree_map(
-                    lambda m: m.astype(cdt), new_master)
+                    # norm/overflow: partitioned shards are disjoint over DP
+                    # (weight 1, psum over data); replicated leaves identical
+                    # over DP (1/dp); model/pipe dedup per the leaf spec —
+                    # every shard takes the same skip/clip decision (reference
+                    # deepspeed_utils.py:62-75, 100-158)
+                    sq, finite = zero3_mod.local_sqnorm_and_finite(
+                        grads, z3_dims, param_specs, world, state_axes)
+                    overflow = comm.overflow_any(jnp.logical_not(finite),
+                                                 DATA_AXIS)
+                    sq = jax.lax.psum(sq, DATA_AXIS)
+                    for ax, _ in state_axes:
+                        overflow = comm.overflow_any(overflow, ax)
+                        sq = jax.lax.psum(sq, ax)
+                    total_norm = jnp.sqrt(sq)
+                with obs_scopes.scope("boundary/update"):
+                    combined = prec.combined_unscale_and_clip_factor(
+                        total_norm, ls_state, clip) if fp16 else (
+                        prec.combined_unscale_and_clip_factor(
+                            total_norm, prec.static_loss_scale_state(1.0),
+                            clip)
+                        if clip > 0 else 1.0)
+                    # elementwise Adam-family update directly on the local
+                    # (master, moment, grad) shards — the partitioning is
+                    # invisible to the optimizer
+                    new_master, new_opt = opt.update(
+                        master, grads, opt_state,
+                        lr=lr, beta1=b1, beta2=b2, weight_decay=wd,
+                        combined_scale=combined)
+                    if skip_bad:
+                        new_master = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(overflow, old, new),
+                            new_master, master)
+                        new_opt = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(overflow, old, new),
+                            new_opt, opt_state)
+                    # NO weight all-gather: params persist partitioned; the
+                    # next step's layer gathers re-materialise them on use.
+                    # The cast is the update's (see the replicated arm).
+                    params = jax.tree_util.tree_map(
+                        lambda m: m.astype(cdt), new_master)
+            else:
+                with obs_scopes.scope("boundary/reduce"):
+                    knobs = dict(
+                        fp32_allreduce=cfg.fp32_allreduce,
+                        prescale_gradients=cfg.prescale_gradients,
+                        gradient_predivide_factor=(
+                            cfg.gradient_predivide_factor))
+                    if sparse_flags is None:
+                        grads = comm.allreduce_grads(grads, DATA_AXIS, world,
+                                                     bucket_elems=bucket_elems,
+                                                     **knobs)
+                    else:
+                        # marked leaves (embeddings) reduce as gathered
+                        # (indices, values) with a dense-psum fallback
+                        # (reference sparse_allreduce,
+                        # deepspeed_light.py:884-940)
+                        from deepspeed_tpu import sparse as sparse_mod
+
+                        def reduce_one(g, flag):
+                            if g is None:
+                                return None
+                            if flag:
+                                return sparse_mod.sparse_psum(
+                                    g, DATA_AXIS, world,
+                                    cfg.sparse_gradients_max_rows, **knobs)
+                            return comm.allreduce_grads(
+                                g, DATA_AXIS, world,
+                                bucket_elems=bucket_elems, **knobs)
+
+                        grads = jax.tree_util.tree_map(
+                            reduce_one, grads, sparse_flags,
+                            is_leaf=lambda x: x is None)
+                    overflow, sq = self._global_overflow_and_sqnorm(grads)
+                    total_norm = jnp.sqrt(sq)
+                with obs_scopes.scope("boundary/update"):
+                    combined = prec.combined_unscale_and_clip_factor(
+                        total_norm, ls_state, clip) if fp16 else (
+                        prec.combined_unscale_and_clip_factor(
+                            total_norm, prec.static_loss_scale_state(1.0),
+                            clip)
+                        if clip > 0 else 1.0)
+                    new_master, new_opt = opt.update(
+                        master, grads, opt_state,
+                        lr=lr, beta1=b1, beta2=b2, weight_decay=wd,
+                        combined_scale=combined)
+                    if skip_bad:
+                        new_master = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(overflow, old, new),
+                            new_master, master)
+                        new_opt = jax.tree_util.tree_map(
+                            lambda new, old: jnp.where(overflow, old, new),
+                            new_opt, opt_state)
+                    # no gather here, and XLA fuses this cast into the
+                    # update's fusions as their last instruction, whose
+                    # op_name the fusion then carries: under another scope
+                    # it would read the whole update as that scope
+                    params = jax.tree_util.tree_map(
+                        lambda m: m.astype(cdt), new_master)
 
             new_ls = (prec.update_loss_scale(ls_state, overflow,
                                              variant=variant)
@@ -2730,6 +2763,11 @@ class DeepSpeedTpuEngine:
                 self._train_batch_fns, key,
                 lambda: self._build_train_batch(batch))
             self._train_batch_key = key
+            # device scopes: remember (never lower) this program and its
+            # arguments' shapes for a trace reader's step_scope_map()
+            obs_scopes.remember_step(
+                self._train_batch_fn,
+                graph_lint.train_batch_args(self, batch))
         self._maybe_graph_lint(
             "train_batch", key,
             lambda: graph_lint.analyze_engine_train_batch(self, batch))

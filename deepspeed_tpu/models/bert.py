@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import (DATA_AXIS, MODEL_AXIS,
                                              SEQ_AXIS)
 
@@ -65,11 +66,14 @@ def _encode(cfg, params, input_ids, attention_mask, token_type_ids,
     ZeRO-3 (``z3_block_dims`` = its deferred block dims; ``z3_prefetch``
     pairs the per-layer gathers — transformer.scan_layers)."""
     T_len = input_ids.shape[1]
-    x = L.vocab_parallel_embedding(input_ids, params["wte"])
-    x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
-        x.dtype)[None]
-    x = x + jnp.take(params["wtt"].astype(x.dtype), token_type_ids, axis=0)
-    x = L.layer_norm(x, params["ln_emb_s"], params["ln_emb_b"], cfg.ln_eps)
+    with S.scope("embed"):
+        x = L.vocab_parallel_embedding(input_ids, params["wte"])
+        x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
+            x.dtype)[None]
+        x = x + jnp.take(params["wtt"].astype(x.dtype), token_type_ids,
+                         axis=0)
+        x = L.layer_norm(x, params["ln_emb_s"], params["ln_emb_b"],
+                         cfg.ln_eps)
     return T.stack_apply(x, params["blocks"], cfg, attn_mask=attention_mask,
                          z3_dims=z3_block_dims, z3_prefetch=z3_prefetch)
 
@@ -227,51 +231,53 @@ class BertForPreTraining:
                     z3_block_dims=z3_deferred.get("blocks"),
                     z3_prefetch=getattr(self, "zero3_prefetch", False))
 
-        if mlm_positions is None:
-            budget = self.mlm_gather_budget
-            if budget and L.axis_size_or_1(SEQ_AXIS) == 1:
-                # sparse head for the dense-labels format: select <= budget
-                # masked positions per sequence (top_k of the 0/1 mask is
-                # stable, so masked positions come first, in order), gather
-                # them, and run the vocab projection on [B, P, H] instead
-                # of [B, T, H].  Matches the dense loss exactly while every
-                # sequence's masked count fits the budget (see the field
-                # docstring for the overflow contract).
-                P_ = min(int(budget), mlm_labels.shape[1])
-                maskf = (mlm_labels >= 0).astype(jnp.float32)
-                w, pos = jax.lax.top_k(maskf, P_)           # [B, P] each
-                ids = jnp.clip(jnp.take_along_axis(mlm_labels, pos, axis=1),
-                               0, None)                     # w=0 rows: any id
-                h_m = L.gather_positions(x, pos)
-                logits = self._mlm_head(params, h_m)        # [B, P, vocab/mp]
-                tok_loss = L.vocab_parallel_cross_entropy(logits, ids)
-                loss = (jnp.sum(tok_loss * w)
-                        / jnp.maximum(jnp.sum(w), 1.0))
+        with S.scope("head"):
+            if mlm_positions is None:
+                budget = self.mlm_gather_budget
+                if budget and L.axis_size_or_1(SEQ_AXIS) == 1:
+                    # sparse head for the dense-labels format: select <= budget
+                    # masked positions per sequence (top_k of the 0/1 mask is
+                    # stable, so masked positions come first, in order), gather
+                    # them, and run the vocab projection on [B, P, H] instead
+                    # of [B, T, H].  Matches the dense loss exactly while every
+                    # sequence's masked count fits the budget (see the field
+                    # docstring for the overflow contract).
+                    P_ = min(int(budget), mlm_labels.shape[1])
+                    maskf = (mlm_labels >= 0).astype(jnp.float32)
+                    w, pos = jax.lax.top_k(maskf, P_)           # [B, P] each
+                    ids = jnp.clip(                 # w=0 rows: any id
+                        jnp.take_along_axis(mlm_labels, pos, axis=1), 0, None)
+                    h_m = L.gather_positions(x, pos)
+                    logits = self._mlm_head(params, h_m)  # [B, P, vocab/mp]
+                    tok_loss = L.vocab_parallel_cross_entropy(logits, ids)
+                    loss = (jnp.sum(tok_loss * w)
+                            / jnp.maximum(jnp.sum(w), 1.0))
+                else:
+                    logits = self._mlm_head(params, x)
+                    tok_loss = L.vocab_parallel_cross_entropy(
+                        logits, mlm_labels)
+                    loss = L.masked_mean_loss(tok_loss, mlm_labels >= 0)
             else:
-                logits = self._mlm_head(params, x)
-                tok_loss = L.vocab_parallel_cross_entropy(logits, mlm_labels)
-                loss = L.masked_mean_loss(tok_loss, mlm_labels >= 0)
-        else:
-            h_m = L.gather_positions(x, mlm_positions)
-            logits = self._mlm_head(params, h_m)          # [B, P, vocab/mp]
-            tok_loss = L.vocab_parallel_cross_entropy(logits, mlm_ids)
-            w = mlm_weights.astype(jnp.float32)
-            loss = jnp.sum(tok_loss * w) / jnp.maximum(jnp.sum(w), 1.0)
+                h_m = L.gather_positions(x, mlm_positions)
+                logits = self._mlm_head(params, h_m)      # [B, P, vocab/mp]
+                tok_loss = L.vocab_parallel_cross_entropy(logits, mlm_ids)
+                w = mlm_weights.astype(jnp.float32)
+                loss = jnp.sum(tok_loss * w) / jnp.maximum(jnp.sum(w), 1.0)
 
-        if self.use_nsp and nsp_labels is not None:
-            if L.axis_size_or_1(SEQ_AXIS) > 1:
-                raise NotImplementedError(
-                    "NSP pools the global [CLS] token, which lives only on "
-                    "sequence shard 0 — NSP is not supported under "
-                    "context_parallel_size > 1")
-            pooled = jnp.tanh(x[:, 0] @ params["pool_w"].astype(x.dtype)
-                              + params["pool_b"].astype(x.dtype))
-            nsp_logits = (pooled @ params["nsp_w"].astype(pooled.dtype)
-                          + params["nsp_b"].astype(pooled.dtype))
-            logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), -1)
-            nsp = -jnp.mean(jnp.take_along_axis(
-                logp, nsp_labels[:, None], axis=-1)[:, 0])
-            loss = loss + nsp
+            if self.use_nsp and nsp_labels is not None:
+                if L.axis_size_or_1(SEQ_AXIS) > 1:
+                    raise NotImplementedError(
+                        "NSP pools the global [CLS] token, which lives only "
+                        "on sequence shard 0 — NSP is not supported under "
+                        "context_parallel_size > 1")
+                pooled = jnp.tanh(x[:, 0] @ params["pool_w"].astype(x.dtype)
+                                  + params["pool_b"].astype(x.dtype))
+                nsp_logits = (pooled @ params["nsp_w"].astype(pooled.dtype)
+                              + params["nsp_b"].astype(pooled.dtype))
+                logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), -1)
+                nsp = -jnp.mean(jnp.take_along_axis(
+                    logp, nsp_labels[:, None], axis=-1)[:, 0])
+                loss = loss + nsp
         return loss
 
     __call__ = apply
@@ -343,9 +349,10 @@ class BertForQuestionAnswering:
         x = _encode(cfg, params, input_ids, attention_mask, token_type_ids,
                     z3_block_dims=z3_deferred.get("blocks"),
                     z3_prefetch=getattr(self, "zero3_prefetch", False))
-        logits = (x @ params["qa_w"].astype(x.dtype)
-                  + params["qa_b"].astype(x.dtype)).astype(jnp.float32)
-        return logits[..., 0], logits[..., 1]
+        with S.scope("head"):
+            logits = (x @ params["qa_w"].astype(x.dtype)
+                      + params["qa_b"].astype(x.dtype)).astype(jnp.float32)
+            return logits[..., 0], logits[..., 1]
 
     def apply(self, params, input_ids, attention_mask, token_type_ids,
               start_positions, end_positions):
@@ -358,7 +365,8 @@ class BertForQuestionAnswering:
             return -jnp.mean(jnp.take_along_axis(
                 logp, pos[:, None], axis=-1)[:, 0])
 
-        return 0.5 * (span_loss(start_logits, start_positions)
-                      + span_loss(end_logits, end_positions))
+        with S.scope("head"):
+            return 0.5 * (span_loss(start_logits, start_positions)
+                          + span_loss(end_logits, end_positions))
 
     __call__ = apply

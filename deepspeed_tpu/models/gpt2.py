@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
 
@@ -193,14 +194,16 @@ class GPT2:
         cfg = self.config
         T_len = tokens.shape[1]
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
-        x = L.vocab_parallel_embedding(tokens, params["wte"])
-        x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
-            x.dtype)[None]
+        with S.scope("embed"):
+            x = L.vocab_parallel_embedding(tokens, params["wte"])
+            x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
+                x.dtype)[None]
         x, aux = self._stack(x, params["blocks"],
                              z3_dims=z3_deferred.get("blocks"))
-        x = L.layer_norm(x, params["lnf_s"], params["lnf_b"], cfg.ln_eps)
-        logits = L.vocab_parallel_logits(x, params["wte"])
-        loss = L.vocab_parallel_cross_entropy(logits, labels)
-        return L.masked_mean_loss(loss, labels >= 0) + aux
+        with S.scope("head"):
+            x = L.layer_norm(x, params["lnf_s"], params["lnf_b"], cfg.ln_eps)
+            logits = L.vocab_parallel_logits(x, params["wte"])
+            loss = L.vocab_parallel_cross_entropy(logits, labels)
+            return L.masked_mean_loss(loss, labels >= 0) + aux
 
     __call__ = apply

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, SEQ_AXIS
 
 # Pallas attention dispatch (DSTPU_FUSED_ATTN = "auto" | "1" | "0").
@@ -320,6 +321,7 @@ def masked_mean_loss(loss, mask):
     return local_sum / jnp.maximum(local_cnt, 1.0)
 
 
+@S.scoped("norm")
 def layer_norm(x, scale, bias, eps=1e-5):
     """LayerNorm in fp32 (bf16/fp16 inputs upcast for the moments)."""
     xf = x.astype(jnp.float32)
@@ -589,6 +591,7 @@ def core_attention(q, k, v, *, causal, attn_mask=None):
                                     fwd_impl, bwd_impl)
 
 
+@S.scoped("attn")
 def multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local, proj_b,
                         *, n_heads_global, causal, attn_mask=None,
                         axis=MODEL_AXIS, sp_impl="ring"):

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.gpt2 import GPT2
+from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel import pipeline as pipe_mod
 from deepspeed_tpu.parallel.topology import PIPE_AXIS
 
@@ -71,9 +72,10 @@ class GPT2Pipelined(GPT2):
                 "(expected 'gpipe' or '1f1b')")
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         z3_block_dims = z3_deferred.get("blocks")
-        x = L.vocab_parallel_embedding(tokens, params["wte"])
-        x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
-            x.dtype)[None]
+        with S.scope("embed"):
+            x = L.vocab_parallel_embedding(tokens, params["wte"])
+            x = x + L.seq_shard_positions(params["wpe"], T_len).astype(
+                x.dtype)[None]
         x_micro = x.reshape(m, B // m, T_len, x.shape[-1])
 
         if self.schedule == "1f1b":
@@ -91,6 +93,7 @@ class GPT2Pipelined(GPT2):
                 return self._pipe_stack(u, blocks,
                                         z3_dims=z3_block_dims)   # (y, aux)
 
+            @S.scoped("head")
             def head_1f1b(hp, y, ys):
                 h = L.layer_norm(y, hp["lnf_s"], hp["lnf_b"], cfg.ln_eps)
                 logits = L.vocab_parallel_logits(h, hp["wte"])
@@ -115,6 +118,7 @@ class GPT2Pipelined(GPT2):
         # repeating the full O(B·T·V·H) head; the psum'd scalar stays
         # pipe-uniform, so replicated-leaf grads still arrive as
         # per-stage partials the engine completes over 'pipe'
+        @S.scoped("head")
         def head_fn(xs, ys):
             h = L.layer_norm(xs, params["lnf_s"], params["lnf_b"],
                              cfg.ln_eps)

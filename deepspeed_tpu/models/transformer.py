@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu import zero3 as Z
 from deepspeed_tpu.models import layers as L
+from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
 
@@ -121,6 +122,7 @@ def block_partition_specs() -> dict:
     }
 
 
+@S.scoped("ffn")
 def _mlp(x, p):
     y = L.column_parallel_linear(x, p["fc_w"], p["fc_b"])
     # named for the "selective" remat policy: saving the pre-GELU ffn lets
@@ -136,7 +138,9 @@ def block_with_ffn(x, p, cfg: TransformerConfig, attn_mask=None, ffn=None):
     ``ffn(u, p) -> (delta, aux)`` replaces the dense MLP (MoE plugs in
     here, models/moe.py); default is the dense MLP with aux 0.  p leaves
     have NO leading layer axis (scan slices it off).  Returns (x, aux)."""
-    f = ffn if ffn is not None else (lambda u, pp: (_mlp(u, pp), 0.0))
+    # a plug-in FFN (MoE) runs under the same device scope as the dense MLP
+    f = (S.scoped("ffn")(ffn) if ffn is not None
+         else (lambda u, pp: (_mlp(u, pp), 0.0)))
     attn = lambda u: L.multihead_attention(
         u, p["qkv_w"], p["qkv_b"], p["proj_w"], p["proj_b"],
         n_heads_global=cfg.num_heads, causal=cfg.causal,
@@ -246,6 +250,7 @@ def _sched_barrier_bwd(_, ct):
 _sched_barrier.defvjp(_sched_barrier_fwd, _sched_barrier_bwd)
 
 
+@S.scoped("block")
 def scan_layers(body, carry, stacked_params, cfg: TransformerConfig,
                 z3_dims=None, z3_prefetch=False):
     """``lax.scan`` of ``body(carry, layer_params) -> (carry, y)`` over the
@@ -271,7 +276,11 @@ def scan_layers(body, carry, stacked_params, cfg: TransformerConfig,
     separates the two blocks, which keeps bitwise parity with the
     on-demand path; ODD layer counts fall back to on-demand (an odd tail
     outside the scan tiles its bf16 grad reductions differently and
-    drifts by ulps — family depths are even)."""
+    drifts by ulps — family depths are even).
+
+    The whole scan runs under the ``dstpu/block`` device scope, so the
+    per-layer slicing of the stacked parameters and saved activations,
+    forward and backward, counts with the blocks it feeds."""
     if z3_dims is None or not Z.partitioned_any(z3_dims):
         return jax.lax.scan(remat_wrap(body, cfg), carry, stacked_params)
     num_layers = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]

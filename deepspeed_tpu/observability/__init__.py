@@ -9,7 +9,10 @@ Four pieces, one facade:
   (the ROADMAP-4 prerequisite); trajectory-neutral by construction.
 * :mod:`~deepspeed_tpu.observability.tracing` — programmatic
   ``jax.profiler`` capture over a configured step window, ``dstpu/*``
-  TraceAnnotation spans, and watchdog-triggered hang capture.
+  TraceAnnotation spans, and watchdog-triggered hang capture;
+  :mod:`~deepspeed_tpu.observability.scopes` names the device side of the
+  same capture (``dstpu/*`` ``named_scope``s inside the compiled step and
+  the instruction-name map a trace reader joins them by).
 * :mod:`~deepspeed_tpu.observability.registry` — MetricRegistry exporter
   fan-out: engine throughput/goodput, resilience counters and
   compile-cache counters all emit through one path to TensorBoard and a

@@ -13,8 +13,11 @@ Two capture paths share one :class:`Tracer`:
   a profile of what the host was doing, not just a stack dump.
 
 :func:`annotate` provides the ``TraceAnnotation`` spans the engine wraps
-around fwd/bwd/boundary/checkpoint — named ``dstpu/<span>`` in the trace
-viewer.  Annotations are host-side markers, ~free when no trace is active.
+around its calls — ``fwdbwd``, ``boundary``, ``train_batch``,
+``train_many``, ``eval``, ``checkpoint.save``, ``checkpoint.load`` — named
+``dstpu/<span>`` in the trace viewer.  Annotations are host-side markers,
+~free when no trace is active; the device-side ``dstpu/*`` names inside the
+compiled step are :mod:`~deepspeed_tpu.observability.scopes`.
 """
 
 from __future__ import annotations
