@@ -1101,15 +1101,16 @@ def run_head_bench(repeats=None):
 
 def run_overlap_bench():
     """Boundary comm/compute-overlap microbench (overlap_comm): ZeRO-1 and
-    ZeRO-3 engines stepped with the bucketed/pipelined boundary vs the
-    serial monolithic path (DSTPU_OVERLAP=off program shape).
+    ZeRO-3 engines stepped with the knob on vs off.  Since PR 25 the
+    stage-1 boundary is ONE program either way (one reduce-scatter, one
+    all-gather: the bucketed form bought no overlap on the chip, PERF.md),
+    so its two rows are the control; the stage-3 rows compare the
+    paired-layer prefetch with on-demand gathers.
 
     CPU evidence (what this run can prove off-chip): (1) PARITY — after
     ``steps`` fused train_batch steps the two engines' parameters are
-    bitwise identical (bucketing only re-tiles the same elementwise math);
-    (2) DISPATCH — the overlap step program really issues K independent
-    reduce-scatter / all-gather collectives where the serial program
-    issues one of each (counted in the traced jaxpr).  Wall-clock overlap
+    bitwise identical; (2) DISPATCH — the collectives each step program
+    issues (counted in the traced jaxpr).  Wall-clock overlap
     needs real ICI ∥ MXU concurrency — on the virtual CPU mesh all
     devices share host cores, so ms/step here is contention noise; the
     artifact records the platform and the chip re-measurement command
@@ -1190,16 +1191,13 @@ def run_overlap_bench():
             float(loss)
             dt = (time.perf_counter() - t0) / steps
             counts = collective_counts(engine)
-            buckets = (len(engine._comm_buckets() or ()) if engine.zero_flat
-                       else None)
             rows.append({
                 "stage": stage, "overlap": overlap,
-                "ms_per_step": round(dt * 1000, 2),
-                "buckets": buckets, **counts})
+                "ms_per_step": round(dt * 1000, 2), **counts})
             final_params[(stage, overlap)] = jax.tree_util.tree_map(
                 np.asarray, engine.params)
             print(f"zero-{stage} overlap={overlap}: {dt*1e3:.1f} ms/step "
-                  f"buckets={buckets} {counts}", file=sys.stderr)
+                  f"{counts}", file=sys.stderr)
 
     parity = {}
     for stage in (1, 3):
@@ -1218,7 +1216,6 @@ def run_overlap_bench():
         "hardware_true": on_tpu,
         "seq": seq, "hidden": hidden, "layers": layers,
         "comm_bucket_mb": bucket_mb, "batch_per_chip": bpc,
-        "zero1_buckets_overlap": r[(1, True)]["buckets"],
         "zero1_scatter_ops": [r[(1, True)]["reduce_scatter"],
                               r[(1, False)]["reduce_scatter"]],
         "zero1_gather_ops": [r[(1, True)]["all_gather"],
@@ -1227,7 +1224,7 @@ def run_overlap_bench():
                              r[(3, False)]["all_gather"]],
         **{k: v for k, v in parity.items()},
         "rows": rows,
-        "note": ("CPU rows prove bit-exact parity and the bucketed "
+        "note": ("CPU rows prove bit-exact parity and the "
                  "dispatch structure only — virtual CPU devices share "
                  "host cores, so ms/step is contention noise, not "
                  "overlap.  Re-measure on chip: "

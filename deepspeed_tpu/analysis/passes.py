@@ -12,9 +12,9 @@ the rule catalogue; rule codes are stable and suppressible by prefix.
    branch whose predicate carries that taint (the 1F1B/GPipe stage
    schedules in parallel/pipeline.py are exactly this shape).  Signatures
    include the operand shape/dtype — the wire format — so the
-   ``overlap_comm`` bucketed boundary (K same-primitive collectives told
-   apart only by their bucket shapes) and the ZeRO-3 prefetched gather
-   sequence compare exactly: branches bucketing the same payload
+   ``overlap_comm`` chunked psums (K same-primitive collectives told
+   apart only by their chunk shapes) and the ZeRO-3 prefetched gather
+   sequence compare exactly: branches chunking the same payload
    differently are a real deadlock and are flagged.  Also checks
    axis names against the engine mesh and ``ppermute`` permutation validity
    — all of ``comm.py``'s wrappers (psum, psum_scatter with
@@ -102,9 +102,9 @@ def _collective_sig(eqn) -> Tuple:
     perm = p.get("perm")
     layout = tuple((k, p[k]) for k in _SIG_LAYOUT_KEYS if k in p)
     # operand shapes/dtypes are part of the wire format: under overlap_comm
-    # the boundary issues K same-primitive bucketed collectives whose only
-    # distinguishing feature is the buffer shape, so two branches bucketing
-    # the same payload DIFFERENTLY (or one bucketed, one monolithic) must
+    # a big leaf reduces as K same-primitive chunked psums whose only
+    # distinguishing feature is the buffer shape, so two branches chunking
+    # the same payload DIFFERENTLY (or one chunked, one monolithic) must
     # compare unequal — ranks in either branch would block exchanging
     # mismatched buffers.  ALL operands are hashed: psum-family eqns carry
     # several arrays at once, and a divergence in operand 2..N (or in the

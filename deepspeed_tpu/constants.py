@@ -553,12 +553,11 @@ SEQUENCE_PARALLEL_IMPL_DEFAULT = None     # None | "ring" | "ulysses"
 ZERO_PARAMETER_PARALLEL_SIZE = "parameter_parallel_size"
 ZERO_PARAMETER_PARALLEL_SIZE_DEFAULT = None
 
-# Comm/compute overlap: the boundary collectives (reduce-scatter / weight
-# all-gather, and the plain-DP grad psum) split into lane-aligned buckets so
-# XLA's async collectives can overlap each other and the shard-local update
-# (docs/scaling.md "Communication/compute overlap").  Bucketing only re-tiles
-# the same elementwise math, so it is bit-exact with the serial path;
-# DSTPU_OVERLAP=off restores the monolithic programs.
+# Comm/compute overlap (docs/scaling.md "Communication/compute overlap"):
+# stage 0 (and stage 3's replicated leaves) psums gradient leaves above
+# comm_bucket_mb in independent lane-aligned chunks; stage 3 prefetches the
+# next layer's gather.  Bit-exact with the knob off (DSTPU_OVERLAP=off).
+# Stages 1-2 build one boundary whatever the knob says.
 ZERO_OVERLAP_COMM = "overlap_comm"
 ZERO_OVERLAP_COMM_DEFAULT = True
 ZERO_COMM_BUCKET_MB = "comm_bucket_mb"

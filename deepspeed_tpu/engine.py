@@ -562,13 +562,16 @@ class DeepSpeedTpuEngine:
         # layout; ``zero_flat`` gates every flat-layout code path.
         self.zero3 = self.zero_stage == 3
         self.zero_flat = self.zero_enabled and not self.zero3
-        # -- comm/compute overlap (zero_optimization.overlap_comm): the
-        # boundary collectives split into lane-aligned buckets so XLA's
-        # async collectives overlap the shard-local update (and, at ZeRO-3,
-        # the block scan prefetches the next layer's gather).  Bucketing
-        # only re-tiles the same elementwise math — bit-exact with serial.
-        # DSTPU_OVERLAP=off is the escape hatch restoring today's exact
-        # monolithic programs (DSTPU_OVERLAP=on forces it over the config).
+        # -- comm/compute overlap (zero_optimization.overlap_comm,
+        # DSTPU_OVERLAP=on|off over the config).  What it governs, by stage:
+        # 0 (and the replicated leaves of 3): gradient leaves above
+        # comm_bucket_mb psum in independent lane-aligned chunks; 3: the
+        # block scan prefetches the next layer's gather.  Both bit-exact
+        # with the knob off.  Stages 1-2: nothing — the flat boundary is
+        # one contiguous reduce-scatter and one compute-dtype all-gather
+        # whatever the knob says (on the v5e the bucketed [group,
+        # partition] form overlapped nothing and spent most of the step
+        # re-tiling full-size buffers; PERF.md, PR 25).
         self.overlap_comm = bool(self.config.zero_overlap_comm)
         _ov = os.environ.get("DSTPU_OVERLAP", "").strip().lower()
         if _ov in ("off", "0", "false"):
@@ -579,9 +582,8 @@ class DeepSpeedTpuEngine:
             raise DeepSpeedConfigError(
                 f"DSTPU_OVERLAP={_ov!r} is not a valid mode: use 'on' or "
                 f"'off'")
-        # bucket size in fp32 elements, floored to the 128-lane tile (the
-        # flat partition is 128-padded, so aligned buckets never split a
-        # lane); comm_bucket_mb may be fractional for tiny test meshes
+        # chunk size in fp32 elements, floored to the 128-lane tile;
+        # comm_bucket_mb may be fractional for tiny test meshes
         self.comm_bucket_elems = max(
             128, (int(self.config.zero_comm_bucket_mb * (1 << 20)) // 4
                   // 128) * 128)
@@ -1488,29 +1490,16 @@ class DeepSpeedTpuEngine:
             gradient_predivide_factor=cfg.gradient_predivide_factor,
             partition_group_size=self.zero_pps,
             across_subgroups=across_subgroups)
-        bounds = self._comm_buckets()
         # the flatten belongs to the boundary wherever this is called from
         # (stage 2 calls it per micro-step, outside step_local)
         with obs_scopes.scope("boundary"):
             flat = zero_mod.flatten_tree(grads, self.flat_meta)
             with obs_scopes.scope("boundary/reduce"):
-                if bounds is not None:
-                    gpart = comm.reduce_scatter_grads_bucketed(
-                        flat, DATA_AXIS, self.dp_world_size, bounds, **knobs)
-                else:
-                    gpart = comm.reduce_scatter_grads(
-                        flat, DATA_AXIS, self.dp_world_size, **knobs)
+                gpart = comm.reduce_scatter_grads(
+                    flat, DATA_AXIS, self.dp_world_size, **knobs)
         if rows is None:
             rows = bool(self._zero_state_axes)
         return gpart[None] if rows else gpart
-
-    def _comm_buckets(self):
-        """Bucket bounds over the owned flat partition under overlap_comm
-        (None = the serial monolithic path, DSTPU_OVERLAP=off)."""
-        if not self.overlap_comm or self.flat_meta is None:
-            return None
-        return comm.bucket_bounds(self.flat_meta.partition,
-                                  self.comm_bucket_elems)
 
     #: built batch-format executables kept per engine (a training run
     #: alternating two MLM formats needs exactly two)
@@ -1982,7 +1971,6 @@ class DeepSpeedTpuEngine:
         sparse_flags = self._sparse_flags
         group_ids = self._group_ids
         multi_group = len(self._group_defs) > 1
-        bounds = self._comm_buckets()      # None = serial boundary
         bucket_elems = (self.comm_bucket_elems if self.overlap_comm
                         else None)
 
@@ -2070,87 +2058,40 @@ class DeepSpeedTpuEngine:
                             total_norm, prec.static_loss_scale_state(1.0),
                             clip)
                         if clip > 0 else 1.0)
-                @obs_scopes.scoped("boundary/update")
-                def upd_seg(mseg, gseg, oin, lr_, b1_, b2_, wd_):
-                    """Shard-local update + skip-on-overflow on one flat
-                    segment (the whole partition, or one overlap bucket —
-                    elementwise, so the tiling cannot change the values).
-                    skip-on-overflow: reference zero_optimizer.py:349-359;
-                    bf16/fp32 have no loss-scale recovery loop — a NaN
-                    propagates visibly, like the reference fp32 path."""
-                    new_p, new_o = opt.update(
-                        {"flat": mseg}, {"flat": gseg}, oin,
-                        lr=lr_, beta1=b1_, beta2=b2_, weight_decay=wd_,
+                    # shard-local update on the owned partition.
+                    # skip-on-overflow: reference zero_optimizer.py:349-359;
+                    # bf16/fp32 have no loss-scale recovery loop — a NaN
+                    # propagates visibly, like the reference fp32 path.
+                    new_p, new_opt = opt.update(
+                        {"flat": master_1d}, {"flat": gpart}, opt_in,
+                        lr=lr, beta1=b1, beta2=b2, weight_decay=wd,
                         combined_scale=combined)
-                    nm = new_p["flat"]
+                    new_master = new_p["flat"]
                     if skip_bad:
-                        nm = jnp.where(overflow, mseg, nm)
-                        new_o = jax.tree_util.tree_map(
+                        new_master = jnp.where(overflow, master_1d,
+                                               new_master)
+                        new_opt = jax.tree_util.tree_map(
                             lambda new, old: jnp.where(overflow, old, new),
-                            new_o, oin)
-                    return nm, new_o
-
-                hy_seg = (lambda h, s, e:
-                          {"flat": h["flat"][s:e]} if isinstance(h, dict)
-                          else h)
-                if bounds is not None and len(bounds) > 1:
-                    # software-pipelined boundary (overlap_comm): each
-                    # bucket's update → all-gather chain is data-independent
-                    # of every other bucket's, so XLA's async collectives
-                    # run gather(i-1) ∥ update(i) instead of one monolithic
-                    # update followed by one monolithic gather
-                    segs, blocks = [], []
-                    new_step = opt_in.step
-                    for s, e in bounds:
-                        oin = optim_mod.OptimizerState(
-                            step=opt_in.step,
-                            m=(None if opt_in.m is None
-                               else {"flat": opt_in.m["flat"][s:e]}),
-                            v=(None if opt_in.v is None
-                               else {"flat": opt_in.v["flat"][s:e]}))
-                        nm, new_o = upd_seg(
-                            master_1d[s:e], gpart[s:e], oin,
-                            hy_seg(lr, s, e), hy_seg(b1, s, e),
-                            hy_seg(b2, s, e), hy_seg(wd, s, e))
-                        segs.append((nm, new_o))
-                        with obs_scopes.scope("boundary/gather"):
-                            # weight all-gather, per bucket (reference
-                            # zero_optimizer.py:397-432)
-                            blocks.append(comm.allgather_partition_bucket(
-                                nm.astype(jnp.float32), DATA_AXIS,
-                                world_size=world, partition_group_size=pps))
-                        new_step = new_o.step
-                    with obs_scopes.scope("boundary/update"):
-                        new_master = jnp.concatenate([nm for nm, _ in segs])
-                        cat = lambda pick: {"flat": jnp.concatenate(
-                            [pick(o) for _, o in segs])}
-                        new_opt = optim_mod.OptimizerState(
-                            step=new_step,
-                            m=(None if opt_in.m is None
-                               else cat(lambda o: o.m["flat"])),
-                            v=(None if opt_in.v is None
-                               else cat(lambda o: o.v["flat"])))
-                    with obs_scopes.scope("boundary/gather"):
-                        flat_full = jnp.reshape(
-                            jnp.concatenate(blocks, axis=1), (-1,))
-                else:
-                    new_master, new_opt = upd_seg(master_1d, gpart, opt_in,
-                                                  lr, b1, b2, wd)
-                    with obs_scopes.scope("boundary/gather"):
-                        # weight all-gather (reference
-                        # zero_optimizer.py:397-432)
-                        flat_full = comm.allgather_params(
-                            new_master.astype(jnp.float32), DATA_AXIS,
-                            world_size=world, partition_group_size=pps)
+                            new_opt, opt_in)
+                    # the gather runs in the compute dtype, as the
+                    # reference gathers its fp16 partitions:
+                    # cast(gather(x)) == gather(cast(x)) element for
+                    # element, the fp32 master stays whole, and the wire
+                    # and the gathered buffer halve.  The cast is the
+                    # update's (see the replicated arm).
+                    new_part = new_master.astype(cdt)
                 with obs_scopes.scope("boundary/gather"):
+                    # weight all-gather (reference zero_optimizer.py:397-432)
+                    flat_full = comm.allgather_params(
+                        new_part, DATA_AXIS,
+                        world_size=world, partition_group_size=pps)
                     # fence the gathered buffer: left free to rewrite the
                     # all-gather together with the per-leaf slices that consume
                     # it, the TPU compiler (libtpu 0.0.34) took 930 s over
                     # BERT-large's boundary; fenced, seconds.  The gather's
                     # output is a real buffer either way.
                     params = zero_mod.unflatten_tree(
-                        jax.lax.optimization_barrier(flat_full), meta,
-                        dtype=cdt)
+                        jax.lax.optimization_barrier(flat_full), meta)
                 if zero_2d:
                     new_master = new_master[None]
                     new_opt = optim_mod.OptimizerState(

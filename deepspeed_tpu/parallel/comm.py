@@ -16,7 +16,7 @@ All functions take pytrees and an axis name; they must be called inside
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +72,13 @@ def allreduce_grads(grads,
     ``allreduce_bucket``, deepspeed_light.py:819-849; knob semantics in
     ``scaled_reduce``).  The reduction lowers to an ICI all-reduce.
 
-    ``bucket_elems`` (overlap_comm): leaves larger than this split into
-    lane-aligned chunks reduced by INDEPENDENT psums, so XLA's async
-    collectives can overlap each other and the downstream elementwise
-    update instead of serialising one monolithic reduce per giant leaf.
-    Chunking is elementwise-identical to the whole-leaf psum (same
-    addends, same per-element order), hence bit-exact."""
+    ``bucket_elems`` (overlap_comm at ZeRO stage 0, and the replicated
+    leaves of stage 3): leaves larger than this split into lane-aligned
+    chunks reduced by INDEPENDENT psums, which leaves XLA free to overlap
+    them with each other and with the elementwise update (never timed on
+    a chip: no benchmark cell runs plain DP across chips yet).  Chunking
+    is elementwise-identical to the whole-leaf psum (same addends, same
+    per-element order), hence bit-exact."""
     knobs = dict(fp32_allreduce=fp32_allreduce,
                  prescale_gradients=prescale_gradients,
                  gradient_predivide_factor=gradient_predivide_factor)
@@ -103,9 +104,8 @@ def bucket_bounds(total: int, bucket_elems: int,
                   align: int = 128) -> Tuple[Tuple[int, int], ...]:
     """Contiguous ``(start, stop)`` slices covering ``[0, total)`` with each
     bucket ``<= max(bucket_elems, align)`` elements and every boundary a
-    multiple of ``align`` (lane alignment: the ZeRO flat partition is
-    128-padded, so aligned buckets never split a lane tile).  One bucket
-    when ``bucket_elems >= total``."""
+    multiple of ``align`` (lane alignment).  One bucket when
+    ``bucket_elems >= total``.  The chunking of ``allreduce_grads``."""
     if total <= 0:
         return ((0, total),)
     step = max(align, (int(bucket_elems) // align) * align)
@@ -141,10 +141,13 @@ def reduce_scatter_grads(flat_grad: jnp.ndarray,
     partition (flat_grad length must be divisible by the partition group).
 
     The reference's ZeRO-1 reduces the *full* grad then frees non-owned slices
-    (zero_optimizer.py:370-384); the reduce-scatter formulation moves half the
-    bytes and was the reference's own roadmap item
-    (docs/_posts/2020-03-17-reduce-scatter.md).  Same scaling knobs as
-    ``allreduce_grads``.
+    (zero_optimizer.py:370-384); the reduce-scatter formulation asks for half
+    the bytes and was the reference's own roadmap item
+    (docs/_posts/2020-03-17-reduce-scatter.md).  On the v5e, libtpu 0.0.34
+    lowers it to an all-reduce of the flat buffer and a slice all the same
+    (PERF.md, PR 25: the largest row left in the boundary).  Same scaling
+    knobs as ``allreduce_grads``.  This is the ONE reduction of the ZeRO-1/2
+    boundary, on the contiguous 1-D buffer, whatever ``overlap_comm`` says.
 
     With ``partition_group_size`` g < world (ZeRO parameter_parallel_size,
     reference deepspeed_light.py:63-77) the scatter runs within each
@@ -178,70 +181,6 @@ def reduce_scatter_grads(flat_grad: jnp.ndarray,
         gradient_predivide_factor=gradient_predivide_factor)
 
 
-def reduce_scatter_grads_bucketed(flat_grad: jnp.ndarray,
-                                  axis_name: str,
-                                  world_size: int,
-                                  bounds: Sequence[Tuple[int, int]],
-                                  fp32_allreduce: bool = False,
-                                  prescale_gradients: bool = False,
-                                  gradient_predivide_factor: float = 1.0,
-                                  partition_group_size: Optional[int] = None,
-                                  across_subgroups: bool = True
-                                  ) -> jnp.ndarray:
-    """Bucketed ``reduce_scatter_grads`` (overlap_comm): the flat [padded]
-    gradient is viewed as ``[group, partition]`` (row r = rank r's owned
-    slice) and each column bucket ``[group, w]`` reduce-scatters as an
-    INDEPENDENT collective, so XLA's async scheduler can overlap the K
-    scatters with each other and with the flatten/compute that feeds them.
-
-    Bit-exact with the serial path: element ``(r, s+j)`` of the 2-D view is
-    flat element ``r*partition + s + j``, so each bucket's tiled
-    ``psum_scatter`` reduces exactly the same addends onto exactly the same
-    owner as the monolithic scatter, and concatenating the bucket outputs
-    in order reconstructs the rank's contiguous partition."""
-    pps = (world_size if partition_group_size is None
-           else int(partition_group_size))
-    if pps == world_size:
-        within = across = None
-    else:
-        within, across = subgroup_index_groups(world_size, pps)
-    part = flat_grad.shape[0] // pps
-    flat2d = jnp.reshape(flat_grad, (pps, part))
-
-    def reduce_fn(x):
-        p = lax.psum_scatter(x, axis_name, scatter_dimension=0, tiled=True,
-                             axis_index_groups=within)
-        if across is not None and across_subgroups:
-            p = lax.psum(p, axis_name, axis_index_groups=across)
-        return p
-
-    parts = [scaled_reduce(
-        flat2d[:, s:e], reduce_fn, world_size,
-        fp32_allreduce=fp32_allreduce,
-        prescale_gradients=prescale_gradients,
-        gradient_predivide_factor=gradient_predivide_factor)[0]
-        for s, e in bounds]
-    return jnp.concatenate(parts)
-
-
-def allgather_partition_bucket(bucket: jnp.ndarray, axis_name: str,
-                               world_size: Optional[int] = None,
-                               partition_group_size: Optional[int] = None
-                               ) -> jnp.ndarray:
-    """All-gather ONE updated-weight bucket (a ``[w]`` slice of the owned
-    partition) into its ``[group, w]`` block — the bucketed counterpart of
-    ``allgather_params``.  The caller reassembles the full flat buffer with
-    ``concatenate(blocks, axis=1).reshape(-1)``: block column ``(r, s+j)``
-    is flat element ``r*partition + s + j``, the serial gather's layout."""
-    if (partition_group_size is None or world_size is None
-            or partition_group_size == world_size):
-        within = None
-    else:
-        within, _ = subgroup_index_groups(world_size, partition_group_size)
-    return lax.all_gather(bucket[None], axis_name, axis=0, tiled=True,
-                          axis_index_groups=within)
-
-
 def finish_subgroup_reduce(partition: jnp.ndarray, axis_name: str,
                            world_size: int,
                            partition_group_size: int) -> jnp.ndarray:
@@ -258,7 +197,8 @@ def allgather_params(partition: jnp.ndarray, axis_name: str,
                      partition_group_size: Optional[int] = None
                      ) -> jnp.ndarray:
     """Gather updated weight partitions from all DP ranks (flat, tiled) —
-    the ZeRO-1 weight allgather (reference zero_optimizer.py:397-432).
+    the ZeRO-1 weight allgather (reference zero_optimizer.py:397-432), in
+    whatever dtype the caller hands over (the engine: the compute dtype).
     With ``partition_group_size`` the gather stays within each sub-group
     (each block of g ranks already holds all g sub-partitions)."""
     if (partition_group_size is None or world_size is None

@@ -50,10 +50,20 @@ class FlatMeta(NamedTuple):
     partition: int        # padded // dp
 
 
-def make_flat_meta(params, dp_size: int, align: int = 128) -> FlatMeta:
-    """Compute the flatten layout.  ``align=128`` keeps every partition
-    lane-aligned for the MXU/VPU (the reference aligns to the DP world size
-    only, zero_optimizer.py:20-41; 128 additionally keeps XLA tiling clean)."""
+#: Elements per tile of a 1-D array on the TPU, in every dtype the boundary
+#: moves (f32 ``T(1024)``, bf16/fp16 ``T(1024)(128)(2,1)``).  A partition of
+#: whole tiles is what lets each rank's piece of a collective land in place:
+#: with 128 (a lane, not a tile) libtpu 0.0.34 compiled the weight all-gather
+#: into ``[group, 1, partition]`` and reached the flat buffer from there
+#: through re-tiling copies and unaligned ``dynamic-update-slice`` loops
+#: (PERF.md, PR 25).
+FLAT_ALIGN = 1024
+
+
+def make_flat_meta(params, dp_size: int, align: int = FLAT_ALIGN) -> FlatMeta:
+    """Compute the flatten layout.  Every partition is a whole number of
+    the TPU's 1-D tiles (``FLAT_ALIGN``; the reference aligns to the DP
+    world size only, zero_optimizer.py:20-41)."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     shapes = tuple(tuple(l.shape) for l in leaves)
     return _meta_from_shapes(treedef, shapes, dp_size, align)
@@ -142,7 +152,7 @@ def _local_shape(shape, spec, axis_sizes) -> Tuple[int, ...]:
 
 
 def make_local_flat_meta(params, specs, axis_sizes, dp_size: int,
-                         align: int = 128) -> FlatMeta:
+                         align: int = FLAT_ALIGN) -> FlatMeta:
     """Flatten layout of the LOCAL (per-model-shard) parameter slices.
 
     Under ZeRO x tensor parallelism the reference partitions optimizer state
@@ -252,23 +262,18 @@ def flatten_tree(tree, meta: FlatMeta, dtype=jnp.float32) -> jnp.ndarray:
     return flat
 
 
-def unflatten_tree(flat: jnp.ndarray, meta: FlatMeta, dtype=None):
+def unflatten_tree(flat: jnp.ndarray, meta: FlatMeta):
     """Split a flat [padded] vector back into the original pytree (jit-safe).
     Equivalent of re-viewing model params into the flat buffer
-    (zero_optimizer.py:146-149).
-
-    With a ``dtype`` the cast runs on the 1-D slice and an optimization
-    barrier keeps XLA from fusing it with the reshape: the TPU compiler
-    (libtpu 0.0.34) spends ~0.5 ms PER ROW compiling a fused slice →
-    reshape → down-cast when the slice offset is a multiple of the row
-    width (25 s for a 2-layer BERT-large tree), and XLA's own cost
-    analysis counts the same bytes moved either way."""
+    (zero_optimizer.py:146-149).  No cast here: the ZeRO boundary gathers
+    in the compute dtype, so each leaf is one slice and one reshape (a
+    down-cast fused between the two cost the TPU compiler, libtpu 0.0.34,
+    ~0.5 ms PER ROW of compile time at aligned offsets: 25 s for a 2-layer
+    BERT-large tree)."""
     out = []
     offset = 0
     for shape, size in zip(meta.shapes, meta.sizes):
         piece = jax.lax.dynamic_slice_in_dim(flat, offset, size)
-        if dtype is not None:
-            piece = jax.lax.optimization_barrier(piece.astype(dtype))
         out.append(jnp.reshape(piece, shape))
         offset += size
     return meta.treedef.unflatten(out)

@@ -601,8 +601,8 @@ def test_divergent_bucket_shapes_detected():
     two rank-divergent branches issuing the SAME primitive over the same
     axis but with DIFFERENT bucket tilings are a real deadlock — ranks in
     either branch would block exchanging mismatched buffers.  This is the
-    failure class the overlap_comm bucketed boundary could introduce if a
-    schedule ever bucketed per-branch."""
+    failure class the overlap_comm chunked psums (comm.allreduce_grads)
+    could introduce if a schedule ever chunked per-branch."""
     def bad(x):
         r = lax.axis_index("data")
 

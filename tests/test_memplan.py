@@ -139,10 +139,10 @@ def test_parity_mlp_stage0():
     _assert_parity(eng, _mlp_batch(_full_batch_size(eng)), "mlp stage0")
 
 
-#: overlap_comm=False in the parity matrix: at toy scale the stage-1/2
-#: bucketed boundary compiles to the identical single-bucket program, and
-#: the ZeRO-3 paired-gather prefetch is pinned separately (its own
-#: parity cell + the computed-envelope assertions below)
+#: overlap_comm=False in the parity matrix: the stage-1/2 boundary is one
+#: program whatever the knob says, and the ZeRO-3 paired-gather prefetch
+#: is pinned separately (its own parity cell + the computed-envelope
+#: assertions below)
 @pytest.mark.parametrize("stage,remat", [
     (0, False), (0, True), (1, False), (1, True),
     (2, False), (2, True), (3, False), (3, True)])

@@ -80,10 +80,10 @@ def test_zero1_optimizer_state_partition_ratio():
     # replicated: every device holds full fp32 master + m + v = 12 bytes/p
     assert repl_opt == 12 * n, (repl_opt, n)
     # ZeRO-1: each device holds its 1/dp partition of all three buffers;
-    # the flat layout pads to a multiple of dp*128 elements
+    # the flat layout pads to a multiple of dp*1024 elements (zero.FLAT_ALIGN)
     padded = zero.flat_meta.padded
     assert zero_opt == 12 * padded // dp, (zero_opt, padded)
-    assert zero_opt <= repl_opt / dp + 12 * 128  # ratio holds past padding
+    assert zero_opt <= repl_opt / dp + 12 * 1024  # ratio holds past padding
 
     # compute params are replicated in BOTH engines (ZeRO-1 partitions
     # optimizer state only — stage-1 semantics, zero.py docstring)
@@ -100,7 +100,7 @@ def test_pps_subgroups_trade_memory_for_gather_locality():
     sub = make_engine(zero={"stage": 1, "parameter_parallel_size": 4})
     b_full = opt_state_bytes(full, dev)
     b_sub = opt_state_bytes(sub, dev)
-    # partition size scales with 1/pps; padding differs (dp*128 vs pps*128)
+    # partition size scales with 1/pps; padding differs (dp*1024 vs pps*1024)
     assert b_sub == 12 * sub.flat_meta.padded // 4
     assert abs(b_sub - 2 * b_full) <= 12 * 512
 
