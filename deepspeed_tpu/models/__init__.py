@@ -15,6 +15,7 @@ from deepspeed_tpu.models.gpt2_moe import GPT2MoE, GPT2MoEPipelined
 from deepspeed_tpu.models.moe import MoEConfig
 from deepspeed_tpu.models.bert import (BertForPreTraining,
                                        BertForQuestionAnswering, BERT_SIZES)
+from deepspeed_tpu.models.looped import LoopedConfig, LoopedLM, LOOPED_SIZES
 
 __all__ = [
     "TransformerConfig", "init_block_params", "block_partition_specs",
@@ -22,4 +23,5 @@ __all__ = [
     "GPT2", "GPT2_SIZES",
     "GPT2Pipelined", "GPT2MoE", "GPT2MoEPipelined", "MoEConfig",
     "BertForPreTraining", "BertForQuestionAnswering", "BERT_SIZES",
+    "LoopedConfig", "LoopedLM", "LOOPED_SIZES",
 ]

@@ -340,6 +340,51 @@ def gelu(x):
     return y.astype(x.dtype)
 
 
+@S.scoped("norm")
+def rms_norm(x, scale, eps=1e-6):
+    """RMSNorm: ``x / sqrt(mean(x^2) + eps) * scale``, the statistic in
+    fp32 (no mean subtraction, no offset)."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def silu(x):
+    xf = x.astype(jnp.float32)
+    return (xf * jax.nn.sigmoid(xf)).astype(x.dtype)
+
+
+@S.scoped("rope")
+def rotary_tables(t_local, head_dim, theta):
+    """``(cos, sin)``, each fp32 ``[t_local, head_dim]``, of the rotary
+    position embedding for THIS sequence shard (global offset
+    ``seq_index * t_local`` under context parallelism, like
+    ``seq_shard_positions``): frequency ``theta^(-2i/d)`` for the pair
+    ``(i, i + d/2)`` — the "rotate-half" pairing."""
+    pos0 = (jax.lax.axis_index(SEQ_AXIS) * t_local
+            if axis_size_or_1(SEQ_AXIS) > 1 else 0)
+    pos = (pos0 + jnp.arange(t_local)).astype(jnp.float32)
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                / head_dim))
+    angles = pos[:, None] * inv_freq[None, :]               # [T, d/2]
+    angles = jnp.concatenate([angles, angles], axis=-1)     # [T, d]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+@S.scoped("rope")
+def apply_rotary(x, rope):
+    """Rotate ``x`` [B, T, n, d] by its position: ``x cos + rotate_half(x)
+    sin`` with ``rotate_half(x) = (-x[d/2:], x[:d/2])``; the arithmetic in
+    fp32, the result in ``x``'s dtype."""
+    cos, sin = rope
+    xf = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+    y = xf * cos[None, :, None, :] + rotated * sin[None, :, None, :]
+    return y.astype(x.dtype)
+
+
 # --------------------------------------------------------------- serving
 # int8 weight-only quantization (deepspeed_tpu/inference/): weights are
 # stored as {"q": int8, "s": per-output-channel scale} subtrees, and the
@@ -641,3 +686,32 @@ def multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local, proj_b,
         ctx = core_attention(q, k, v, causal=causal, attn_mask=attn_mask)
     ctx = ctx.reshape(B, T, n_local * d)                        # [B,T,h/mp]
     return row_parallel_linear(ctx, proj_w_local, proj_b, axis=axis)
+
+
+@S.scoped("attn")
+def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
+                               rope, *, head_dim, causal, attn_mask=None,
+                               axis=MODEL_AXIS):
+    """Tensor-parallel multi-head attention with separate, bias-free
+    projections and rotary positions on q and k.
+
+    x:        [B, T, h] replicated over ``model``
+    wq/wk/wv: [h, n*d/mp]  column-parallel, heads contiguous (a shard holds
+              whole heads)
+    wo_local: [n*d/mp, h]  row-parallel output projection
+    rope:     ``rotary_tables(T, head_dim, theta)`` of this sequence shard
+
+    Same ``core_attention`` dispatch as ``multihead_attention``.  Under
+    context parallelism the rotated k/v go round the ring (positions are
+    already in them); Ulysses is not wired for this block."""
+    B, T, _ = x.shape
+    q, k, v = (checkpoint_name(column_parallel_linear(x, w), "qkv")
+               .reshape(B, T, -1, head_dim)
+               for w in (wq_local, wk_local, wv_local))
+    q, k = apply_rotary(q, rope), apply_rotary(k, rope)
+    if axis_size_or_1(SEQ_AXIS) > 1:
+        from deepspeed_tpu.models.ring_attention import ring_attention
+        ctx = ring_attention(q, k, v, causal=causal, kv_mask=attn_mask)
+    else:
+        ctx = core_attention(q, k, v, causal=causal, attn_mask=attn_mask)
+    return row_parallel_linear(ctx.reshape(B, T, -1), wo_local, axis=axis)

@@ -72,7 +72,7 @@ def init_moe_block_params(cfg: MoEConfig, rng) -> dict:
     for k in ("fc_w", "fc_b", "fc2_w", "fc2_b"):
         del base[k]
     Lyr, h, E = cfg.num_layers, cfg.hidden_size, cfg.num_experts
-    ff = cfg.mlp_ratio * h
+    ff = cfg.ffn_width
     ks = jax.random.split(jax.random.fold_in(rng, 17), 3)
     std = cfg.init_std
     resid_std = std / jnp.sqrt(2.0 * Lyr)

@@ -56,6 +56,9 @@ class TransformerConfig:
     num_layers: int = 12
     num_heads: int = 12
     mlp_ratio: int = 4
+    #: FFN width where it is no whole multiple of the hidden size; None:
+    #: ``mlp_ratio * hidden_size`` (see ``ffn_width``)
+    ffn_size: Optional[int] = None
     pre_ln: bool = True           # GPT-2 pre-LN; BERT uses post-LN
     causal: bool = True
     remat: bool = True            # per-block activation checkpointing
@@ -70,6 +73,11 @@ class TransformerConfig:
     init_std: float = 0.02
     ln_eps: float = 1e-5
 
+    @property
+    def ffn_width(self) -> int:
+        return (self.ffn_size if self.ffn_size is not None
+                else self.mlp_ratio * self.hidden_size)
+
     def validate(self, mp_size: int = 1):
         h, n = self.hidden_size, self.num_heads
         if h % n:
@@ -79,13 +87,16 @@ class TransformerConfig:
         if self.vocab_size % mp_size:
             raise ValueError(
                 f"vocab {self.vocab_size} not divisible by mp {mp_size}")
+        if self.ffn_width % mp_size:
+            raise ValueError(
+                f"FFN width {self.ffn_width} not divisible by mp {mp_size}")
 
 
 def init_block_params(cfg: TransformerConfig, rng) -> dict:
     """Stacked [L, ...] block parameters, GPT-2 style init (normal 0.02;
     residual projections scaled by 1/sqrt(2L))."""
     Lyr, h = cfg.num_layers, cfg.hidden_size
-    ff = cfg.mlp_ratio * h
+    ff = cfg.ffn_width
     ks = jax.random.split(rng, 4)
     std = cfg.init_std
     resid_std = std / jnp.sqrt(2.0 * Lyr)
@@ -164,7 +175,74 @@ def block_apply(x, p, cfg: TransformerConfig, attn_mask=None):
     return x
 
 
-def remat_wrap(body, cfg: TransformerConfig):
+# ------------------------------------------------------ sandwich block
+# RMSNorm / rotary / SwiGLU block with a norm before AND after each
+# sub-layer, inside the residual (models/looped.py runs it).  ``cfg`` is any
+# config with ``hidden_size``, ``num_layers``, ``num_heads``, ``head_dim``,
+# ``ffn_size``, ``norm_eps``, ``init_std`` and the ``remat`` fields
+# ``remat_wrap`` reads.
+
+def init_sandwich_block_params(cfg, rng) -> dict:
+    """Stacked [L, ...] parameters of the sandwich block: four norm scales,
+    separate bias-free q/k/v/o projections (heads contiguous), and the gated
+    FFN's gate, up and down matrices.  Normal ``init_std`` for every matrix:
+    the norm after each sub-layer rescales its output, so the 1/sqrt(2L) of
+    ``init_block_params`` on the residual projections would shrink nothing
+    but the weights themselves — and make them the ones an optimizer's
+    first steps swamp (PERF.md, PR 26)."""
+    Lyr, h, ff = cfg.num_layers, cfg.hidden_size, cfg.ffn_size
+    nd = cfg.num_heads * cfg.head_dim
+    ks = jax.random.split(rng, 7)
+    norm = lambda k, shape: (
+        jax.random.normal(k, shape, jnp.float32) * cfg.init_std)
+    ones = lambda: jnp.ones((Lyr, h), jnp.float32)
+    return {
+        "norm1_s": ones(), "norm2_s": ones(),
+        "norm3_s": ones(), "norm4_s": ones(),
+        "q_w": norm(ks[0], (Lyr, h, nd)),
+        "k_w": norm(ks[1], (Lyr, h, nd)),
+        "v_w": norm(ks[2], (Lyr, h, nd)),
+        "o_w": norm(ks[3], (Lyr, nd, h)),
+        "gate_w": norm(ks[4], (Lyr, h, ff)),
+        "up_w": norm(ks[5], (Lyr, h, ff)),
+        "down_w": norm(ks[6], (Lyr, ff, h)),
+    }
+
+
+def sandwich_block_partition_specs() -> dict:
+    """Megatron sharding of the sandwich block: q/k/v/gate/up
+    column-parallel, o/down row-parallel, norm scales replicated."""
+    col, row = P(None, None, MODEL_AXIS), P(None, MODEL_AXIS, None)
+    return {
+        "norm1_s": P(), "norm2_s": P(), "norm3_s": P(), "norm4_s": P(),
+        "q_w": col, "k_w": col, "v_w": col, "o_w": row,
+        "gate_w": col, "up_w": col, "down_w": row,
+    }
+
+
+@S.scoped("ffn")
+def _gated_mlp(x, p):
+    """SwiGLU: ``(silu(x Wg) * (x Wu)) Wd``, no biases."""
+    # named like ``_mlp``'s pre-activation for the "selective" policy
+    g = checkpoint_name(L.column_parallel_linear(x, p["gate_w"]), "ffn1")
+    u = checkpoint_name(L.column_parallel_linear(x, p["up_w"]), "ffn1")
+    return L.row_parallel_linear(L.silu(g) * u, p["down_w"])
+
+
+def sandwich_block_apply(x, p, cfg, rope, attn_mask=None):
+    """``a = x + norm2(attn(norm1(x)))``, ``a + norm4(ffn(norm3(a)))`` on
+    local shards; ``rope`` = ``layers.rotary_tables`` of this shard."""
+    eps = cfg.norm_eps
+    a = L.rotary_multihead_attention(
+        L.rms_norm(x, p["norm1_s"], eps), p["q_w"], p["k_w"], p["v_w"],
+        p["o_w"], rope, head_dim=cfg.head_dim, causal=True,
+        attn_mask=attn_mask)
+    x = x + L.rms_norm(a, p["norm2_s"], eps)
+    f = _gated_mlp(L.rms_norm(x, p["norm3_s"], eps), p)
+    return x + L.rms_norm(f, p["norm4_s"], eps)
+
+
+def remat_wrap(body, cfg):
     """Apply the configured per-block rematerialisation policy to a scan
     body (shared by the dense and MoE stacks)."""
     if not cfg.remat:
@@ -251,8 +329,8 @@ _sched_barrier.defvjp(_sched_barrier_fwd, _sched_barrier_bwd)
 
 
 @S.scoped("block")
-def scan_layers(body, carry, stacked_params, cfg: TransformerConfig,
-                z3_dims=None, z3_prefetch=False):
+def scan_layers(body, carry, stacked_params, cfg, z3_dims=None,
+                z3_prefetch=False):
     """``lax.scan`` of ``body(carry, layer_params) -> (carry, y)`` over the
     stacked [L, ...] layers, with the ZeRO-3 per-layer gather when
     ``z3_dims`` marks partitioned leaves.  Shared by the dense and MoE

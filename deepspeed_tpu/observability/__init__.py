@@ -169,6 +169,11 @@ class Telemetry:
         self.registry.register("samples", self._samples_source)
         self.registry.register("observability",
                                detectors.COUNTERS.as_dict)
+        # what the model declares its step is made of (a looped model: the
+        # passes, exits and layer applications behind ``dstpu/loop``);
+        # models that declare nothing have no group
+        if callable(getattr(engine.module, "step_counts", None)):
+            self.registry.register("model", self._model_source)
 
         # spool (report_window >= 1)
         self.spool: Optional[MetricSpool] = None
@@ -249,6 +254,16 @@ class Telemetry:
     def _live_writer(self):
         engine = self._engine_ref()
         return engine.summary_writer if engine is not None else None
+
+    def _model_source(self) -> dict:
+        engine = self._engine_ref()
+        if engine is None:
+            return {}
+        counts = dict(engine.module.step_counts())
+        counts["layer_applications_per_step"] = (
+            counts.pop("layer_applications")
+            * engine.gradient_accumulation_steps())
+        return counts
 
     def _samples_source(self) -> dict:
         engine = self._engine_ref()

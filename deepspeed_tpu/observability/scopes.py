@@ -41,6 +41,9 @@ PREFIX = "dstpu/"
 SCOPES = (
     "embed", "block", "attn", "ffn", "norm", "head",
     "boundary", "boundary/reduce", "boundary/update", "boundary/gather",
+    # a looped (weight-shared depth) model: the pass loop, the rotation of
+    # q and k inside ``attn``, and the exit gate's part of the loss
+    "loop", "rope", "exit",
 )
 
 FORWARD, BACKWARD, REPLAY = "forward", "backward", "replay"
