@@ -32,12 +32,14 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.observability import scopes as S
+from deepspeed_tpu.ops.remat_names import QKV
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, SEQ_AXIS
 
 # Pallas attention dispatch (DSTPU_FUSED_ATTN = "auto" | "1" | "0").
-# Measured on a v5e chip, END-TO-END training step (12-layer model,
-# selective remat — the remat replay doubles attention's share, so these
-# are the numbers that matter for users; bench_attn_sweep.json r4/r5):
+# Measured on a v5e chip, END-TO-END training step (12-layer model, under
+# a selective remat that replayed the kernel's forward in the backward
+# pass — today's keeps its output, PERF.md PR 27, so these ratios are
+# upper bounds; bench_attn_sweep.json r4/r5):
 #   GPT-2 causal:   kernel 1.127x @128 (whole-tile — streaming needs a
 #                   256 tile), 1.18x @512, 1.87x @1024, 2.44x @2048,
 #                   3.21x @4096
@@ -657,9 +659,12 @@ def multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local, proj_b,
     B, T, h = x.shape
     d = h // n_heads_global
     qkv = column_parallel_linear(x, qkv_w_local, qkv_b_local)  # [B,T,3h/mp]
-    # named for the "selective" remat policy: saving qkv lets backward
-    # recompute attention (cheap einsums) without replaying the qkv matmul
-    qkv = checkpoint_name(qkv, "qkv")
+    # named for the "selective" remat policy (ops/remat_names.py): backward
+    # re-derives q, k, v from the saved qkv by a slice and a layout copy,
+    # no qkv matmul.  The streaming kernel's output and log-sum-exp carry
+    # names of their own, so its forward is not replayed either; the XLA
+    # path's einsums and softmax are.
+    qkv = checkpoint_name(qkv, QKV)
     n_local = qkv.shape[-1] // (3 * d)
     qkv = qkv.reshape(B, T, n_local, 3, d)
 
@@ -705,7 +710,7 @@ def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
     context parallelism the rotated k/v go round the ring (positions are
     already in them); Ulysses is not wired for this block."""
     B, T, _ = x.shape
-    q, k, v = (checkpoint_name(column_parallel_linear(x, w), "qkv")
+    q, k, v = (checkpoint_name(column_parallel_linear(x, w), QKV)
                .reshape(B, T, -1, head_dim)
                for w in (wq_local, wk_local, wv_local))
     q, k = apply_rotary(q, rope), apply_rotary(k, rope)

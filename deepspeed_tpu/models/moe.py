@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.ops.remat_names import FFN1
 from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
 
@@ -183,7 +184,7 @@ def moe_ffn(x, p, cfg: MoEConfig, axis=MODEL_AXIS, valid=None):
     ein = ein.astype(x.dtype)                                  # [e, C, h]
     y = jnp.einsum("ech,ehf->ecf", ein, p["exp1_w"].astype(x.dtype))
     y = y + p["exp1_b"].astype(y.dtype)[:, None, :]
-    y = checkpoint_name(y, "ffn1")
+    y = checkpoint_name(y, FFN1)
     y = L.gelu(y)
     y = jnp.einsum("ecf,efh->ech", y, p["exp2_w"].astype(y.dtype))
     y = y + p["exp2_b"].astype(y.dtype)[:, None, :]

@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu import zero3 as Z
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.observability import scopes as S
+from deepspeed_tpu.ops.remat_names import FFN1, POST_LN_SUM, SELECTIVE_SAVES
 from deepspeed_tpu.parallel.topology import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
 
@@ -69,6 +70,8 @@ class TransformerConfig:
     # "full": recompute everything in backward (max memory savings, ~33%
     # extra FLOPs).  "dots": save matmul outputs, recompute only cheap
     # elementwise/softmax/LN — the usual TPU sweet spot when HBM allows.
+    # "selective": save the named residuals of ops/remat_names.py (what
+    # costs a matmul or a kernel call to replay), a fraction of "dots"' bytes.
     remat_policy: str = "full"
     init_std: float = 0.02
     ln_eps: float = 1e-5
@@ -137,8 +140,8 @@ def block_partition_specs() -> dict:
 def _mlp(x, p):
     y = L.column_parallel_linear(x, p["fc_w"], p["fc_b"])
     # named for the "selective" remat policy: saving the pre-GELU ffn lets
-    # backward recompute only the elementwise GELU, no matmul replay
-    y = checkpoint_name(y, "ffn1")
+    # backward recompute the elementwise GELU without the fc matmul
+    y = checkpoint_name(y, FFN1)
     y = L.gelu(y)
     return L.row_parallel_linear(y, p["fc2_w"], p["fc2_b"])
 
@@ -163,9 +166,14 @@ def block_with_ffn(x, p, cfg: TransformerConfig, attn_mask=None, ffn=None):
         delta, aux = f(ln2(x), p)
         x = x + delta
     else:  # post-LN (BERT)
-        x = ln1(x + attn(x))
+        # a LayerNorm's backward needs its input, here the output of the
+        # proj / fc2 matmul plus the residual: named, so the "selective"
+        # replay stops at the sum and re-derives only the statistics.  In
+        # the pre-LN branch the same sums are the carried state, which the
+        # layer scan keeps anyway.
+        x = ln1(checkpoint_name(x + attn(x), POST_LN_SUM))
         delta, aux = f(x, p)
-        x = ln2(x + delta)
+        x = ln2(checkpoint_name(x + delta, POST_LN_SUM))
     return x, aux
 
 
@@ -224,8 +232,8 @@ def sandwich_block_partition_specs() -> dict:
 def _gated_mlp(x, p):
     """SwiGLU: ``(silu(x Wg) * (x Wu)) Wd``, no biases."""
     # named like ``_mlp``'s pre-activation for the "selective" policy
-    g = checkpoint_name(L.column_parallel_linear(x, p["gate_w"]), "ffn1")
-    u = checkpoint_name(L.column_parallel_linear(x, p["up_w"]), "ffn1")
+    g = checkpoint_name(L.column_parallel_linear(x, p["gate_w"]), FFN1)
+    u = checkpoint_name(L.column_parallel_linear(x, p["up_w"]), FFN1)
     return L.row_parallel_linear(L.silu(g) * u, p["down_w"])
 
 
@@ -251,12 +259,16 @@ def remat_wrap(body, cfg):
         return jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_saveable)
     if cfg.remat_policy == "selective":
-        # save qkv + pre-GELU ffn (named in layers/_mlp/moe_ffn): backward
-        # replays no matmuls, only the attention einsums and elementwise ops
+        # keep what costs a matmul or a kernel call to replay and is no
+        # larger than the FFN's hidden state (ops/remat_names.py lists the
+        # names and their bytes; layers / _mlp / moe_ffn / block_with_ffn /
+        # the streaming kernel tag them).  Backward replays elementwise ops,
+        # norms, layout copies and, on the XLA attention path, the score
+        # einsums and softmax; in a pre-LN block also the proj matmul.
         return jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "qkv", "ffn1"))
+                *SELECTIVE_SAVES))
     if cfg.remat_policy == "full":
         return jax.checkpoint(body)
     raise ValueError(

@@ -11,12 +11,15 @@ traffic per layer that never needed to leave the chip.  Two kernels:
   score tile fits on chip (short sequences).
 * ``stream_attention`` — flash-attention-style ONLINE-SOFTMAX streaming
   over KV tiles for long sequences (gate: ``stream_supported``).  Measured
-  on a v5e chip END-TO-END (GPT-2 training step, selective remat, causal
-  bf16; bench_attn_sweep.json): 1.14x at seq 512, 1.86x at 1024, 2.44x
-  at 2048 — the remat replay doubles attention's share, so the end-to-end
-  win exceeds the isolated fwd+bwd microbenchmark.  ``models/layers.py``
-  auto-dispatches from ``stream_auto_min(causal)`` tokens (512 causal /
-  1024 non-causal on v5e).
+  on a v5e chip END-TO-END (GPT-2 training step, causal bf16;
+  bench_attn_sweep.json): 1.14x at seq 512, 1.86x at 1024, 2.44x at 2048
+  — under the ``selective`` policy of that time, which ran the forward
+  kernel again in the backward pass.  Its output and log-sum-exp now carry
+  checkpoint names (``_name_stream_residuals``) and ``selective`` keeps
+  them, so a layer costs two kernel calls, not three, and the ratios
+  above overstate today's.  ``models/layers.py`` auto-dispatches from
+  ``stream_auto_min(causal)`` tokens (512 causal / 1024 non-causal on
+  v5e).
 
 Numerics: scores and probabilities are fp32 (max-subtracted softmax); the
 probability·V contraction runs in the input dtype (bf16 on TPU) with fp32
@@ -35,8 +38,11 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.remat_names import ATTN_LSE, ATTN_OUT
 
 # fp32 score-tile budget per program; several such tiles are live in the
 # backward kernel, so keep a healthy margin under the ~16 MB VMEM
@@ -528,11 +534,27 @@ def stream_attention(q, k, v, attn_mask, causal: bool = False,
     return _unfold_gtd(o, B, n)
 
 
+def _name_stream_residuals(out, lse):
+    """Tag the attention output ``[B, T, n, d]`` and the forward kernel's
+    log-sum-exp ``[G, 1, T]`` for the ``selective`` recomputation policy.
+    A ``custom_vjp``'s residuals are saveable under
+    ``save_only_these_names`` only by name; with both saved, the forward
+    ``pallas_call`` of the rematerialised computation has no consumer and
+    is dropped.  The output is named unfolded, as the projection reads it,
+    and the backward kernel's folded operand is re-derived from it by one
+    layout copy: saved folded ``[G, T, d]``, d = 64 is padded to the
+    128-lane tile and takes twice the HBM, and the copy the other way is
+    needed for the projection's weight gradient anyway (measured both ways
+    on the chip: PERF.md, PR 27)."""
+    return checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
+
+
 def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret):
     B, T, n, d = q.shape
     o, lse, (qg, kg, vg, maskg) = _stream_fwd_impl(q, k, v, attn_mask,
                                                    causal, interpret)
-    return _unfold_gtd(o, B, n), (qg, kg, vg, maskg, o, lse, B, n)
+    out, lse = _name_stream_residuals(_unfold_gtd(o, B, n), lse)
+    return out, (qg, kg, vg, maskg, _fold_gtd(out), lse, B, n)
 
 
 def _stream_bwd_mode() -> str:
@@ -745,31 +767,30 @@ def _dispatch_vjp_fwd(q, k, v, attn_mask, causal, fwd_impl, bwd_impl,
     _check_impls(fwd_impl, bwd_impl)
     B, T, n, d = q.shape
     need_stream_res = bwd_impl == "stream"
-    extra = None
+    lse = None
     if fwd_impl == "stream":
         o, lse, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret)
         out = _unfold_gtd(o, B, n)
-        if need_stream_res:
-            extra = (o, lse)
     elif fwd_impl == "block":
         out = _fwd(q, k, v, attn_mask, causal, interpret)
     else:
         out, lse = xla_attention(q, k, v, attn_mask, causal,
-                            with_lse=need_stream_res)
-        if need_stream_res:
-            extra = (_fold_gtd(out), lse)
-    return out, (q, k, v, attn_mask, extra)
+                                 with_lse=need_stream_res)
+    if need_stream_res:
+        out, lse = _name_stream_residuals(out, lse)
+    return out, (q, k, v, attn_mask,
+                 (out, lse) if need_stream_res else None)
 
 
 def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, res, g):
     q, k, v, attn_mask, extra = res
     B, T, n, d = q.shape
     if bwd_impl == "stream":
-        o, lse = extra
+        out, lse = extra
         dq, dk, dv = _stream_bwd_impl(
             _fold_gtd(q), _fold_gtd(k), _fold_gtd(v),
-            _mask_gtd(attn_mask, B, T, n), o, lse, _fold_gtd(g),
-            causal, interpret)
+            _mask_gtd(attn_mask, B, T, n), _fold_gtd(out), lse,
+            _fold_gtd(g), causal, interpret)
         dq, dk, dv = (_unfold_gtd(x, B, n) for x in (dq, dk, dv))
     elif bwd_impl == "block":
         dq, dk, dv = _block_bwd_impl(q, k, v, attn_mask, g, causal,

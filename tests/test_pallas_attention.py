@@ -7,12 +7,15 @@ against a plain-JAX reference for every mask mode, plus the shape gate.
 
 import dataclasses
 import functools
+import re
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.transformer import remat_wrap
 from deepspeed_tpu.ops import pallas_attention as pattn
 
 B, T, N, D = 4, 32, 2, 16
@@ -355,6 +358,50 @@ def test_dispatch_rejects_block_then_stream():
                                  True)
     with pytest.raises(ValueError, match="impls must be one of"):
         pattn.dispatch_attention(q, k, v, mask, False, "nope", "xla", True)
+
+
+# ------------------------------------- residuals named for selective remat
+
+def grad_jaxpr_under(policy, attention):
+    """jaxpr text of the gradient of ``attention(q, k, v)`` as a scan body
+    wrapped by ``transformer.remat_wrap`` under ``policy``."""
+    body = remat_wrap(lambda c, _: (attention(c, 2.0 * c, 3.0 * c), None),
+                      types.SimpleNamespace(remat=True, remat_policy=policy))
+    q, _, _ = stream_qkv(seed=9)
+    text = str(jax.make_jaxpr(
+        jax.grad(lambda q: jnp.sum(body(q, None)[0])))(q))
+    return lambda prim: len(re.findall(rf"\b{prim}\b", text))
+
+
+ONES = jnp.ones((2, ST), jnp.float32)
+NAMED_KERNELS = {
+    "stream_attention": lambda q, k, v: pattn.stream_attention(
+        q, k, v, ONES, True, True),
+    "dispatch-stream-stream": lambda q, k, v: pattn.dispatch_attention(
+        q, k, v, ONES, True, "stream", "stream", True),
+}
+
+
+@pytest.mark.parametrize("policy,calls", [("selective", 2), ("full", 3)])
+@pytest.mark.parametrize("kernel", sorted(NAMED_KERNELS))
+def test_selective_remat_drops_the_replayed_forward_kernel(kernel, policy,
+                                                           calls):
+    """The forward kernel's output and log-sum-exp carry checkpoint names,
+    so under ``selective`` the backward pass holds the forward and the fused
+    backward ``pallas_call`` and no replay of the forward; ``full`` saves
+    nothing and runs it again."""
+    count = grad_jaxpr_under(policy, NAMED_KERNELS[kernel])
+    assert count("pallas_call") == calls
+
+
+def test_selective_remat_keeps_an_xla_forward_for_a_stream_backward():
+    """Mixed plan ("xla", "stream"): the same two names save the einsum
+    forward's output and log-sum-exp, so its two matmuls are not replayed."""
+    mixed = lambda q, k, v: pattn.dispatch_attention(
+        q, k, v, ONES, True, "xla", "stream", True)
+    sel, full = (grad_jaxpr_under(p, mixed) for p in ("selective", "full"))
+    assert sel("pallas_call") == full("pallas_call") == 1
+    assert full("dot_general") - sel("dot_general") == 2
 
 
 def test_attention_plan_directions(monkeypatch):
