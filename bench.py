@@ -23,8 +23,6 @@ also write the JSON line to a file (committed sweep artifacts),
 BENCH_PP_SWEEP=1 with BENCH_PP_SCHEDULES=gpipe,1f1b for the pipeline
 schedule sweep, BENCH_ATTN_SWEEP=1 for the attention-kernel sweep,
 BENCH_HEAD=1 for the MLM-head sparse-vs-dense microbench (CPU-safe),
-BENCH_OVERLAP=1 for the ZeRO boundary comm/compute-overlap microbench
-(CPU-safe: parity + bucket-count evidence; see bench_overlap.json),
 BENCH_SERVE=1 for the serving bench (continuous vs static batching,
 tokens/s/chip + p50/p99 TTFT/ITL -> bench_serve.json),
 BENCH_RESUME=1 for the time-to-first-step-after-relaunch bench (serial vs
@@ -1096,142 +1094,6 @@ def run_head_bench(repeats=None):
                     "re-measure on chip with BENCH_HEAD=1 python bench.py "
                     "(the gather-VJP scatter the onehot path removes is "
                     "TPU-specific, so the chip ratio is LARGER)")})
-    return 0
-
-
-def run_overlap_bench():
-    """Boundary comm/compute-overlap microbench (overlap_comm): ZeRO-1 and
-    ZeRO-3 engines stepped with the knob on vs off.  Since PR 25 the
-    stage-1 boundary is ONE program either way (one reduce-scatter, one
-    all-gather: the bucketed form bought no overlap on the chip, PERF.md),
-    so its two rows are the control; the stage-3 rows compare the
-    paired-layer prefetch with on-demand gathers.
-
-    CPU evidence (what this run can prove off-chip): (1) PARITY — after
-    ``steps`` fused train_batch steps the two engines' parameters are
-    bitwise identical; (2) DISPATCH — the collectives each step program
-    issues (counted in the traced jaxpr).  Wall-clock overlap
-    needs real ICI ∥ MXU concurrency — on the virtual CPU mesh all
-    devices share host cores, so ms/step here is contention noise; the
-    artifact records the platform and the chip re-measurement command
-    (WALLCLOCK.md §8).  One JSON line -> bench_overlap.json."""
-    import jax
-
-    from deepspeed_tpu.analysis import graph as G
-
-    n = jax.device_count()
-    if n < 2:
-        raise RuntimeError(
-            "overlap bench needs >= 2 devices; set JAX_PLATFORMS=cpu "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-            "for a virtual mesh")
-    import deepspeed_tpu
-    from deepspeed_tpu.models import GPT2
-    from deepspeed_tpu.parallel.topology import make_mesh
-
-    on_tpu = jax.default_backend() == "tpu"
-    seq = int(os.environ.get("BENCH_SEQ", "128" if on_tpu else "32"))
-    hidden = int(os.environ.get("BENCH_OVERLAP_HIDDEN",
-                                "1024" if on_tpu else "128"))
-    layers = int(os.environ.get("BENCH_OVERLAP_LAYERS",
-                                "24" if on_tpu else "4"))
-    vocab = 50304 if on_tpu else 2048
-    bucket_mb = float(os.environ.get("BENCH_OVERLAP_BUCKET_MB",
-                                     "32" if on_tpu else "0.05"))
-    bpc = int(os.environ.get("BENCH_BATCH", "8" if on_tpu else "2"))
-    steps = int(os.environ.get("BENCH_STEPS", "20" if on_tpu else "4"))
-    B = bpc * n
-
-    rng = np.random.default_rng(0)
-    toks = rng.integers(0, vocab, size=(B, seq)).astype(np.int32)
-    labels = np.roll(toks, -1, axis=1)
-    labels[:, -1] = -1
-
-    def build(stage, overlap):
-        model = GPT2.from_size(
-            "tiny", vocab_size=vocab, max_seq_len=seq, num_layers=layers,
-            hidden_size=hidden, num_heads=max(4, hidden // 64))
-        engine, _, _, _ = deepspeed_tpu.initialize(
-            config={"train_batch_size": B, "steps_per_print": 10 ** 9,
-                    "optimizer": {"type": "Adam", "params": {"lr": 1e-4}},
-                    "bf16": {"enabled": True},
-                    "zero_optimization": {
-                        "stage": stage, "overlap_comm": overlap,
-                        "comm_bucket_mb": bucket_mb}},
-            model=model,
-            model_parameters=model.init_params(jax.random.PRNGKey(0)),
-            mesh=make_mesh())
-        return engine
-
-    def collective_counts(engine):
-        """reduce-scatter / all-gather equation counts of the fused step
-        program (the dispatch/bucket-count evidence)."""
-        from deepspeed_tpu import analysis
-
-        jaxpr = analysis.trace_train_batch(engine, (toks, labels))
-        counts = {"reduce_scatter": 0, "all_gather": 0, "psum": 0}
-        for eqn, _ in G.walk(jaxpr.jaxpr):
-            name = eqn.primitive.name
-            if name == "psum_scatter":      # spelling varies by jax version
-                name = "reduce_scatter"
-            if name in counts:
-                counts[name] += 1
-        return counts
-
-    rows = []
-    final_params = {}
-    for stage in (1, 3):
-        for overlap in (True, False):
-            engine = build(stage, overlap)
-            loss = engine.train_batch((toks, labels))   # compile + step 1
-            float(loss)
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                loss = engine.train_batch((toks, labels))
-            float(loss)
-            dt = (time.perf_counter() - t0) / steps
-            counts = collective_counts(engine)
-            rows.append({
-                "stage": stage, "overlap": overlap,
-                "ms_per_step": round(dt * 1000, 2), **counts})
-            final_params[(stage, overlap)] = jax.tree_util.tree_map(
-                np.asarray, engine.params)
-            print(f"zero-{stage} overlap={overlap}: {dt*1e3:.1f} ms/step "
-                  f"{counts}", file=sys.stderr)
-
-    parity = {}
-    for stage in (1, 3):
-        diffs = [float(np.max(np.abs(
-            np.asarray(a, np.float32) - np.asarray(b, np.float32))))
-            for a, b in zip(
-                jax.tree_util.tree_leaves(final_params[(stage, True)]),
-                jax.tree_util.tree_leaves(final_params[(stage, False)]))]
-        parity[f"zero{stage}_max_abs_param_diff"] = max(diffs)
-
-    r = {(row["stage"], row["overlap"]): row for row in rows}
-    _emit({
-        "metric": "boundary_overlap_microbench",
-        "unit": "ms/step (+ per-program collective counts)",
-        "platform": jax.default_backend(),
-        "hardware_true": on_tpu,
-        "seq": seq, "hidden": hidden, "layers": layers,
-        "comm_bucket_mb": bucket_mb, "batch_per_chip": bpc,
-        "zero1_scatter_ops": [r[(1, True)]["reduce_scatter"],
-                              r[(1, False)]["reduce_scatter"]],
-        "zero1_gather_ops": [r[(1, True)]["all_gather"],
-                             r[(1, False)]["all_gather"]],
-        "zero3_gather_ops": [r[(3, True)]["all_gather"],
-                             r[(3, False)]["all_gather"]],
-        **{k: v for k, v in parity.items()},
-        "rows": rows,
-        "note": ("CPU rows prove bit-exact parity and the "
-                 "dispatch structure only — virtual CPU devices share "
-                 "host cores, so ms/step is contention noise, not "
-                 "overlap.  Re-measure on chip: "
-                 "BENCH_OVERLAP=1 BENCH_OUT=bench_overlap.json "
-                 "python bench.py, then BENCH_SEQ=512 BENCH_GAS=32 "
-                 "python bench.py with DSTPU_OVERLAP=off vs on for the "
-                 "recipe-step delta (WALLCLOCK.md §8)")})
     return 0
 
 
@@ -2592,8 +2454,6 @@ def main():
         return run_opt_bench()
     if os.environ.get("BENCH_HEAD", "0") == "1":
         return run_head_bench()
-    if os.environ.get("BENCH_OVERLAP", "0") == "1":
-        return run_overlap_bench()
     if os.environ.get("BENCH_OBS", "0") == "1":
         return run_obs_bench()
     if os.environ.get("BENCH_DISPATCH", "0") == "1":

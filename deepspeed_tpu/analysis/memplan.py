@@ -31,11 +31,7 @@ The walk (:func:`peak_of`) is a liveness simulation over one jaxpr level:
 Accuracy contract: tests/test_memplan.py pins the prediction against
 ``compiled.memory_analysis()`` across ZeRO stages 0-3 x remat on/off x
 MP/PP at +-10% (with a small absolute floor for toy-scale
-buffer-assignment noise).  The ZeRO-3 paired-gather prefetch transient —
-documented in docs/scaling.md as "budget two gathered layers" — stops
-being prose here: :func:`zero3_prefetch_transient_bytes` computes it from
-the engine's own dims tree, and the walk reproduces it from the traced
-program.
+buffer-assignment noise).
 """
 
 from __future__ import annotations
@@ -371,7 +367,6 @@ class CapacityPlan:
     persistent: dict                        # engine.memory_estimate()
     profile: Optional[prof_mod.BackendProfile]
     budget_bytes: Optional[int]
-    zero3_prefetch_bytes: int = 0           # computed two-layer envelope
     comm: Optional[object] = None           # whole-step commplan.CommPlan
     boundary_comm: Optional[object] = None  # step-program-only CommPlan
 
@@ -486,11 +481,6 @@ class CapacityPlan:
                     f"{pers['draft_params_bytes'] / 2**20:.2f}Mi + "
                     f"kv cache "
                     f"{pers.get('draft_kv_cache_bytes', 0) / 2**20:.2f}Mi")
-        if self.zero3_prefetch_bytes:
-            lines.append(
-                f"zero3 prefetch transient: "
-                f"{self.zero3_prefetch_bytes / 2**20:.2f}Mi "
-                f"(two gathered layers)")
         if self.comm is not None:
             lines.append(self.comm.format_summary())
         return "\n".join(lines)
@@ -502,7 +492,6 @@ class CapacityPlan:
             "peak_bytes": self.peak_bytes,
             "fits": self.fits(),
             "persistent": dict(self.persistent),
-            "zero3_prefetch_bytes": self.zero3_prefetch_bytes,
             "programs": [{
                 "subject": p.subject,
                 "argument_bytes": p.argument_bytes,
@@ -528,38 +517,6 @@ def _fmt_bytes(n: int) -> str:
     if abs(n) >= int(0.01 * 2**30):
         return f"{n / 2**30:.3f} GiB"
     return f"{n / 2**20:.3f} MiB"
-
-
-def zero3_prefetch_transient_bytes(engine) -> int:
-    """The ZeRO-3 paired-gather transient, COMPUTED: two gathered layers'
-    compute-dtype bytes (docs/scaling.md's documented envelope).  Block
-    leaves are the ones partitioned at dim >= 1 — ``zero3_min_dims`` pins
-    the leading scan/layer axis as never-partitioned, so a partition dim
-    of 1+ identifies a per-layer [L, ...] stack; gathering restores the
-    full per-layer slice (size / L).  0 when prefetch is off, the engine
-    is not stage 3, or the stack depth makes ``scan_layers`` fall back
-    to on-demand gathers (L < 2 or odd — transformer.py's exact
-    condition; the paired-gather transient only exists when the paired
-    scan actually runs)."""
-    import jax.numpy as jnp
-
-    dims = getattr(engine, "_zero3_dims", None)
-    if dims is None or not getattr(engine, "overlap_comm", False):
-        return 0
-    itemsize = jnp.dtype(engine.policy.compute_dtype).itemsize
-    leaves = jax.tree_util.tree_leaves(engine.params)
-    dim_leaves = jax.tree_util.tree_structure(
-        engine.params).flatten_up_to(dims)
-    layer = 0
-    depth = None
-    for leaf, d in zip(leaves, dim_leaves):
-        if int(d) >= 1 and leaf.ndim >= 1 and leaf.shape[0] > 0:
-            if depth is None:
-                depth = int(leaf.shape[0])
-            layer += (int(leaf.size) // int(leaf.shape[0])) * itemsize
-    if depth is None or depth < 2 or depth % 2:
-        return 0
-    return 2 * layer
 
 
 def _engine_train_batch_args(engine, batch):
@@ -712,5 +669,4 @@ def plan_engine(engine, batch, train: bool = True,
         persistent=engine.memory_estimate(),
         profile=profile,
         budget_bytes=budget_bytes,
-        zero3_prefetch_bytes=zero3_prefetch_transient_bytes(engine),
         comm=comm, boundary_comm=boundary_comm)

@@ -11,11 +11,10 @@ the rule catalogue; rule codes are stable and suppressible by prefix.
    compares the ordered collective signatures of every ``cond``/``switch``
    branch whose predicate carries that taint (the 1F1B/GPipe stage
    schedules in parallel/pipeline.py are exactly this shape).  Signatures
-   include the operand shape/dtype — the wire format — so the
-   ``overlap_comm`` chunked psums (K same-primitive collectives told
-   apart only by their chunk shapes) and the ZeRO-3 prefetched gather
-   sequence compare exactly: branches chunking the same payload
-   differently are a real deadlock and are flagged.  Also checks
+   include the operand shape/dtype — the wire format — so branches
+   chunking the same payload differently (K same-primitive collectives
+   told apart only by their chunk shapes) are a real deadlock and are
+   flagged.  Also checks
    axis names against the engine mesh and ``ppermute`` permutation validity
    — all of ``comm.py``'s wrappers (psum, psum_scatter with
    ``axis_index_groups`` sub-groups, all_gather) produce these primitives.
@@ -101,9 +100,8 @@ def _collective_sig(eqn) -> Tuple:
     groups = p.get("axis_index_groups")
     perm = p.get("perm")
     layout = tuple((k, p[k]) for k in _SIG_LAYOUT_KEYS if k in p)
-    # operand shapes/dtypes are part of the wire format: under overlap_comm
-    # a big leaf reduces as K same-primitive chunked psums whose only
-    # distinguishing feature is the buffer shape, so two branches chunking
+    # operand shapes/dtypes are part of the wire format: K same-primitive
+    # chunked psums differ only in the buffer shape, so two branches chunking
     # the same payload DIFFERENTLY (or one chunked, one monolithic) must
     # compare unequal — ranks in either branch would block exchanging
     # mismatched buffers.  ALL operands are hashed: psum-family eqns carry

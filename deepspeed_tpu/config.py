@@ -253,32 +253,10 @@ class DeepSpeedConfig:
             self.zero_enabled = self.zero_stage > 0
             self.zero_parameter_parallel_size = zero.get(
                 C.ZERO_PARAMETER_PARALLEL_SIZE, C.ZERO_PARAMETER_PARALLEL_SIZE_DEFAULT)
-            self.zero_overlap_comm = bool(zero.get(
-                C.ZERO_OVERLAP_COMM, C.ZERO_OVERLAP_COMM_DEFAULT))
-            self.zero_comm_bucket_mb = zero.get(
-                C.ZERO_COMM_BUCKET_MB, C.ZERO_COMM_BUCKET_MB_DEFAULT)
         else:
             self.zero_enabled = bool(zero)
             self.zero_stage = 1 if self.zero_enabled else 0
             self.zero_parameter_parallel_size = C.ZERO_PARAMETER_PARALLEL_SIZE_DEFAULT
-            # the overlap knobs also govern the plain-DP (stage-0) gradient
-            # reduction, so they default on even without a zero section
-            self.zero_overlap_comm = C.ZERO_OVERLAP_COMM_DEFAULT
-            self.zero_comm_bucket_mb = C.ZERO_COMM_BUCKET_MB_DEFAULT
-        try:
-            self.zero_comm_bucket_mb = float(self.zero_comm_bucket_mb)
-        except (TypeError, ValueError):
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COMM_BUCKET_MB} must be a number "
-                f"of megabytes, got {self.zero_comm_bucket_mb!r}")
-        # a non-positive bucket is only an error when bucketing is actually
-        # on — overlap_comm=false with the size zeroed out is a valid way
-        # to spell "disabled"
-        if self.zero_overlap_comm and self.zero_comm_bucket_mb <= 0:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COMM_BUCKET_MB} must be > 0 "
-                f"(got {self.zero_comm_bucket_mb}); to disable bucketing set "
-                f"{C.ZERO_OVERLAP_COMM}=false instead")
 
         self.gradient_clipping = get_scalar_param(
             pd, C.GRADIENT_CLIPPING, C.GRADIENT_CLIPPING_DEFAULT)

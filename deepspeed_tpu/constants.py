@@ -552,13 +552,3 @@ SEQUENCE_PARALLEL_IMPL_DEFAULT = None     # None | "ring" | "ulysses"
 
 ZERO_PARAMETER_PARALLEL_SIZE = "parameter_parallel_size"
 ZERO_PARAMETER_PARALLEL_SIZE_DEFAULT = None
-
-# Comm/compute overlap (docs/scaling.md "Communication/compute overlap"):
-# stage 0 (and stage 3's replicated leaves) psums gradient leaves above
-# comm_bucket_mb in independent lane-aligned chunks; stage 3 prefetches the
-# next layer's gather.  Bit-exact with the knob off (DSTPU_OVERLAP=off).
-# Stages 1-2 build one boundary whatever the knob says.
-ZERO_OVERLAP_COMM = "overlap_comm"
-ZERO_OVERLAP_COMM_DEFAULT = True
-ZERO_COMM_BUCKET_MB = "comm_bucket_mb"
-ZERO_COMM_BUCKET_MB_DEFAULT = 32.0

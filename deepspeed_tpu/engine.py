@@ -562,31 +562,6 @@ class DeepSpeedTpuEngine:
         # layout; ``zero_flat`` gates every flat-layout code path.
         self.zero3 = self.zero_stage == 3
         self.zero_flat = self.zero_enabled and not self.zero3
-        # -- comm/compute overlap (zero_optimization.overlap_comm,
-        # DSTPU_OVERLAP=on|off over the config).  What it governs, by stage:
-        # 0 (and the replicated leaves of 3): gradient leaves above
-        # comm_bucket_mb psum in independent lane-aligned chunks; 3: the
-        # block scan prefetches the next layer's gather.  Both bit-exact
-        # with the knob off.  Stages 1-2: nothing — the flat boundary is
-        # one contiguous reduce-scatter and one compute-dtype all-gather
-        # whatever the knob says (on the v5e the bucketed [group,
-        # partition] form overlapped nothing and spent most of the step
-        # re-tiling full-size buffers; PERF.md, PR 25).
-        self.overlap_comm = bool(self.config.zero_overlap_comm)
-        _ov = os.environ.get("DSTPU_OVERLAP", "").strip().lower()
-        if _ov in ("off", "0", "false"):
-            self.overlap_comm = False
-        elif _ov in ("on", "1", "true"):
-            self.overlap_comm = True
-        elif _ov:
-            raise DeepSpeedConfigError(
-                f"DSTPU_OVERLAP={_ov!r} is not a valid mode: use 'on' or "
-                f"'off'")
-        # chunk size in fp32 elements, floored to the 128-lane tile;
-        # comm_bucket_mb may be fractional for tiny test meshes
-        self.comm_bucket_elems = max(
-            128, (int(self.config.zero_comm_bucket_mb * (1 << 20)) // 4
-                  // 128) * 128)
         if self.zero3:
             if not hasattr(model, "zero3_dims"):
                 raise DeepSpeedConfigError(
@@ -708,13 +683,6 @@ class DeepSpeedTpuEngine:
             else:
                 model = self.module
             model.zero3_dims = self._zero3_dims
-            # overlap_comm at stage 3: the block scan runs over layer
-            # pairs and issues both gathers up front, so the second
-            # layer's all-gather hides under the first layer's compute
-            # (forward AND the remat-replayed backward) — transient
-            # weight memory is two gathered layers instead of one
-            # (transformer.scan_layers; docs/scaling.md)
-            model.zero3_prefetch = self.overlap_comm
         if param_groups is None and self.client_optimizer is None:
             # pure-JSON spelling (optimizer.param_groups); the explicit
             # initialize(param_groups=...) argument beats it, and a
@@ -1971,8 +1939,6 @@ class DeepSpeedTpuEngine:
         sparse_flags = self._sparse_flags
         group_ids = self._group_ids
         multi_group = len(self._group_defs) > 1
-        bucket_elems = (self.comm_bucket_elems if self.overlap_comm
-                        else None)
 
         @obs_scopes.scoped("boundary")
         def step_local(master, opt_state, grads, ls_state, hypers,
@@ -2118,7 +2084,6 @@ class DeepSpeedTpuEngine:
                         if d >= 0:
                             return g / world
                         return comm.allreduce_grads(g, DATA_AXIS, world,
-                                                    bucket_elems=bucket_elems,
                                                     **knobs)
 
                     grads = jax.tree_util.tree_map(
@@ -2173,7 +2138,6 @@ class DeepSpeedTpuEngine:
                             cfg.gradient_predivide_factor))
                     if sparse_flags is None:
                         grads = comm.allreduce_grads(grads, DATA_AXIS, world,
-                                                     bucket_elems=bucket_elems,
                                                      **knobs)
                     else:
                         # marked leaves (embeddings) reduce as gathered
@@ -2190,8 +2154,7 @@ class DeepSpeedTpuEngine:
                                     g, DATA_AXIS, world,
                                     cfg.sparse_gradients_max_rows, **knobs)
                             return comm.allreduce_grads(
-                                g, DATA_AXIS, world,
-                                bucket_elems=bucket_elems, **knobs)
+                                g, DATA_AXIS, world, **knobs)
 
                         grads = jax.tree_util.tree_map(
                             reduce_one, grads, sparse_flags,

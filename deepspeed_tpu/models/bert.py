@@ -60,11 +60,10 @@ def _backbone_partition_specs() -> dict:
 
 
 def _encode(cfg, params, input_ids, attention_mask, token_type_ids,
-            z3_block_dims=None, z3_prefetch=False):
+            z3_block_dims=None):
     """Embed + encoder stack (runs inside shard_map on local shards).
     Callers must already have run ``T.zero3_enter`` on ``params`` under
-    ZeRO-3 (``z3_block_dims`` = its deferred block dims; ``z3_prefetch``
-    pairs the per-layer gathers — transformer.scan_layers)."""
+    ZeRO-3 (``z3_block_dims`` = its deferred block dims)."""
     T_len = input_ids.shape[1]
     with S.scope("embed"):
         x = L.vocab_parallel_embedding(input_ids, params["wte"])
@@ -75,7 +74,7 @@ def _encode(cfg, params, input_ids, attention_mask, token_type_ids,
         x = L.layer_norm(x, params["ln_emb_s"], params["ln_emb_b"],
                          cfg.ln_eps)
     return T.stack_apply(x, params["blocks"], cfg, attn_mask=attention_mask,
-                         z3_dims=z3_block_dims, z3_prefetch=z3_prefetch)
+                         z3_dims=z3_block_dims)
 
 
 def _zero3_min_dims(params):
@@ -107,10 +106,6 @@ class BertForPreTraining:
     mlm_gather_budget: object = None
     #: ZeRO-3 partition dims (set by the engine at stage 3; zero3.py)
     zero3_dims: object = None
-    #: ZeRO-3 gather prefetch (engine overlap_comm): paired-layer scan
-    #: hiding the second gather under the first block's compute
-    #: (transformer.scan_layers)
-    zero3_prefetch: bool = False
 
     @classmethod
     def from_size(cls, size: str, use_nsp: bool = False,
@@ -228,8 +223,7 @@ class BertForPreTraining:
 
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         x = _encode(cfg, params, input_ids, attention_mask, token_type_ids,
-                    z3_block_dims=z3_deferred.get("blocks"),
-                    z3_prefetch=getattr(self, "zero3_prefetch", False))
+                    z3_block_dims=z3_deferred.get("blocks"))
 
         with S.scope("head"):
             if mlm_positions is None:
@@ -294,10 +288,6 @@ class BertForQuestionAnswering:
     config: T.TransformerConfig
     #: ZeRO-3 partition dims (set by the engine at stage 3; zero3.py)
     zero3_dims: object = None
-    #: ZeRO-3 gather prefetch (engine overlap_comm): paired-layer scan
-    #: hiding the second gather under the first block's compute
-    #: (transformer.scan_layers)
-    zero3_prefetch: bool = False
 
     @classmethod
     def from_size(cls, size: str, **overrides):
@@ -347,8 +337,7 @@ class BertForQuestionAnswering:
         cfg = self.config
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         x = _encode(cfg, params, input_ids, attention_mask, token_type_ids,
-                    z3_block_dims=z3_deferred.get("blocks"),
-                    z3_prefetch=getattr(self, "zero3_prefetch", False))
+                    z3_block_dims=z3_deferred.get("blocks"))
         with S.scope("head"):
             logits = (x @ params["qa_w"].astype(x.dtype)
                       + params["qa_b"].astype(x.dtype)).astype(jnp.float32)

@@ -52,11 +52,6 @@ class GPT2:
     #: The block subtree is gathered per layer inside the scan, the rest
     #: at apply entry (transformer.zero3_enter).
     zero3_dims: object = None
-    #: ZeRO-3 gather prefetch (set by the engine from overlap_comm): the
-    #: block scan runs over layer pairs issuing both gathers up front, so
-    #: the second layer's all-gather hides under the first layer's
-    #: compute (transformer.scan_layers; two-layer transient memory).
-    zero3_prefetch: bool = False
 
     @classmethod
     def from_size(cls, size: str, **overrides) -> "GPT2":
@@ -120,9 +115,7 @@ class GPT2:
     def _stack(self, x, blocks, z3_dims=None):
         """Block-stack hook: returns (x, auxiliary loss term).  GPT2MoE
         overrides this with the MoE stack + weighted load-balance loss."""
-        return T.stack_apply(
-            x, blocks, self.config, z3_dims=z3_dims,
-            z3_prefetch=getattr(self, "zero3_prefetch", False)), 0.0
+        return T.stack_apply(x, blocks, self.config, z3_dims=z3_dims), 0.0
 
     # ------------------------------------------------- serving (inference/)
     def kv_cache_dims(self, mp_size: int = 1):

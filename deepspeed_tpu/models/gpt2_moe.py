@@ -42,9 +42,7 @@ class GPT2MoE(GPT2):
         return M.moe_block_partition_specs()
 
     def _stack(self, x, blocks, z3_dims=None):
-        x, aux = M.moe_stack_apply(
-            x, blocks, self.config, z3_dims=z3_dims,
-            z3_prefetch=getattr(self, "zero3_prefetch", False))
+        x, aux = M.moe_stack_apply(x, blocks, self.config, z3_dims=z3_dims)
         return x, self.config.aux_weight * aux
 
 

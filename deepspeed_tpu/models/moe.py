@@ -206,14 +206,12 @@ def moe_block_apply(x, p, cfg: MoEConfig, attn_mask=None):
 
 
 def moe_stack_apply(x, stacked_params, cfg: MoEConfig, attn_mask=None,
-                    z3_dims=None, z3_prefetch=False):
+                    z3_dims=None):
     """lax.scan over the stacked [L, ...] MoE blocks; returns (x, aux_sum).
     ``z3_dims``: ZeRO-3 partition dims of the stacked leaves (per-layer
-    gather); ``z3_prefetch`` pairs the gathers so the second hides
-    under compute (transformer.scan_layers)."""
+    gather, transformer.scan_layers)."""
     def body(carry, lp):
         return moe_block_apply(carry, lp, cfg, attn_mask)
 
-    x, auxes = T.scan_layers(body, x, stacked_params, cfg,
-                             z3_dims=z3_dims, z3_prefetch=z3_prefetch)
+    x, auxes = T.scan_layers(body, x, stacked_params, cfg, z3_dims=z3_dims)
     return x, jnp.sum(auxes)

@@ -165,8 +165,6 @@ class GPT2Pipelined(GPT2):
     def _pipe_stack(self, u, blocks, z3_dims=None):
         """Stage-stack hook: returns (y, aux scalar).  The MoE variant
         overrides this with the expert stack + load-balance aux."""
-        return T.stack_apply(
-            u, blocks, self.config, z3_dims=z3_dims,
-            z3_prefetch=getattr(self, "zero3_prefetch", False)), 0.0
+        return T.stack_apply(u, blocks, self.config, z3_dims=z3_dims), 0.0
 
     __call__ = apply

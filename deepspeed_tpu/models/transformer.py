@@ -316,92 +316,17 @@ def zero3_wrap_body(body, z3_dims):
     return wrapped
 
 
-@jax.custom_vjp
-def _sched_barrier(args):
-    """Identity that stops XLA fusing across it, in forward AND backward
-    (``optimization_barrier`` has no autodiff rule, hence the custom_vjp).
-    Placed between the two block applications of the prefetch pair body so
-    each block compiles exactly like the on-demand scan's single-block
-    body — cross-block fusion re-tiles large bf16 reductions and costs
-    bitwise parity.  Scheduling across it is unaffected: the pair's second
-    gather and the first block both sit before the barrier with no mutual
-    data dependence, so the gather still hides under the compute."""
-    return jax.lax.optimization_barrier(args)
-
-
-def _sched_barrier_fwd(args):
-    return _sched_barrier(args), None
-
-
-def _sched_barrier_bwd(_, ct):
-    return (jax.lax.optimization_barrier(ct),)
-
-
-_sched_barrier.defvjp(_sched_barrier_fwd, _sched_barrier_bwd)
-
-
 @S.scoped("block")
-def scan_layers(body, carry, stacked_params, cfg, z3_dims=None,
-                z3_prefetch=False):
+def scan_layers(body, carry, stacked_params, cfg, z3_dims=None):
     """``lax.scan`` of ``body(carry, layer_params) -> (carry, y)`` over the
-    stacked [L, ...] layers, with the ZeRO-3 per-layer gather when
-    ``z3_dims`` marks partitioned leaves.  Shared by the dense and MoE
-    stacks.
-
-    ``z3_prefetch`` (engine ``overlap_comm``, stage 3): the scan runs over
-    PAIRS of layers, and the body issues BOTH layers' all-gathers up
-    front — layer b's gather has no data dependence on layer a's block,
-    so XLA's async collectives hide it under layer a's compute (one
-    exposed gather per pair instead of per layer).  The scan carry stays
-    activations-only: a gathered layer threaded through the carry would
-    be saved as a per-iteration scan residual, resurrecting the full
-    unsharded weight set in the backward — exactly the memory ZeRO-3
-    exists to avoid (measured: L× gathered-layer temp blowup).  Here the
-    residuals per iteration are the activations and the PARTITIONED pair
-    slice; under remat the body — both gathers included — replays in the
-    backward, so the backward prefetches the same way and the gather
-    transpose still delivers grads reduce-scattered.  Transient weight
-    memory is TWO gathered layers (the pair in flight) instead of one.
-    The pair body is uniform across iterations and a ``_sched_barrier``
-    separates the two blocks, which keeps bitwise parity with the
-    on-demand path; ODD layer counts fall back to on-demand (an odd tail
-    outside the scan tiles its bf16 grad reductions differently and
-    drifts by ulps — family depths are even).
-
+    stacked [L, ...] layers, under ``cfg``'s recomputation policy; where
+    ``z3_dims`` marks partitioned leaves (ZeRO-3) the body first gathers its
+    layer (``zero3_wrap_body``).  Shared by the dense, MoE and looped stacks.
     The whole scan runs under the ``dstpu/block`` device scope, so the
     per-layer slicing of the stacked parameters and saved activations,
     forward and backward, counts with the blocks it feeds."""
-    if z3_dims is None or not Z.partitioned_any(z3_dims):
-        return jax.lax.scan(remat_wrap(body, cfg), carry, stacked_params)
-    num_layers = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
-    if not z3_prefetch or num_layers < 2 or num_layers % 2:
-        return jax.lax.scan(
-            remat_wrap(zero3_wrap_body(body, z3_dims), cfg), carry,
-            stacked_params)
-
-    body_dims = Z.shift_dims(z3_dims, -1)
-    paired = jax.tree_util.tree_map(
-        lambda l: l.reshape((num_layers // 2, 2) + l.shape[1:]),
-        stacked_params)
-
-    def pair_body(c, lp2):
-        wa = Z.gather_tree(
-            jax.tree_util.tree_map(lambda l: l[0], lp2), body_dims)
-        wb = Z.gather_tree(
-            jax.tree_util.tree_map(lambda l: l[1], lp2), body_dims)
-        c, ya = body(c, wa)    # wb's gather rides under this compute
-        c, wb = _sched_barrier((c, wb))
-        c, yb = body(c, wb)
-        return c, (None if ya is None else (ya, yb))
-
-    carry, ys = jax.lax.scan(remat_wrap(pair_body, cfg), carry, paired)
-    if ys is None:
-        return carry, None
-    ya, yb = ys
-    return carry, jax.tree_util.tree_map(
-        lambda a, b: jnp.concatenate(
-            [a[:, None], b[:, None]], axis=1
-        ).reshape((num_layers,) + a.shape[1:]), ya, yb)
+    return jax.lax.scan(remat_wrap(zero3_wrap_body(body, z3_dims), cfg),
+                        carry, stacked_params)
 
 
 # ------------------------------------------------------------- serving
@@ -487,13 +412,11 @@ def stack_extend(x, stacked_params, cfg: TransformerConfig, k, v, rows,
 
 
 def stack_apply(x, stacked_params, cfg: TransformerConfig, attn_mask=None,
-                z3_dims=None, z3_prefetch=False):
+                z3_dims=None):
     """Run all layers via lax.scan over the stacked [L, ...] params.
     ``z3_dims``: ZeRO-3 partition dims of the stacked leaves (gather per
-    layer inside the body); ``z3_prefetch`` pairs the gathers so the
-    second hides under compute — see ``scan_layers``."""
+    layer inside the body) — see ``scan_layers``."""
     def body(carry, lp):
         return block_apply(carry, lp, cfg, attn_mask), None
-    x, _ = scan_layers(body, x, stacked_params, cfg,
-                       z3_dims=z3_dims, z3_prefetch=z3_prefetch)
+    x, _ = scan_layers(body, x, stacked_params, cfg, z3_dims=z3_dims)
     return x

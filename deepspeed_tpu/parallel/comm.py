@@ -16,7 +16,7 @@ All functions take pytrees and an axis name; they must be called inside
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,50 +66,21 @@ def allreduce_grads(grads,
                     world_size: int,
                     fp32_allreduce: bool = False,
                     prescale_gradients: bool = False,
-                    gradient_predivide_factor: float = 1.0,
-                    bucket_elems: Optional[int] = None):
-    """Sum-reduce grads over the DP axis and average (reference
-    ``allreduce_bucket``, deepspeed_light.py:819-849; knob semantics in
-    ``scaled_reduce``).  The reduction lowers to an ICI all-reduce.
-
-    ``bucket_elems`` (overlap_comm at ZeRO stage 0, and the replicated
-    leaves of stage 3): leaves larger than this split into lane-aligned
-    chunks reduced by INDEPENDENT psums, which leaves XLA free to overlap
-    them with each other and with the elementwise update (never timed on
-    a chip: no benchmark cell runs plain DP across chips yet).  Chunking
-    is elementwise-identical to the whole-leaf psum (same addends, same
-    per-element order), hence bit-exact."""
-    knobs = dict(fp32_allreduce=fp32_allreduce,
-                 prescale_gradients=prescale_gradients,
-                 gradient_predivide_factor=gradient_predivide_factor)
-
+                    gradient_predivide_factor: float = 1.0):
+    """Sum-reduce grads over the DP axis and average, one reduction per leaf
+    (reference ``allreduce_bucket``, deepspeed_light.py:819-849; knob
+    semantics in ``scaled_reduce``).  The reduction lowers to an ICI
+    all-reduce."""
     def reduce_one(g):
         if g is None:
             return None
-        if bucket_elems is not None and g.size > bucket_elems:
-            flat = jnp.reshape(g, (-1,))
-            bounds = bucket_bounds(flat.shape[0], bucket_elems)
-            parts = [scaled_reduce(flat[s:e],
-                                   lambda x: lax.psum(x, axis_name),
-                                   world_size, **knobs)
-                     for s, e in bounds]
-            return jnp.reshape(jnp.concatenate(parts), g.shape)
         return scaled_reduce(
-            g, lambda x: lax.psum(x, axis_name), world_size, **knobs)
+            g, lambda x: lax.psum(x, axis_name), world_size,
+            fp32_allreduce=fp32_allreduce,
+            prescale_gradients=prescale_gradients,
+            gradient_predivide_factor=gradient_predivide_factor)
 
     return _tree_map(reduce_one, grads)
-
-
-def bucket_bounds(total: int, bucket_elems: int,
-                  align: int = 128) -> Tuple[Tuple[int, int], ...]:
-    """Contiguous ``(start, stop)`` slices covering ``[0, total)`` with each
-    bucket ``<= max(bucket_elems, align)`` elements and every boundary a
-    multiple of ``align`` (lane alignment).  One bucket when
-    ``bucket_elems >= total``.  The chunking of ``allreduce_grads``."""
-    if total <= 0:
-        return ((0, total),)
-    step = max(align, (int(bucket_elems) // align) * align)
-    return tuple((s, min(s + step, total)) for s in range(0, total, step))
 
 
 def subgroup_index_groups(world_size: int, group_size: int):
@@ -147,7 +118,7 @@ def reduce_scatter_grads(flat_grad: jnp.ndarray,
     lowers it to an all-reduce of the flat buffer and a slice all the same
     (PERF.md, PR 25: the largest row left in the boundary).  Same scaling
     knobs as ``allreduce_grads``.  This is the ONE reduction of the ZeRO-1/2
-    boundary, on the contiguous 1-D buffer, whatever ``overlap_comm`` says.
+    boundary, on the contiguous 1-D buffer.
 
     With ``partition_group_size`` g < world (ZeRO parameter_parallel_size,
     reference deepspeed_light.py:63-77) the scatter runs within each

@@ -34,7 +34,6 @@ class IntLayerModel:
     and the shard-record keying must survive it."""
 
     zero3_dims = None
-    zero3_prefetch = False
 
     def init_params(self, rng):
         k1, k2, k3 = jax.random.split(rng, 3)

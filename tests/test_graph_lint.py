@@ -600,9 +600,8 @@ def test_divergent_bucket_shapes_detected():
     """Collective signatures include the operand shape (the wire format):
     two rank-divergent branches issuing the SAME primitive over the same
     axis but with DIFFERENT bucket tilings are a real deadlock — ranks in
-    either branch would block exchanging mismatched buffers.  This is the
-    failure class the overlap_comm chunked psums (comm.allreduce_grads)
-    could introduce if a schedule ever chunked per-branch."""
+    either branch would block exchanging mismatched buffers: the failure
+    class of a schedule that chunks a reduction per branch."""
     def bad(x):
         r = lax.axis_index("data")
 

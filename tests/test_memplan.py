@@ -16,8 +16,7 @@ additionally widens elementwise compute unpredictably, so the parity
 matrix pins fp16; the planner's TPU predictions use the same walk minus
 the quirk.
 
-Plus: the ZeRO-3 prefetch two-layer envelope as a *computed* planner
-number, wire-cost formulas, the memory.* suppression contract, and the
+Plus: wire-cost formulas, the memory.* suppression contract, and the
 engine/config/CLI wiring.
 """
 
@@ -139,10 +138,6 @@ def test_parity_mlp_stage0():
     _assert_parity(eng, _mlp_batch(_full_batch_size(eng)), "mlp stage0")
 
 
-#: overlap_comm=False in the parity matrix: the stage-1/2 boundary is one
-#: program whatever the knob says, and the ZeRO-3 paired-gather prefetch
-#: is pinned separately (its own parity cell + the computed-envelope
-#: assertions below)
 @pytest.mark.parametrize("stage,remat", [
     (0, False), (0, True), (1, False), (1, True),
     (2, False), (2, True), (3, False), (3, True)])
@@ -151,21 +146,10 @@ def test_parity_gpt2_zero_stage_x_remat(stage, remat):
     model = GPT2.from_size("tiny", num_layers=4)
     cfg = {"activation_checkpointing": remat}
     if stage:
-        cfg["zero_optimization"] = {"stage": stage, "overlap_comm": False}
+        cfg["zero_optimization"] = {"stage": stage}
     eng = _engine(model, **cfg)
     _assert_parity(eng, _gpt2_batch(model, _full_batch_size(eng)),
                    f"gpt2 zero{stage} remat={remat}")
-
-
-def test_parity_gpt2_zero3_prefetch_on():
-    """The paired-gather prefetch program (overlap_comm on, remat on) —
-    the two-gathered-layer transient must be IN the prediction."""
-    from deepspeed_tpu.models.gpt2 import GPT2
-    model = GPT2.from_size("tiny", num_layers=4)
-    eng = _engine(model, activation_checkpointing=True,
-                  zero_optimization={"stage": 3, "overlap_comm": True})
-    _assert_parity(eng, _gpt2_batch(model, _full_batch_size(eng)),
-                   "gpt2 zero3 prefetch")
 
 
 def test_parity_gpt2_mp2():
@@ -192,70 +176,6 @@ def test_parity_bert():
     model = BertForPreTraining.from_size("tiny")
     eng = _engine(model)
     _assert_parity(eng, _bert_batch(model, _full_batch_size(eng)), "bert")
-
-
-# ======================================================================
-# the ZeRO-3 prefetch envelope becomes a computed number
-# ======================================================================
-
-def test_zero3_prefetch_envelope_is_computed():
-    """docs/scaling.md's 'budget two gathered layers' stops being prose:
-    the planner computes the envelope from the engine's dims tree, and
-    the traced-program prediction's prefetch delta stays O(1) in layer
-    count — bounded by the in-flight pair (forward + its remat-replayed
-    backward and the CPU-profile fp32 dot copies), never the full
-    gathered stack (the carried-weight leak the envelope guards
-    against).  Planner-only: no compile, so L=8 is cheap and makes the
-    full-stack comparison meaningful."""
-    from deepspeed_tpu.models.gpt2 import GPT2
-    L = 8
-
-    def build(overlap):
-        model = GPT2.from_size("tiny", num_layers=L)
-        return _engine(model, activation_checkpointing=True,
-                       zero_optimization={"stage": 3,
-                                          "overlap_comm": overlap}), model
-
-    eng_on, model = build(True)
-    eng_off, _ = build(False)
-    batch = _gpt2_batch(model, _full_batch_size(eng_on))
-    plan_on = eng_on.plan_capacity(batch, profile=CPU)
-    plan_off = eng_off.plan_capacity(batch, profile=CPU)
-
-    # the computed envelope: two gathered layers' compute-dtype bytes
-    env = plan_on.zero3_prefetch_bytes
-    itemsize = jnp.dtype(eng_on.policy.compute_dtype).itemsize
-    leaves = jax.tree_util.tree_leaves(eng_on.params)
-    dims = jax.tree_util.tree_structure(eng_on.params).flatten_up_to(
-        eng_on._zero3_dims)
-    expect_layer = sum(
-        (int(l.size) // int(l.shape[0])) * itemsize
-        for l, d in zip(leaves, dims) if int(d) >= 1)
-    assert env == 2 * expect_layer and env > 0
-
-    # prefetch off -> no envelope; on -> the traced prediction grows by
-    # the pair in flight (fwd + bwd replay + fp32 dot copies ~ 2x env +
-    # a layer of slack), NOT by the full gathered stack
-    assert plan_off.zero3_prefetch_bytes == 0
-    delta = plan_on.peak_bytes - plan_off.peak_bytes
-    assert 0 < delta <= 2 * env + expect_layer, (delta, env)
-    assert delta < L * expect_layer, (
-        f"prefetch delta {delta} looks like the full gathered stack "
-        f"({L} x {expect_layer}) — carried-weight leak")
-
-
-def test_zero3_prefetch_envelope_zero_on_odd_depth():
-    """Odd layer counts make scan_layers fall back to on-demand gathers
-    (transformer.py's L < 2 or L % 2 condition), so the computed
-    envelope must be 0 — reporting a phantom two-layer transient would
-    overstate the plan by exactly the number docs/scaling.md calls
-    'computed'."""
-    from deepspeed_tpu.models.gpt2 import GPT2
-    model = GPT2.from_size("tiny", num_layers=3)
-    eng = _engine(model, activation_checkpointing=True,
-                  zero_optimization={"stage": 3, "overlap_comm": True})
-    batch = _gpt2_batch(model, _full_batch_size(eng))
-    assert eng.plan_capacity(batch, profile=CPU).zero3_prefetch_bytes == 0
 
 
 # ======================================================================

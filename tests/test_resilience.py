@@ -365,14 +365,20 @@ def test_engine_stall_injection_fires_watchdog(tmpdir):
     from deepspeed_tpu.observability import flightrec
 
     cfg = dict(NAN_CFG)
-    cfg["resilience"] = {"watchdog_timeout_s": 0.3}
+    # a production-size deadline while the step programs compile: under a
+    # loaded host the compile of step 0 outlasts any deadline short enough
+    # to test with, and the dump would name the wrong step
+    cfg["resilience"] = {"watchdog_timeout_s": 120.0}
     cfg["observability"] = {"flight_recorder_dir": str(tmpdir)}
     engine = _engine_factory(cfg)()
-    engine._watchdog.poll_s = 0.05
-    chaos.configure(stall_step=1, stall_s=1.5)
     _split_step(engine, _fp32_batch(0))      # boundary: global step 0 -> 1
-    _split_step(engine, _fp32_batch(1))      # stalls at global step 1
     wd = engine._watchdog
+    assert not wd.fired
+    # compiled: a step is milliseconds now.  The stall ends when the
+    # watchdog has fired (60 s is a ceiling, not a wait)
+    wd.timeout_s, wd.poll_s = 1.0, 0.05
+    chaos.configure(stall_step=1, stall_s=60.0, stall_until=wd.fire_event)
+    _split_step(engine, _fp32_batch(1))      # stalls at global step 1
     assert wd.fired
     assert "chaos_stall" in wd.last_dump
     assert "optimizer boundary step" in wd.last_dump
