@@ -307,7 +307,12 @@ class DeepSpeedConfig:
 
         ac = get_scalar_param(pd, C.ACTIVATION_CHECKPOINTING,
                               C.ACTIVATION_CHECKPOINTING_DEFAULT)
-        self.activation_checkpointing_policy = None   # None | "full" | "dots"
+        # None | "full" | "dots" | "selective" (transformer.remat_wrap).
+        # "full": save each block's input and the residuals of a Pallas
+        # kernel (the streaming attention kernel's output and log-sum-exp,
+        # where attention_plan chose that kernel); replay everything XLA
+        # computes.
+        self.activation_checkpointing_policy = None
         if isinstance(ac, Mapping):
             self.activation_checkpointing_policy = ac.get("policy", None)
             ac = bool(ac.get("enabled", True))

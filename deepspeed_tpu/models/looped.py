@@ -65,7 +65,11 @@ class LoopedConfig:
     exit_entropy_weight: float = 0.1
     init_std: float = 0.02
     remat: bool = True            # per layer application
-    remat_policy: str = "full"    # see transformer.remat_wrap
+    # "full": save each block's input and the residuals of a Pallas kernel
+    # (the streaming attention kernel's output and log-sum-exp, where
+    # attention_plan chose that kernel); replay everything XLA computes.
+    # The other policies: transformer.remat_wrap.
+    remat_policy: str = "full"
     sp_impl: str = "ring"         # the only one this block wires
     #: also return, beside the loss, each exit's mean cross-entropy and mean
     #: exit probability as ``2 * loop_passes`` more scalars (gradient

@@ -23,7 +23,8 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu import zero3 as Z
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.observability import scopes as S
-from deepspeed_tpu.ops.remat_names import FFN1, POST_LN_SUM, SELECTIVE_SAVES
+from deepspeed_tpu.ops.remat_names import (
+    FFN1, FULL_SAVES, POST_LN_SUM, SELECTIVE_SAVES)
 from deepspeed_tpu.parallel.topology import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 
 
@@ -67,8 +68,11 @@ class TransformerConfig:
     # "ring" (K/V rotation) or "ulysses" (head<->seq all-to-all); the
     # engine's sequence_parallel_impl JSON key overrides this field
     sp_impl: str = "ring"
-    # "full": recompute everything in backward (max memory savings, ~33%
-    # extra FLOPs).  "dots": save matmul outputs, recompute only cheap
+    # "full": save each block's input and the residuals of a Pallas kernel
+    # (the streaming attention kernel's output and log-sum-exp, where
+    # attention_plan chose that kernel); replay everything XLA computes
+    # (max memory savings, ~33% extra FLOPs).
+    # "dots": save matmul outputs, recompute only cheap
     # elementwise/softmax/LN — the usual TPU sweet spot when HBM allows.
     # "selective": save the named residuals of ops/remat_names.py (what
     # costs a matmul or a kernel call to replay), a fraction of "dots"' bytes.
@@ -270,7 +274,15 @@ def remat_wrap(body, cfg):
             policy=jax.checkpoint_policies.save_only_these_names(
                 *SELECTIVE_SAVES))
     if cfg.remat_policy == "full":
-        return jax.checkpoint(body)
+        # save each block's input and the residuals of a Pallas kernel (the
+        # streaming attention kernel's output and log-sum-exp, where
+        # attention_plan chose that kernel); replay everything XLA computes.
+        # A program on the XLA attention plan has neither name, and saves
+        # the input alone.
+        return jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FULL_SAVES))
     raise ValueError(
         f"unknown remat_policy {cfg.remat_policy!r} "
         "(expected 'full', 'dots' or 'selective')")

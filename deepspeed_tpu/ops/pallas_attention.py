@@ -15,11 +15,11 @@ traffic per layer that never needed to leave the chip.  Two kernels:
   bench_attn_sweep.json): 1.14x at seq 512, 1.86x at 1024, 2.44x at 2048
   — under the ``selective`` policy of that time, which ran the forward
   kernel again in the backward pass.  Its output and log-sum-exp now carry
-  checkpoint names (``_name_stream_residuals``) and ``selective`` keeps
-  them, so a layer costs two kernel calls, not three, and the ratios
-  above overstate today's.  ``models/layers.py`` auto-dispatches from
-  ``stream_auto_min(causal)`` tokens (512 causal / 1024 non-causal on
-  v5e).
+  checkpoint names (``_name_stream_residuals``) and ``selective`` and
+  ``full`` keep them, so a layer costs two kernel calls, not three, and
+  the ratios above overstate today's.  ``models/layers.py``
+  auto-dispatches from ``stream_auto_min(causal)`` tokens (512 causal /
+  1024 non-causal on v5e).
 
 Numerics: scores and probabilities are fp32 (max-subtracted softmax); the
 probability·V contraction runs in the input dtype (bf16 on TPU) with fp32
@@ -536,7 +536,8 @@ def stream_attention(q, k, v, attn_mask, causal: bool = False,
 
 def _name_stream_residuals(out, lse):
     """Tag the attention output ``[B, T, n, d]`` and the forward kernel's
-    log-sum-exp ``[G, 1, T]`` for the ``selective`` recomputation policy.
+    log-sum-exp ``[G, 1, T]`` for the ``selective`` and ``full``
+    recomputation policies (``remat_names.FULL_SAVES``).
     A ``custom_vjp``'s residuals are saveable under
     ``save_only_these_names`` only by name; with both saved, the forward
     ``pallas_call`` of the rematerialised computation has no consumer and

@@ -1,14 +1,23 @@
-"""Names of the residuals the ``selective`` recomputation policy keeps.
+"""Names of the residuals the recomputation policies keep.
 
-``models/transformer.remat_wrap`` builds ``save_only_these_names`` from
-``SELECTIVE_SAVES``; the code that produces such a tensor tags it with
-``jax.ad_checkpoint.checkpoint_name`` under the constant below.  A name is
-listed when replaying its tensor costs a Pallas call or a whole matmul and
-the tensor is no larger than a block's hidden state (``FFN1``: the FFN
-width).  Which names exist in a program follows from the program: a pre-LN
-block has no ``POST_LN_SUM``, an XLA attention plan no ``ATTN_OUT`` /
-``ATTN_LSE``.  Bytes per layer, in the compute dtype (``rows`` = micro-batch
-x sequence, ``h`` hidden, ``ffn`` FFN width, ``n`` heads):
+``models/transformer.remat_wrap`` builds ``save_only_these_names`` from one
+of the two tuples below; the code that produces such a tensor tags it with
+``jax.ad_checkpoint.checkpoint_name`` under its constant.
+
+``FULL_SAVES``: ``full`` saves each layer application's input and the
+residuals of a Pallas kernel, and replays everything XLA computes.  The
+streaming kernel's output and log-sum-exp are its backward's own residuals
+and no XLA replay rebuilds them for less than the whole kernel call:
+``rows x h`` in the compute dtype + ``4 x rows x n`` bytes a layer.
+
+``SELECTIVE_SAVES``: those, and what costs a whole matmul to replay and is
+no larger than a block's hidden state (``FFN1``: the FFN width).
+
+Which names exist in a program follows from the program: a pre-LN block has
+no ``POST_LN_SUM``, an XLA attention plan no ``ATTN_OUT`` / ``ATTN_LSE``
+(under ``full`` it then saves the input alone).  Bytes per layer, in the
+compute dtype (``rows`` = micro-batch x sequence, ``h`` hidden, ``ffn`` FFN
+width, ``n`` heads):
 
 * ``QKV``          3 x rows x h   packed q/k/v projection output
 * ``FFN1``         rows x ffn     first FFN matmul, before the activation
@@ -27,4 +36,5 @@ ATTN_OUT = "attn_out"
 ATTN_LSE = "attn_lse"
 POST_LN_SUM = "post_ln_sum"
 
-SELECTIVE_SAVES = (QKV, FFN1, ATTN_OUT, ATTN_LSE, POST_LN_SUM)
+FULL_SAVES = (ATTN_OUT, ATTN_LSE)
+SELECTIVE_SAVES = (QKV, FFN1) + FULL_SAVES + (POST_LN_SUM,)

@@ -5,6 +5,8 @@ block.  Tiny sizes, CPU.  The comparison with the plain reference is in
 tests/benchmark_harness/test_bench_ouro.py."""
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,7 @@ from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import looped
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.observability import scopes
+from deepspeed_tpu.ops import pallas_attention as pattn
 from deepspeed_tpu.parallel.topology import make_mesh
 
 SEQ = 32
@@ -303,6 +306,37 @@ def test_bf16_loss_and_gradient_stay_near_float32(setting):
         assert g16["blocks"][name].dtype == jnp.bfloat16
         rel = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
         assert rel < 0.05, (name, rel)
+
+
+@pytest.mark.parametrize("policy", ["full", "selective"])
+def test_no_policy_replays_the_streaming_kernel(monkeypatch, policy):
+    """On the streaming plan (interpreter; seq 256 is the kernel's least) a
+    layer application costs two ``pallas_call``s, the forward and the fused
+    backward: the gradient's jaxpr holds the layer scan's body once forward
+    and once backward, and the rematerialised body has no third.  The saved
+    output and log-sum-exp are the values a replay would produce, so the
+    loss is that of recomputation off, bit for bit, and the gradients its
+    gradients to test_selective_remat.py's tolerance."""
+    monkeypatch.setattr(L, "attention_plan",
+                        lambda T, n, d, causal: ("stream", "stream"))
+    monkeypatch.setattr(pattn, "stream_attention", functools.partial(
+        pattn.stream_attention, interpret=True))
+    toks = np.random.default_rng(3).integers(
+        0, 512, size=(1, 257), dtype=np.int32)
+    batch = (toks[:, :-1].copy(), toks[:, 1:].copy())
+    run = lambda model: jax.value_and_grad(
+        lambda p: on_one_device(model.apply, p, *batch))
+    model = tiny(remat_policy=policy)
+    params = moved(model.init_params(jax.random.PRNGKey(0)))
+    text = str(jax.make_jaxpr(run(model))(params))
+    assert len(re.findall(r"\bpallas_call\b", text)) == 2
+    loss, got = run(model)(params)
+    want_loss, want = run(tiny(remat=False))(params)
+    assert float(loss) == float(want_loss)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------------------- the engine

@@ -360,17 +360,30 @@ def test_dispatch_rejects_block_then_stream():
         pattn.dispatch_attention(q, k, v, mask, False, "nope", "xla", True)
 
 
-# ------------------------------------- residuals named for selective remat
+# ------------------------------ residuals named for the recomputation policies
+
+def grad_jaxpr_text(wrap, attention):
+    """jaxpr text of the gradient of ``attention(q, k, v)`` as a scan body
+    that went through ``wrap``."""
+    body = wrap(lambda c, _: (attention(c, 2.0 * c, 3.0 * c), None))
+    q, _, _ = stream_qkv(seed=9)
+    return str(jax.make_jaxpr(
+        jax.grad(lambda q: jnp.sum(body(q, None)[0])))(q))
+
+
+def counter(text):
+    """How often a primitive occurs in a jaxpr's text."""
+    return lambda prim: len(re.findall(rf"\b{prim}\b", text))
+
+
+def under(policy):
+    """``transformer.remat_wrap`` under ``policy``, as a ``wrap``."""
+    cfg = types.SimpleNamespace(remat=True, remat_policy=policy)
+    return lambda body: remat_wrap(body, cfg)
+
 
 def grad_jaxpr_under(policy, attention):
-    """jaxpr text of the gradient of ``attention(q, k, v)`` as a scan body
-    wrapped by ``transformer.remat_wrap`` under ``policy``."""
-    body = remat_wrap(lambda c, _: (attention(c, 2.0 * c, 3.0 * c), None),
-                      types.SimpleNamespace(remat=True, remat_policy=policy))
-    q, _, _ = stream_qkv(seed=9)
-    text = str(jax.make_jaxpr(
-        jax.grad(lambda q: jnp.sum(body(q, None)[0])))(q))
-    return lambda prim: len(re.findall(rf"\b{prim}\b", text))
+    return counter(grad_jaxpr_text(under(policy), attention))
 
 
 ONES = jnp.ones((2, ST), jnp.float32)
@@ -380,28 +393,58 @@ NAMED_KERNELS = {
     "dispatch-stream-stream": lambda q, k, v: pattn.dispatch_attention(
         q, k, v, ONES, True, "stream", "stream", True),
 }
+XLA_FORWARD_AND_BACKWARD = {
+    "dispatch-xla-xla": lambda q, k, v: pattn.dispatch_attention(
+        q, k, v, ONES, True, "xla", "xla", True),
+    "xla_attention": lambda q, k, v: pattn.xla_attention(
+        q, k, v, ONES, True)[0],
+}
 
 
-@pytest.mark.parametrize("policy,calls", [("selective", 2), ("full", 3)])
+@pytest.mark.parametrize("policy,calls", [("selective", 2), ("full", 2)])
 @pytest.mark.parametrize("kernel", sorted(NAMED_KERNELS))
 def test_selective_remat_drops_the_replayed_forward_kernel(kernel, policy,
                                                            calls):
     """The forward kernel's output and log-sum-exp carry checkpoint names,
-    so under ``selective`` the backward pass holds the forward and the fused
-    backward ``pallas_call`` and no replay of the forward; ``full`` saves
-    nothing and runs it again."""
+    so under ``selective`` and under ``full`` the backward pass holds the
+    forward and the fused backward ``pallas_call`` and no replay of the
+    forward: no policy replays a Pallas call."""
     count = grad_jaxpr_under(policy, NAMED_KERNELS[kernel])
     assert count("pallas_call") == calls
 
 
-def test_selective_remat_keeps_an_xla_forward_for_a_stream_backward():
+@pytest.mark.parametrize("kernel", sorted(NAMED_KERNELS))
+def test_checkpoint_with_no_policy_would_replay_the_forward_kernel(kernel):
+    """What the names are for: ``jax.checkpoint`` with no policy (``full``
+    as it was) runs the forward ``pallas_call`` a second time."""
+    count = counter(grad_jaxpr_text(jax.checkpoint, NAMED_KERNELS[kernel]))
+    assert count("pallas_call") == 3
+
+
+@pytest.mark.parametrize("attention", sorted(XLA_FORWARD_AND_BACKWARD))
+def test_full_remat_on_the_xla_plan_is_checkpoint_with_no_policy(attention):
+    """A program on the XLA attention plan has neither name, so ``full``
+    saves the body's input alone: the gradient's jaxpr is that of
+    ``jax.checkpoint`` with no policy, but for the ``policy=`` parameter
+    the remat equation prints."""
+    fn = XLA_FORWARD_AND_BACKWARD[attention]
+    strip = lambda text: re.sub(r"policy=[^\n]*", "policy=", text)
+    full = grad_jaxpr_text(under("full"), fn)
+    assert "save_only_these_names" in full
+    assert strip(full) == strip(grad_jaxpr_text(jax.checkpoint, fn))
+
+
+def test_remat_keeps_an_xla_forward_for_a_stream_backward():
     """Mixed plan ("xla", "stream"): the same two names save the einsum
-    forward's output and log-sum-exp, so its two matmuls are not replayed."""
+    forward's output and log-sum-exp (the backward kernel's residuals), so
+    under ``selective`` and ``full`` its two matmuls are not replayed."""
     mixed = lambda q, k, v: pattn.dispatch_attention(
         q, k, v, ONES, True, "xla", "stream", True)
     sel, full = (grad_jaxpr_under(p, mixed) for p in ("selective", "full"))
+    none = counter(grad_jaxpr_text(jax.checkpoint, mixed))
     assert sel("pallas_call") == full("pallas_call") == 1
-    assert full("dot_general") - sel("dot_general") == 2
+    assert sel("dot_general") == full("dot_general")
+    assert none("dot_general") - sel("dot_general") == 2
 
 
 def test_attention_plan_directions(monkeypatch):

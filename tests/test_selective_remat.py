@@ -1,10 +1,12 @@
-"""What the ``selective`` recomputation policy keeps, read in the jaxpr.
+"""What the recomputation policies keep, read in the jaxpr.
 
-``transformer.remat_wrap`` saves the names of ``ops/remat_names.py``: the
-QKV and first FFN matmul outputs, the streaming kernel's output and
-log-sum-exp, and a post-LN block's two residual sums.  These tests count
-what the backward pass still replays, and pin that no policy moves the
-loss or a gradient.  (The kernel-call count is in test_pallas_attention.py.)
+``transformer.remat_wrap`` saves the names of ``ops/remat_names.py``:
+under ``selective`` the QKV and first FFN matmul outputs, the streaming
+kernel's output and log-sum-exp, and a post-LN block's two residual sums;
+under ``full`` the kernel's two alone.  These tests count what the backward
+pass still replays, and pin that no policy moves the loss or a gradient.
+(The kernel-call count is in test_pallas_attention.py and, for the looped
+model, in test_looped_model.py.)
 """
 
 import ast
@@ -18,7 +20,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu
-from deepspeed_tpu.models import GPT2, BertForPreTraining
+from deepspeed_tpu.models import GPT2, BertForPreTraining, LoopedLM
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import remat_names
 from deepspeed_tpu.parallel.topology import make_mesh
@@ -85,8 +87,10 @@ def lm_batch(rows=4, seed=0):
     return toks, labels
 
 
-FAMILIES = {"bert": (BertForPreTraining, bert_batch),
-            "gpt2": (GPT2, lm_batch)}
+#: family -> (model class, overrides of its "tiny" preset, batch maker)
+FAMILIES = {"bert": (BertForPreTraining, TINY, bert_batch),
+            "gpt2": (GPT2, TINY, lm_batch),
+            "ouro": (LoopedLM, {}, lm_batch)}
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
@@ -96,11 +100,11 @@ def test_loss_and_every_gradient_agree_under_every_policy(family):
     tolerance test_models.py pins loss trajectories to (a saved residual is
     the very value its replay produces; XLA orders the fused reductions of
     the three backward programs differently, by under 1e-8 absolute)."""
-    cls, make_batch = FAMILIES[family]
+    cls, tiny, make_batch = FAMILIES[family]
     batch = make_batch()
 
     def loss_and_grads(**remat):
-        model = cls.from_size("tiny", **TINY, **remat)
+        model = cls.from_size("tiny", **tiny, **remat)
         params = model.init_params(jax.random.PRNGKey(7))
         fn = one_device(lambda p, *b: model.apply(p, *b), 1 + len(batch))
         return jax.jit(jax.value_and_grad(fn))(params, *batch)
@@ -132,7 +136,8 @@ def calls_of(name):
 def test_selective_saves_is_the_only_list_of_the_names():
     """Every tagger names its tensor by a constant of ``remat_names`` (no
     string literal at a call site), every constant is in ``SELECTIVE_SAVES``
-    and has a tagger, and the one policy built from names takes the tuple."""
+    and has a tagger, ``FULL_SAVES`` is a part of it, and every policy built
+    from names takes one of the two tuples."""
     consts = {k: v for k, v in vars(remat_names).items()
               if k.isupper() and isinstance(v, str)}
     assert sorted(consts.values()) == sorted(remat_names.SELECTIVE_SAVES)
@@ -143,7 +148,12 @@ def test_selective_saves_is_the_only_list_of_the_names():
             f" got {ast.dump(args[1])}")
         used.add(consts[args[1].id])
     assert used == set(remat_names.SELECTIVE_SAVES)
+    assert set(remat_names.FULL_SAVES) < set(remat_names.SELECTIVE_SAVES)
     policies = list(calls_of("save_only_these_names"))
-    assert [path for path, _ in policies] == ["models/transformer.py"]
-    (arg,) = policies[0][1]
-    assert isinstance(arg, ast.Starred) and arg.value.id == "SELECTIVE_SAVES"
+    assert {path for path, _ in policies} == {"models/transformer.py"}
+    tuples = []
+    for _, args in policies:
+        (arg,) = args
+        assert isinstance(arg, ast.Starred), ast.dump(arg)
+        tuples.append(arg.value.id)
+    assert sorted(tuples) == ["FULL_SAVES", "SELECTIVE_SAVES"]
