@@ -409,7 +409,10 @@ class DeepSpeedTpuEngine:
         # failures inside shard_map)
         validate_fn = getattr(model, "validate", None)
         if validate_fn is not None:
-            validate_fn(self.mp_world_size)
+            # a model that is not built for every layout refuses the
+            # degree here, with a sentence
+            validate_fn(self.mp_world_size, self.sp_world_size,
+                        self.pp_world_size)
 
         # fail fast: context parallelism needs declared batch shardings
         # (the same error _batch_specs raises, but before the expensive

@@ -16,6 +16,7 @@ from deepspeed_tpu.models.moe import MoEConfig
 from deepspeed_tpu.models.bert import (BertForPreTraining,
                                        BertForQuestionAnswering, BERT_SIZES)
 from deepspeed_tpu.models.looped import LoopedConfig, LoopedLM, LOOPED_SIZES
+from deepspeed_tpu.models.hybrid import HybridConfig, HybridLM, HYBRID_SIZES
 
 __all__ = [
     "TransformerConfig", "init_block_params", "block_partition_specs",
@@ -24,4 +25,5 @@ __all__ = [
     "GPT2Pipelined", "GPT2MoE", "GPT2MoEPipelined", "MoEConfig",
     "BertForPreTraining", "BertForQuestionAnswering", "BERT_SIZES",
     "LoopedConfig", "LoopedLM", "LOOPED_SIZES",
+    "HybridConfig", "HybridLM", "HYBRID_SIZES",
 ]

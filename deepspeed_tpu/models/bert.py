@@ -117,8 +117,9 @@ class BertForPreTraining:
         return cls(T.TransformerConfig(**kw), use_nsp=use_nsp,
                    mlm_gather_budget=mlm_gather_budget)
 
-    def validate(self, mp_size: int = 1):
-        """Engine hook: shape checks against the actual mp degree."""
+    def validate(self, mp_size: int = 1, sp_size: int = 1, pp_size: int = 1):
+        """Engine hook: shape checks against the actual degrees (built for
+        every sp / pp degree, so only ``mp_size`` is read)."""
         self.config.validate(mp_size)
 
     def init_params(self, rng):
@@ -297,8 +298,9 @@ class BertForQuestionAnswering:
         kw.setdefault("causal", False)
         return cls(T.TransformerConfig(**kw))
 
-    def validate(self, mp_size: int = 1):
-        """Engine hook: shape checks against the actual mp degree."""
+    def validate(self, mp_size: int = 1, sp_size: int = 1, pp_size: int = 1):
+        """Engine hook: shape checks against the actual degrees (built for
+        every sp / pp degree, so only ``mp_size`` is read)."""
         self.config.validate(mp_size)
 
     def init_params(self, rng):

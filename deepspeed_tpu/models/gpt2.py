@@ -61,8 +61,9 @@ class GPT2:
         kw.setdefault("causal", True)
         return cls(T.TransformerConfig(**kw))
 
-    def validate(self, mp_size: int = 1):
-        """Engine hook: shape checks against the actual mp degree."""
+    def validate(self, mp_size: int = 1, sp_size: int = 1, pp_size: int = 1):
+        """Engine hook: shape checks against the actual degrees (built for
+        every sp / pp degree, so only ``mp_size`` is read)."""
         self.config.validate(mp_size)
 
     # ------------------------------------------------------------------ init

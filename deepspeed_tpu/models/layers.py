@@ -25,6 +25,7 @@ Conventions:
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 import jax
@@ -32,7 +33,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.observability import scopes as S
-from deepspeed_tpu.ops.remat_names import QKV
+from deepspeed_tpu.ops.remat_names import MIXER_IN, QKV
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, SEQ_AXIS
 
 # Pallas attention dispatch (DSTPU_FUSED_ATTN = "auto" | "1" | "0").
@@ -580,17 +581,21 @@ def extend_multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local,
         k_pool, v_pool
 
 
-def attention_plan(T, n, d, causal):
+def attention_plan(T, n, d, causal, window=None, kv_heads=None):
     """(fwd_impl, bwd_impl), each in {"xla", "block", "stream"}, for the
     current backend/mode — the per-direction dispatch table.  Forward and
     backward resolve independently under "auto" (their crossovers differ);
-    "1" forces one kernel for both, "0" / non-TPU yields ("xla", "xla")."""
+    "1" forces one kernel for both, "0" / non-TPU yields ("xla", "xla").
+    A sliding ``window`` or fewer k/v heads than the ``n`` query heads
+    (``kv_heads``, the smaller count of the two) rule the whole-tile kernel
+    out: the streaming kernel and the XLA path take them."""
     mode = _attn_mode()
     if mode == "0" or jax.default_backend() != "tpu":
         return "xla", "xla"
     from deepspeed_tpu.ops import pallas_attention as pattn
     stream_ok = pattn.stream_supported(T, d)
-    block_ok = pattn.supported(T, n, d)
+    block_ok = (pattn.supported(T, n, d) and window is None
+                and kv_heads in (None, n))
     if mode == "1":
         impl = "stream" if stream_ok else ("block" if block_ok else "xla")
         return impl, impl
@@ -611,31 +616,37 @@ def attention_plan(T, n, d, causal):
     return fwd, bwd
 
 
-def core_attention(q, k, v, *, causal, attn_mask=None):
+def core_attention(q, k, v, *, causal, attn_mask=None, window=None):
     """Single-device attention on [B, T, n, d] q/k/v with the per-direction
     kernel dispatch table (``attention_plan``): streaming Pallas kernel from
     the calibrated threshold, whole-tile kernel for short causal shapes (or
     under force mode), XLA einsum otherwise — forward and backward chosen
     independently.  ``attn_mask``: optional [B, T] float/int, 1 = attend.
+    ``k``/``v`` may hold fewer heads than ``q`` (consecutive query heads
+    share one) and ``v`` a wider head; ``window``: causal sliding window,
+    key s visible to query t iff ``t - window < s <= t``
+    (``ops.pallas_attention.stream_attention``).
     Shared by the plain path and Ulysses sequence parallelism (which
     calls it on the all-to-all'd full-sequence view — so long-context
     kernels and sequence sharding compose)."""
     B, T, n, d = q.shape
-    fwd_impl, bwd_impl = attention_plan(T, n, d, causal)
+    fwd_impl, bwd_impl = attention_plan(
+        T, n, d, causal, window=window,
+        kv_heads=min(k.shape[2], v.shape[2]))
     from deepspeed_tpu.ops import pallas_attention as pattn
     mvec = (jnp.ones((B, T), jnp.float32) if attn_mask is None
             else attn_mask.astype(jnp.float32))
     if fwd_impl == bwd_impl == "stream":
-        return pattn.stream_attention(q, k, v, mvec, causal)
+        return pattn.stream_attention(q, k, v, mvec, causal, window=window)
     if fwd_impl == bwd_impl == "block":
         return pattn.fused_attention(q, k, v, mvec, causal)
     if (fwd_impl, bwd_impl) == ("xla", "xla"):
         # single source of the reference einsum math (fp32 MXU
         # accumulation, masked softmax) — also the hybrid paths' "xla"
         # side, so the threshold branches can never drift numerically
-        return pattn.xla_attention(q, k, v, mvec, causal)[0]
+        return pattn.xla_attention(q, k, v, mvec, causal, window=window)[0]
     return pattn.dispatch_attention(q, k, v, mvec, causal,
-                                    fwd_impl, bwd_impl)
+                                    fwd_impl, bwd_impl, window=window)
 
 
 @S.scoped("attn")
@@ -720,3 +731,136 @@ def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
     else:
         ctx = core_attention(q, k, v, causal=causal, attn_mask=attn_mask)
     return row_parallel_linear(ctx.reshape(B, T, -1), wo_local, axis=axis)
+
+
+# ------------------------------------------------ hybrid (SSM / attention)
+# The mixers of a decoder-hybrid-decoder stack (models/hybrid.py):
+# differential attention over shared key/value heads — self (full or
+# sliding-window) and on ANOTHER layer's keys and values — the Gated Memory
+# Unit, and the Mamba-1 mixer.  Tensor parallelism by heads (whole groups of
+# four query heads, below) and by the state-space channels ``E``.
+
+def differential_lambda(p, lam_init):
+    """``exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init`` (fp32 scalar) from a
+    layer's four learned vectors."""
+    f = lambda a, b: jnp.exp(jnp.sum(p[a].astype(jnp.float32)
+                                     * p[b].astype(jnp.float32)))
+    return f("lam_q1", "lam_k1") - f("lam_q2", "lam_k2") + lam_init
+
+
+def _differential_context(q, k, v, p, lam_init, eps, window, core_scope):
+    """The differential combination around ONE ``core_attention`` call.
+
+    Heads of ``head_dim`` pair up: pair ``P`` of query heads, sub-head ``s``,
+    attends key head ``(P // 2, s)`` and the pair's value is ``[v_(P//2, 1) |
+    v_(P//2, 2)]``, ``2 * head_dim`` wide; ``o_P = (P_1 - lambda P_2) V``.
+    Laid out for the kernels' "consecutive query heads share a head": query
+    head ``4g + 2s + r`` is sub-head ``s`` of pair ``2g + r``, key head ``2g
+    + s`` (shared by two query heads), value head ``g`` (shared by four) —
+    no new softmax, one kernel call per direction, ``P_2``'s part
+    subtracted here.  Then a per-pair RMSNorm with a learned scale and
+    ``(1 - lam_init)``.  q [B, T, 4G, d], k [B, T, 2G, d], v [B, T, G, 2d]
+    -> [B, T, 2G * 2d]."""
+    B, T, n, _ = q.shape
+    with S.scope(core_scope) if core_scope else contextlib.nullcontext():
+        ctx = core_attention(q, k, v, causal=True, window=window)
+    lam = differential_lambda(p, lam_init)
+    ctx = ctx.reshape(B, T, n // 4, 2, 2, ctx.shape[-1])
+    o = (ctx[:, :, :, 0].astype(jnp.float32)
+         - lam * ctx[:, :, :, 1].astype(jnp.float32)).astype(q.dtype)
+    o = rms_norm(o, p["subln_s"], eps)
+    return (o * (1.0 - lam_init).astype(o.dtype)).reshape(B, T, -1)
+
+
+def _no_sequence_shards(what):
+    if axis_size_or_1(SEQ_AXIS) > 1:
+        raise ValueError(
+            f"{what} is not built for context parallelism: a window, a "
+            f"shared key/value hand-over and a state-space scan across "
+            f"sequence shards need their state passed between the shards")
+
+
+@S.scoped("attn")
+def differential_attention(x, p, *, head_dim, lam_init, eps, window=None):
+    """Differential self-attention (arXiv:2410.05258) over shared key/value
+    heads, causal, full or under a sliding ``window``.
+
+    x [B, T, h] replicated over ``model``; ``p``: ``q_w`` [h, 4G d / mp],
+    ``k_w`` and ``v_w`` [h, 2G d / mp] column-parallel, ``o_w`` [2G 2d / mp,
+    h] row-parallel, ``lam_q1/k1/q2/k2`` [d], ``subln_s`` [2d].  Returns
+    ``(out, k, v)``: k [B, T, 2G/mp, d] and v [B, T, G/mp, 2d] as another
+    layer's ``shared_kv_attention`` reads them.  The windowed core runs
+    under ``dstpu/swa``."""
+    _no_sequence_shards("differential_attention")
+    B, T, _ = x.shape
+    q, k, v = (checkpoint_name(column_parallel_linear(x, p[w]), QKV)
+               for w in ("q_w", "k_w", "v_w"))
+    q = q.reshape(B, T, -1, head_dim)
+    k = k.reshape(B, T, -1, head_dim)
+    v = v.reshape(B, T, -1, 2 * head_dim)
+    ctx = _differential_context(q, k, v, p, lam_init, eps, window,
+                                "swa" if window is not None else None)
+    return row_parallel_linear(ctx, p["o_w"]), k, v
+
+
+@S.scoped("attn")
+def shared_kv_attention(x, p, k, v, *, head_dim, lam_init, eps):
+    """Cross-decoder attention: differential attention whose keys and
+    values are ANOTHER layer's (``differential_attention``'s second and
+    third results), causal over the whole sequence.  ``p`` has ``q_w``,
+    ``o_w``, its own four ``lam_*`` vectors and ``subln_s``, and no k/v
+    projection; the gradient of ``k``/``v`` flows to the layer that made
+    them, summed over the layers that read them.  The core runs under
+    ``dstpu/xattn``."""
+    _no_sequence_shards("shared_kv_attention")
+    B, T, _ = x.shape
+    q = checkpoint_name(column_parallel_linear(x, p["q_w"]), QKV)
+    ctx = _differential_context(q.reshape(B, T, -1, head_dim), k, v, p,
+                                lam_init, eps, None, "xattn")
+    return row_parallel_linear(ctx, p["o_w"])
+
+
+@S.scoped("gmu")
+def gated_memory_unit(x, memory, p):
+    """``(memory * silu(x W1)) W2``: a gate on ANOTHER layer's state —
+    ``memory`` [B, T, E/mp] is a Mamba layer's scan output (``mamba_mixer``'s
+    second result); ``w1`` [h, E/mp] column-parallel, ``w2`` [E/mp, h]
+    row-parallel."""
+    gate = silu(checkpoint_name(column_parallel_linear(x, p["w1"]), MIXER_IN))
+    return row_parallel_linear(memory.astype(gate.dtype) * gate, p["w2"])
+
+
+def softplus(x):
+    xf = x.astype(jnp.float32)
+    return jax.nn.softplus(xf).astype(x.dtype)
+
+
+@S.scoped("ssm")
+def mamba_mixer(x, p, *, state, dt_rank):
+    """Mamba-1 mixer.  ``[u, z] = x W_in``; ``u = silu(conv(u))``; ``[r, B,
+    C] = u W_x``; ``delta = softplus(r W_dt + b_dt)``; ``y =
+    selective_scan(u, delta, -exp(A_log), B, C, D)``; ``out = (y * silu(z))
+    W_out``.  Returns ``(out, y)``: ``y`` [B, T, E/mp], the scan's output
+    before the gate, is what a ``gated_memory_unit`` reads.
+
+    Sharded over the channels ``E``: ``in_u_w``/``in_z_w`` [h, E/mp] and
+    ``dt_w`` [R, E/mp] column-parallel, ``x_w`` [E/mp, R + 2N] and ``out_w``
+    [E/mp, h] row-parallel (so r, B and C are whole on every shard),
+    ``conv_w`` [K, E/mp], ``conv_b``/``dt_b``/``D`` [E/mp], ``A_log`` [E/mp,
+    N].  The recurrence runs in float32 (ops/selective_scan.py) under
+    ``dstpu/scan``, the convolution under ``dstpu/conv``."""
+    from deepspeed_tpu.ops.selective_scan import causal_conv1d, selective_scan
+    _no_sequence_shards("mamba_mixer")
+    # named for the "selective" policy, like an attention layer's q, k, v
+    u = checkpoint_name(column_parallel_linear(x, p["in_u_w"]), MIXER_IN)
+    z = checkpoint_name(column_parallel_linear(x, p["in_z_w"]), MIXER_IN)
+    with S.scope("conv"):
+        u = silu(causal_conv1d(u, p["conv_w"], p["conv_b"]))
+    rbc = row_parallel_linear(u, p["x_w"])
+    r, b, c = (rbc[..., :dt_rank], rbc[..., dt_rank:dt_rank + state],
+               rbc[..., dt_rank + state:])
+    delta = softplus(column_parallel_linear(r, p["dt_w"], p["dt_b"]))
+    with S.scope("scan"):
+        y = selective_scan(u, delta, -jnp.exp(p["A_log"].astype(jnp.float32)),
+                           b, c, p["D"])
+    return row_parallel_linear(y * silu(z), p["out_w"]), y

@@ -128,7 +128,9 @@ class LoopedLM:
     def from_size(cls, size: str, **overrides) -> "LoopedLM":
         return cls(LoopedConfig(**{**LOOPED_SIZES[size], **overrides}))
 
-    def validate(self, mp_size: int = 1):
+    def validate(self, mp_size: int = 1, sp_size: int = 1, pp_size: int = 1):
+        """Engine hook: shape checks against the actual degrees (built for
+        every sp / pp degree, so only ``mp_size`` is read)."""
         self.config.validate(mp_size)
 
     def step_counts(self) -> dict:

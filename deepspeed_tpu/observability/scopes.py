@@ -44,6 +44,12 @@ SCOPES = (
     # a looped (weight-shared depth) model: the pass loop, the rotation of
     # q and k inside ``attn``, and the exit gate's part of the loss
     "loop", "rope", "exit",
+    # a hybrid (state-space / attention) stack: the whole Mamba mixer with
+    # its scan and its convolution inside, the windowed and the
+    # cross-decoder attention cores inside ``attn``, and the Gated Memory
+    # Unit (the hand-over of the memory and the shared keys and values is
+    # slices of an axis of length one: no instruction, so no scope)
+    "ssm", "scan", "conv", "swa", "xattn", "gmu",
 )
 
 FORWARD, BACKWARD, REPLAY = "forward", "backward", "replay"

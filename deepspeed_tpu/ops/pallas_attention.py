@@ -265,19 +265,63 @@ def stream_supported(seq_len: int, head_dim: int) -> bool:
             and head_dim % 8 == 0)
 
 
-def _tile_mask(s, mask, causal, i, j, qt, kt):
-    """Apply the kv padding mask [gb, kt] and the causal band to a
-    [gb, qt, kt] score tile at (query tile i, kv tile j)."""
+def _tile_mask(s, mask, causal, i, j, qt, kt, window=None):
+    """Apply the kv padding mask [gb, kt], the causal band and, under a
+    sliding ``window``, its far edge (key ``s`` visible to query ``t`` iff
+    ``t - window < s <= t``) to a [gb, qt, kt] score tile at (query tile i,
+    kv tile j)."""
     s = jnp.where(mask[:, None, :] != 0, s, -1e9)
     if causal:
         qpos = i * qt + jax.lax.broadcasted_iota(jnp.int32, (qt, kt), 0)
         kpos = j * kt + jax.lax.broadcasted_iota(jnp.int32, (qt, kt), 1)
         s = jnp.where((kpos <= qpos)[None], s, -1e9)
+        if window is not None:
+            s = jnp.where((kpos > qpos - window)[None], s, -1e9)
     return s
 
 
+def _window_tiles(window, tile, n_tiles):
+    """Length of the kv axis of a windowed call's grid: the kv tiles one
+    query tile can see (query and kv tiles of one size), e.g. 2 of the 16 at
+    T 8192, window 512, tile 512.  The whole axis without a window."""
+    if window is None:
+        return n_tiles
+    return min(n_tiles, -(-(window - 1) // tile) + 1)
+
+
+def _when_visible(update, causal, window, i, j, qt, kt, n_tiles):
+    """Run ``update`` unless the (query tile i, kv tile j) pair is masked
+    whole: past the causal diagonal, out of the window, or — a windowed
+    grid's offset index at the sequence's ends — no tile at all."""
+    if window is not None:
+        pl.when((j >= 0) & (i <= n_tiles - 1)
+                & (j * kt <= (i + 1) * qt - 1)
+                & ((j + 1) * kt - 1 > i * qt - window))(update)
+    elif causal:
+        # a tile whose first kv position is past the last query position is
+        # fully masked: skip its compute entirely (GPT-style models pay for
+        # only the lower-triangular half of the tile grid)
+        pl.when(j * kt <= (i + 1) * qt - 1)(update)
+    else:
+        update()
+
+
+def _shared_heads(x, gb):
+    """A k or v block [hb, kt, d] for the ``gb`` query heads of the
+    program: as it is where every query head has its own, the one head
+    broadcast where the ``gb`` query heads share it."""
+    if x.shape[0] == gb:
+        return x
+    return jnp.broadcast_to(x, (gb,) + x.shape[1:])
+
+
 def _stream_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                       m_scr, l_scr, acc_scr, *, causal, scale, nk):
+                       m_scr, l_scr, acc_scr, *, causal, scale, nk,
+                       window=None, n_tiles=None):
+    """``nk``: length of the grid's kv axis.  Under a ``window`` that axis
+    holds only the tiles a query tile can see and step ``j`` of it is kv
+    tile ``i - (nk - 1) + j`` of the ``n_tiles`` (the index maps say the
+    same, so an out-of-window tile is not fetched either)."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -289,12 +333,16 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     i = pl.program_id(1)
     qt = q_ref.shape[1]
     kt = k_ref.shape[1]
+    gb = q_ref.shape[0]
+    jt = j if window is None else i - (nk - 1) + j      # the kv tile
 
     def update():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        q = q_ref[...]
+        k, v = _shared_heads(k_ref[...], gb), _shared_heads(v_ref[...], gb)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        s = _tile_mask(s, mask_ref[...][:, 0, :], causal, i, j, qt, kt)
+        s = _tile_mask(s, mask_ref[...][:, 0, :], causal, i, jt, qt, kt,
+                       window)
         m_old = m_scr[...]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, :, None])
@@ -307,13 +355,7 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                             preferred_element_type=jnp.float32))
         m_scr[...] = m_new
 
-    if causal:
-        # a tile whose first kv position is past the last query position is
-        # fully masked: skip its compute entirely (GPT-style models pay for
-        # only the lower-triangular half of the tile grid)
-        pl.when(j * kt <= (i + 1) * qt - 1)(update)
-    else:
-        update()
+    _when_visible(update, causal, window, i, jt, qt, kt, n_tiles)
 
     @pl.when(j == nk - 1)
     def _fin():
@@ -324,13 +366,14 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                         + jnp.log(jnp.maximum(l, 1e-30)))[:, None, :]
 
 
-def _recompute_p_ds(q, k, v, do, lse, delta, mask, causal, i, j, scale):
+def _recompute_p_ds(q, k, v, do, lse, delta, mask, causal, i, j, scale,
+                    window=None):
     """Shared backward tile math: probabilities from the logsumexp, then
     dS (scale folded in).  Returns (p, ds) fp32 [gb, qt, kt]."""
     qt, kt = q.shape[1], k.shape[1]
     s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
-    s = _tile_mask(s, mask, causal, i, j, qt, kt)
+    s = _tile_mask(s, mask, causal, i, j, qt, kt, window)
     p = jnp.exp(s - lse[:, :, None])
     dp = jax.lax.dot_general(do, v, (((2,), (2,)), ((0,), (0,))),
                              preferred_element_type=jnp.float32)
@@ -340,7 +383,11 @@ def _recompute_p_ds(q, k, v, do, lse, delta, mask, causal, i, j, scale):
 
 def _stream_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                        delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                       *, causal, scale, nq):
+                       *, causal, scale, nq, window=None, n_tiles=None):
+    """``nq``: length of the grid's query axis; under a ``window`` step
+    ``i`` of it is query tile ``j + i`` (the tiles that see kv tile j).
+    dK and dV come out per QUERY head: where query heads share a k or v
+    head the caller sums them."""
     i = pl.program_id(2)     # query tile (innermost)
     j = pl.program_id(1)     # kv tile
 
@@ -351,13 +398,17 @@ def _stream_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
     qt = q_ref.shape[1]
     kt = k_ref.shape[1]
+    gb = q_ref.shape[0]
+    it = i if window is None else j + i                  # the query tile
 
     def update():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        q = q_ref[...]
+        k, v = _shared_heads(k_ref[...], gb), _shared_heads(v_ref[...], gb)
         do = do_ref[...]
         p, ds = _recompute_p_ds(q, k, v, do, lse_ref[...][:, 0, :],
                                 delta_ref[...][:, 0, :],
-                                mask_ref[...][:, 0, :], causal, i, j, scale)
+                                mask_ref[...][:, 0, :], causal, it, j, scale,
+                                window)
         cdt = q.dtype
         bdims = ((0,), (0,))
         # contract the QUERY axis: dK += dS^T q ; dV += P^T dO
@@ -368,10 +419,7 @@ def _stream_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             p.astype(cdt), do, (((1,), (1,)), bdims),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(j * kt <= (i + 1) * qt - 1)(update)
-    else:
-        update()
+    _when_visible(update, causal, window, it, j, qt, kt, n_tiles)
 
     @pl.when(i == nq - 1)
     def _fin():
@@ -382,14 +430,17 @@ def _stream_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 def _stream_bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
                              delta_ref, dq_ref, dk_ref, dv_ref,
                              dq_scr, dk_scr, dv_scr,
-                             *, causal, scale, nq, nk):
+                             *, causal, scale, nq, nk, window=None,
+                             n_tiles=None):
     """Single-pass backward: one sweep of the (kv tile j, query tile i)
     grid produces dQ, dK AND dV.  The two-kernel split recomputes the
     score tile (QK^T, exp, dP) once per kernel — 7 T²d matmul passes
     total; fusing drops that to 5 and halves the q/k/v/do tile DMAs.
     dK/dV accumulate per parked kv tile (query innermost, as before);
     dQ accumulates into a full-sequence fp32 scratch sliced at the
-    query-tile offset, written out on the final grid step."""
+    query-tile offset, written out on the final grid step.  ``nq``/``nk``:
+    lengths of the grid's axes; under a ``window`` step ``i`` of the query
+    axis is query tile ``j + i``, as in ``_stream_dkv_kernel``."""
     i = pl.program_id(2)     # query tile (innermost)
     j = pl.program_id(1)     # kv tile
 
@@ -404,13 +455,17 @@ def _stream_bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
     qt = q_ref.shape[1]
     kt = k_ref.shape[1]
+    gb = q_ref.shape[0]
+    it = i if window is None else j + i                  # the query tile
 
     def update():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        q = q_ref[...]
+        k, v = _shared_heads(k_ref[...], gb), _shared_heads(v_ref[...], gb)
         do = do_ref[...]
         p, ds = _recompute_p_ds(q, k, v, do, lse_ref[...][:, 0, :],
                                 delta_ref[...][:, 0, :],
-                                mask_ref[...][:, 0, :], causal, i, j, scale)
+                                mask_ref[...][:, 0, :], causal, it, j, scale,
+                                window)
         cdt = q.dtype
         dsc = ds.astype(cdt)
         bdims = ((0,), (0,))
@@ -426,13 +481,10 @@ def _stream_bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
             dsc, k, (((2,), (1,)), bdims),
             preferred_element_type=jnp.float32)
         # Mosaic wants the dynamic sublane offset proven tile-aligned
-        rows = pl.ds(pl.multiple_of(i * qt, qt), qt)
+        rows = pl.ds(pl.multiple_of(it * qt, qt), qt)
         dq_scr[:, rows, :] += dq_blk
 
-    if causal:
-        pl.when(j * kt <= (i + 1) * qt - 1)(update)
-    else:
-        update()
+    _when_visible(update, causal, window, it, j, qt, kt, n_tiles)
 
     @pl.when(i == nq - 1)
     def _fin_dkv():
@@ -445,7 +497,10 @@ def _stream_bwd_fused_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
 
 def _stream_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
-                      delta_ref, dq_ref, dq_scr, *, causal, scale, nk):
+                      delta_ref, dq_ref, dq_scr, *, causal, scale, nk,
+                      window=None, n_tiles=None):
+    """``nk``: length of the grid's kv axis, offset under a ``window`` as
+    in ``_stream_fwd_kernel``."""
     j = pl.program_id(2)     # kv tile (innermost)
     i = pl.program_id(1)     # query tile
 
@@ -455,21 +510,21 @@ def _stream_dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
 
     qt = q_ref.shape[1]
     kt = k_ref.shape[1]
+    gb = q_ref.shape[0]
+    jt = j if window is None else i - (nk - 1) + j      # the kv tile
 
     def update():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        q = q_ref[...]
+        k, v = _shared_heads(k_ref[...], gb), _shared_heads(v_ref[...], gb)
         _, ds = _recompute_p_ds(q, k, v, do_ref[...], lse_ref[...][:, 0, :],
                                 delta_ref[...][:, 0, :],
-                                mask_ref[...][:, 0, :], causal, i, j,
-                                scale)
+                                mask_ref[...][:, 0, :], causal, i, jt,
+                                scale, window)
         dq_scr[...] += jax.lax.dot_general(
             ds.astype(q.dtype), k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(j * kt <= (i + 1) * qt - 1)(update)
-    else:
-        update()
+    _when_visible(update, causal, window, i, jt, qt, kt, n_tiles)
 
     @pl.when(j == nk - 1)
     def _fin():
@@ -491,46 +546,99 @@ def _unfold_gtd(x, B, n):
     return jnp.moveaxis(x.reshape(B, n, T, d), 1, 2)
 
 
-def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret):
+def _head_group(n_q: int, n_kv: int, gb: int) -> int:
+    """Query heads per k (or v) head, checked against the ``gb`` query heads
+    a program takes: a kv block is those ``gb`` heads' own (group 1) or the
+    one head they share."""
+    group, rest = divmod(n_q, n_kv)
+    if rest or (group > 1 and group % gb):
+        raise ValueError(
+            f"{n_q} query heads over {n_kv} key/value heads: the streaming "
+            f"kernel takes whole groups of query heads, {gb} heads a program")
+    return group
+
+
+def _kv_spec(x, gb, group, kt, tile_of):
+    """BlockSpec of a folded k or v operand ``x`` [G / group, T, d]:
+    ``tile_of(a, b)`` is the kv tile at the grid's two inner indices; the
+    heads are the program's own ``gb`` or, where ``group`` query heads share
+    one, that one (found by index map: nothing is repeated in HBM)."""
+    d = x.shape[-1]
+    if group == 1:
+        return pl.BlockSpec((gb, kt, d),
+                            lambda g, a, b: (g, tile_of(a, b), 0))
+    return pl.BlockSpec((1, kt, d),
+                        lambda g, a, b: (g * gb // group, tile_of(a, b), 0))
+
+
+def _check_window(window, causal):
+    if window is not None and not (causal and window >= 1):
+        raise ValueError(f"a sliding window (got {window!r}) is a bound on "
+                         f"a causal mask: key s is visible to query t iff "
+                         f"t - window < s <= t")
+
+
+def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None):
+    _check_window(window, causal)
     B, T, n, d = q.shape
+    dv = v.shape[-1]
     G = B * n
     gb = _stream_gb(G)
+    k_group = _head_group(n, k.shape[2], gb)
+    v_group = _head_group(n, v.shape[2], gb)
     qt = kt = _stream_tile(T)
-    nq, nk = T // qt, T // kt
+    nq, n_tiles = T // qt, T // kt
+    nk = _window_tiles(window, kt, n_tiles)
     scale = 1.0 / (d ** 0.5)
     qg, kg, vg = _fold_gtd(q), _fold_gtd(k), _fold_gtd(v)
     maskg = _mask_gtd(attn_mask, B, T, n)
+    if window is None:
+        kv_tile = lambda i, j: j
+    else:
+        # step j of the kv axis is tile i - (nk - 1) + j; before the
+        # sequence's start the first tile stays parked and the kernel skips
+        kv_tile = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
     q_spec = pl.BlockSpec((gb, qt, d), lambda g, i, j: (g, i, 0))
-    kv_spec = pl.BlockSpec((gb, kt, d), lambda g, i, j: (g, j, 0))
+    o_spec = pl.BlockSpec((gb, qt, dv), lambda g, i, j: (g, i, 0))
+    k_spec = _kv_spec(kg, gb, k_group, kt, kv_tile)
+    v_spec = _kv_spec(vg, gb, v_group, kt, kv_tile)
     # row vectors ride as [G, 1, T]: Mosaic wants the last two block
     # dims (8, 128)-tileable or equal to the array dims
-    mask_spec = pl.BlockSpec((gb, 1, kt), lambda g, i, j: (g, 0, j))
+    mask_spec = pl.BlockSpec((gb, 1, kt),
+                             lambda g, i, j: (g, 0, kv_tile(i, j)))
     row_spec = pl.BlockSpec((gb, 1, qt), lambda g, i, j: (g, 0, i))
+    windowed = ({} if window is None
+                else {"window": window, "n_tiles": n_tiles})
     o, lse = pl.pallas_call(
         functools.partial(_stream_fwd_kernel, causal=causal, scale=scale,
-                          nk=nk),
-        out_shape=(jax.ShapeDtypeStruct((G, T, d), q.dtype),
+                          nk=nk, **windowed),
+        out_shape=(jax.ShapeDtypeStruct((G, T, dv), q.dtype),
                    jax.ShapeDtypeStruct((G, 1, T), jnp.float32)),
         grid=(G // gb, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=(q_spec, row_spec),
+        in_specs=[q_spec, k_spec, v_spec, mask_spec],
+        out_specs=(o_spec, row_spec),
         scratch_shapes=[pltpu.VMEM((gb, qt), jnp.float32),
                         pltpu.VMEM((gb, qt), jnp.float32),
-                        pltpu.VMEM((gb, qt, d), jnp.float32)],
+                        pltpu.VMEM((gb, qt, dv), jnp.float32)],
         interpret=interpret,
     )(qg, kg, vg, maskg)
     return o, lse, (qg, kg, vg, maskg)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def stream_attention(q, k, v, attn_mask, causal: bool = False,
-                     interpret: bool = False):
+                     interpret: bool = False, window=None):
     """Streaming (online-softmax) attention for long sequences.
 
-    q/k/v: [B, T, n, d]; attn_mask: [B, T] float (1 = attend).  Returns
-    [B, T, n, d] context; callers gate on ``stream_supported(T, d)``."""
+    q: [B, T, n, d]; k: [B, T, n / gk, d] and v: [B, T, n / gv, dv], where
+    ``gk`` (``gv``) consecutive query heads share a key (value) head and a
+    value head may be wider than a key head; attn_mask: [B, T] float (1 =
+    attend).  ``window``: key s is visible to query t iff ``t - window < s
+    <= t`` (needs ``causal``); tiles out of the window are neither computed
+    nor fetched.  Returns [B, T, n, dv] context; callers gate on
+    ``stream_supported(T, d)``."""
     B, T, n, d = q.shape
-    o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret)
+    o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window)
     return _unfold_gtd(o, B, n)
 
 
@@ -550,10 +658,10 @@ def _name_stream_residuals(out, lse):
     return checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
 
 
-def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret):
+def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret, window=None):
     B, T, n, d = q.shape
     o, lse, (qg, kg, vg, maskg) = _stream_fwd_impl(q, k, v, attn_mask,
-                                                   causal, interpret)
+                                                   causal, interpret, window)
     out, lse = _name_stream_residuals(_unfold_gtd(o, B, n), lse)
     return out, (qg, kg, vg, maskg, _fold_gtd(out), lse, B, n)
 
@@ -579,82 +687,121 @@ def _fused_bwd_fits(gb: int, T: int, d: int, itemsize: int) -> bool:
             <= VMEM_SCOPED_LIMIT)
 
 
-def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret):
-    """Streaming backward on folded [G, T, d] operands → (dq, dk, dv),
-    same layout.  Fused single pass where ``_fused_bwd_fits`` says its
-    dQ-resident buffers fit VMEM, the two-kernel split otherwise."""
+def _sum_shared(dx, like):
+    """Per-query-head gradients [G, T, d] of a k or v operand summed over
+    the query heads that share a head of ``like`` [G / group, T, d] (fp32
+    sum, ``like``'s dtype); as they are where none is shared."""
+    group = dx.shape[0] // like.shape[0]
+    if group == 1:
+        return dx
+    return jnp.sum(dx.reshape(like.shape[0], group, *dx.shape[1:])
+                   .astype(jnp.float32), axis=1).astype(like.dtype)
+
+
+def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
+                     window=None):
+    """Streaming backward on folded operands (q [G, T, d]; k, v with G /
+    group heads, v ``dv`` wide) → (dq, dk, dv), same layouts.  Fused single
+    pass where ``_fused_bwd_fits`` says its dQ-resident buffers fit VMEM,
+    the two-kernel split otherwise.  The kernels give dK and dV per query
+    head; ``_sum_shared`` folds the heads that share one."""
     G, T, d = qg.shape
+    dv_ = vg.shape[-1]
     gb = _stream_gb(G)
+    k_group, v_group = G // kg.shape[0], G // vg.shape[0]
     qt = kt = _stream_tile(T)
-    nq, nk = T // qt, T // kt
+    n_tiles = T // qt
+    nq = nk = n_tiles
+    # a window shortens the INNER axis of each grid to the tiles that can
+    # see each other; the outer axis walks the whole sequence
+    inner = _window_tiles(window, kt, n_tiles)
     scale = 1.0 / (d ** 0.5)
     delta = jnp.sum(dog.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                    # [G, 1, T]
+    windowed = ({} if window is None
+                else {"window": window, "n_tiles": n_tiles})
+    if window is None:
+        q_tile = lambda j, i: i
+        kv_tile = lambda i, j: j
+    else:
+        q_tile = lambda j, i: jnp.minimum(j + i, n_tiles - 1)
+        kv_tile = lambda i, j: jnp.maximum(i - (inner - 1) + j, 0)
     # grid (G, kv tile, query tile) — query innermost, kv parked
     kv_spec_o = pl.BlockSpec((gb, kt, d), lambda g_, j, i: (g_, j, 0))
+    dv_spec_o = pl.BlockSpec((gb, kt, dv_), lambda g_, j, i: (g_, j, 0))
+    k_spec_o = _kv_spec(kg, gb, k_group, kt, lambda j, i: j)
+    v_spec_o = _kv_spec(vg, gb, v_group, kt, lambda j, i: j)
     mask_spec_o = pl.BlockSpec((gb, 1, kt), lambda g_, j, i: (g_, 0, j))
-    q_spec_o = pl.BlockSpec((gb, qt, d), lambda g_, j, i: (g_, i, 0))
-    row_spec_o = pl.BlockSpec((gb, 1, qt), lambda g_, j, i: (g_, 0, i))
+    q_spec_o = pl.BlockSpec((gb, qt, d),
+                            lambda g_, j, i: (g_, q_tile(j, i), 0))
+    do_spec_o = pl.BlockSpec((gb, qt, dv_),
+                             lambda g_, j, i: (g_, q_tile(j, i), 0))
+    row_spec_o = pl.BlockSpec((gb, 1, qt),
+                              lambda g_, j, i: (g_, 0, q_tile(j, i)))
+    dkv_shapes = (jax.ShapeDtypeStruct((G, T, d), kg.dtype),
+                  jax.ShapeDtypeStruct((G, T, dv_), vg.dtype))
+    dkv_scratch = [pltpu.VMEM((gb, kt, d), jnp.float32),
+                   pltpu.VMEM((gb, kt, dv_), jnp.float32)]
     mode = _stream_bwd_mode()
     if mode == "fused" or (mode == "auto" and _fused_bwd_fits(
             gb, T, d, qg.dtype.itemsize)):
         dq_spec = pl.BlockSpec((gb, T, d), lambda g_, j, i: (g_, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_stream_bwd_fused_kernel, causal=causal,
-                              scale=scale, nq=nq, nk=nk),
+                              scale=scale, nq=inner, nk=nk, **windowed),
             out_shape=(jax.ShapeDtypeStruct((G, T, d), qg.dtype),
-                       jax.ShapeDtypeStruct((G, T, d), kg.dtype),
-                       jax.ShapeDtypeStruct((G, T, d), vg.dtype)),
-            grid=(G // gb, nk, nq),
-            in_specs=[q_spec_o, kv_spec_o, kv_spec_o, mask_spec_o,
-                      q_spec_o, row_spec_o, row_spec_o],
-            out_specs=(dq_spec, kv_spec_o, kv_spec_o),
+                       *dkv_shapes),
+            grid=(G // gb, nk, inner),
+            in_specs=[q_spec_o, k_spec_o, v_spec_o, mask_spec_o,
+                      do_spec_o, row_spec_o, row_spec_o],
+            out_specs=(dq_spec, kv_spec_o, dv_spec_o),
             scratch_shapes=[pltpu.VMEM((gb, T, d), jnp.float32),
-                            pltpu.VMEM((gb, kt, d), jnp.float32),
-                            pltpu.VMEM((gb, kt, d), jnp.float32)],
+                            *dkv_scratch],
             interpret=interpret,
         )(qg, kg, vg, maskg, dog, lse, delta)
-        return dq, dk, dv
+        return dq, _sum_shared(dk, kg), _sum_shared(dv, vg)
     dk, dv = pl.pallas_call(
         functools.partial(_stream_dkv_kernel, causal=causal, scale=scale,
-                          nq=nq),
-        out_shape=(jax.ShapeDtypeStruct((G, T, d), kg.dtype),
-                   jax.ShapeDtypeStruct((G, T, d), vg.dtype)),
-        grid=(G // gb, nk, nq),
-        in_specs=[q_spec_o, kv_spec_o, kv_spec_o, mask_spec_o, q_spec_o,
+                          nq=inner, **windowed),
+        out_shape=dkv_shapes,
+        grid=(G // gb, nk, inner),
+        in_specs=[q_spec_o, k_spec_o, v_spec_o, mask_spec_o, do_spec_o,
                   row_spec_o, row_spec_o],
-        out_specs=(kv_spec_o, kv_spec_o),
-        scratch_shapes=[pltpu.VMEM((gb, kt, d), jnp.float32),
-                        pltpu.VMEM((gb, kt, d), jnp.float32)],
+        out_specs=(kv_spec_o, dv_spec_o),
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
     )(qg, kg, vg, maskg, dog, lse, delta)
     # dQ: grid (G, query tile, kv tile) — kv innermost
     q_spec = pl.BlockSpec((gb, qt, d), lambda g_, i, j: (g_, i, 0))
+    do_spec = pl.BlockSpec((gb, qt, dv_), lambda g_, i, j: (g_, i, 0))
     row_spec = pl.BlockSpec((gb, 1, qt), lambda g_, i, j: (g_, 0, i))
-    kv_spec = pl.BlockSpec((gb, kt, d), lambda g_, i, j: (g_, j, 0))
-    mask_spec = pl.BlockSpec((gb, 1, kt), lambda g_, i, j: (g_, 0, j))
+    k_spec = _kv_spec(kg, gb, k_group, kt, kv_tile)
+    v_spec = _kv_spec(vg, gb, v_group, kt, kv_tile)
+    mask_spec = pl.BlockSpec((gb, 1, kt),
+                             lambda g_, i, j: (g_, 0, kv_tile(i, j)))
     dq = pl.pallas_call(
         functools.partial(_stream_dq_kernel, causal=causal, scale=scale,
-                          nk=nk),
+                          nk=inner, **windowed),
         out_shape=jax.ShapeDtypeStruct((G, T, d), qg.dtype),
-        grid=(G // gb, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec,
+        grid=(G // gb, nq, inner),
+        in_specs=[q_spec, k_spec, v_spec, mask_spec, do_spec,
                   row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((gb, qt, d), jnp.float32)],
         interpret=interpret,
     )(qg, kg, vg, maskg, dog, lse, delta)
-    return dq, dk, dv
+    return dq, _sum_shared(dk, kg), _sum_shared(dv, vg)
 
 
-def _stream_vjp_bwd(causal, interpret, res, g):
+def _stream_vjp_bwd(causal, interpret, window, res, g):
     qg, kg, vg, maskg, o, lse, B, n = res
     dq, dk, dv = _stream_bwd_impl(qg, kg, vg, maskg, o, lse, _fold_gtd(g),
-                                  causal, interpret)
+                                  causal, interpret, window)
     T = qg.shape[1]
     # the mask is a float selector, not a trainable input
-    return (_unfold_gtd(dq, B, n), _unfold_gtd(dk, B, n),
-            _unfold_gtd(dv, B, n), jnp.zeros((B, T), jnp.float32))
+    return (_unfold_gtd(dq, B, n), _unfold_gtd(dk, B, kg.shape[0] // B),
+            _unfold_gtd(dv, B, vg.shape[0] // B),
+            jnp.zeros((B, T), jnp.float32))
 
 
 stream_attention.defvjp(_stream_vjp_fwd, _stream_vjp_bwd)
@@ -717,15 +864,27 @@ def _qk_scores_bwd(res, g):
 _qk_scores.defvjp(_qk_scores_fwd, _qk_scores_bwd)
 
 
-def xla_attention(q, k, v, attn_mask, causal, with_lse=False):
+def _repeat_heads(x, n):
+    """[B, T, n / group, d] -> [B, T, n, d], each head ``group`` times in a
+    row (the XLA path materialises what the streaming kernel reads through
+    its index maps)."""
+    return x if x.shape[2] == n else jnp.repeat(x, n // x.shape[2], axis=2)
+
+
+def xla_attention(q, k, v, attn_mask, causal, with_lse=False, window=None):
     """Plain-XLA attention (the models/layers.py einsum path), optionally
     emitting the logsumexp in the streaming kernels' [G, 1, T] layout so a
-    streaming backward can follow an XLA forward."""
+    streaming backward can follow an XLA forward.  Shared k/v heads, a wider
+    value head and ``window`` as in ``stream_attention``."""
+    _check_window(window, causal)
     B, T, n, d = q.shape
+    k, v = _repeat_heads(k, n), _repeat_heads(v, n)
     scores = _qk_scores(q, k)
     scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
     if causal:
         cmask = jnp.tril(jnp.ones((T, T), jnp.bool_))
+        if window is not None:
+            cmask = cmask & ~jnp.tril(jnp.ones((T, T), jnp.bool_), -window)
         scores = jnp.where(cmask[None, None], scores, -1e9)
     scores = jnp.where(attn_mask[:, None, None, :].astype(jnp.bool_),
                        scores, -1e9)
@@ -743,47 +902,60 @@ def _mask_gtd(attn_mask, B, T, n):
     ).reshape(B * n, 1, T)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _check_block(fwd_impl, bwd_impl, q, k, v, window):
+    if "block" in (fwd_impl, bwd_impl) and (
+            window is not None or k.shape != q.shape or v.shape != q.shape):
+        raise ValueError("the whole-tile kernel takes neither a window nor "
+                         "shared or wider k/v heads")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def dispatch_attention(q, k, v, attn_mask, causal: bool = False,
                        fwd_impl: str = "xla", bwd_impl: str = "xla",
-                       interpret: bool = False):
+                       interpret: bool = False, window=None):
     """Attention with independently chosen forward/backward kernels.
 
-    q/k/v: [B, T, n, d]; attn_mask: [B, T] float (1 = attend).  The impls
-    are {"xla", "block", "stream"}; bwd "stream" after fwd "block" is
-    rejected (no logsumexp).  Callers gate shapes via ``supported`` /
-    ``stream_supported`` per impl."""
+    q/k/v: [B, T, n, d] (k/v heads shared, v wider and ``window`` as in
+    ``stream_attention``, on "xla" and "stream"); attn_mask: [B, T] float
+    (1 = attend).  The impls are {"xla", "block", "stream"}; bwd "stream"
+    after fwd "block" is rejected (no logsumexp).  Callers gate shapes via
+    ``supported`` / ``stream_supported`` per impl."""
     _check_impls(fwd_impl, bwd_impl)
+    _check_block(fwd_impl, bwd_impl, q, k, v, window)
     B, _, n, _ = q.shape
     if fwd_impl == "stream":
-        o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret)
+        o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret,
+                                   window)
         return _unfold_gtd(o, B, n)
     if fwd_impl == "block":
         return _fwd(q, k, v, attn_mask, causal, interpret)
-    return xla_attention(q, k, v, attn_mask, causal)[0]
+    return xla_attention(q, k, v, attn_mask, causal, window=window)[0]
 
 
 def _dispatch_vjp_fwd(q, k, v, attn_mask, causal, fwd_impl, bwd_impl,
-                      interpret):
+                      interpret, window=None):
     _check_impls(fwd_impl, bwd_impl)
+    _check_block(fwd_impl, bwd_impl, q, k, v, window)
     B, T, n, d = q.shape
     need_stream_res = bwd_impl == "stream"
     lse = None
     if fwd_impl == "stream":
-        o, lse, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret)
+        o, lse, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret,
+                                     window)
         out = _unfold_gtd(o, B, n)
     elif fwd_impl == "block":
         out = _fwd(q, k, v, attn_mask, causal, interpret)
     else:
         out, lse = xla_attention(q, k, v, attn_mask, causal,
-                                 with_lse=need_stream_res)
+                                 with_lse=need_stream_res, window=window)
     if need_stream_res:
         out, lse = _name_stream_residuals(out, lse)
     return out, (q, k, v, attn_mask,
                  (out, lse) if need_stream_res else None)
 
 
-def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, res, g):
+def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, window, res,
+                      g):
     q, k, v, attn_mask, extra = res
     B, T, n, d = q.shape
     if bwd_impl == "stream":
@@ -791,8 +963,9 @@ def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, res, g):
         dq, dk, dv = _stream_bwd_impl(
             _fold_gtd(q), _fold_gtd(k), _fold_gtd(v),
             _mask_gtd(attn_mask, B, T, n), _fold_gtd(out), lse,
-            _fold_gtd(g), causal, interpret)
-        dq, dk, dv = (_unfold_gtd(x, B, n) for x in (dq, dk, dv))
+            _fold_gtd(g), causal, interpret, window)
+        dq, dk, dv = (_unfold_gtd(x, B, like.shape[2])
+                      for x, like in ((dq, q), (dk, k), (dv, v)))
     elif bwd_impl == "block":
         dq, dk, dv = _block_bwd_impl(q, k, v, attn_mask, g, causal,
                                      interpret)
@@ -800,7 +973,8 @@ def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, res, g):
         # XLA backward: recompute-and-differentiate the einsum forward
         # (the same work a remat'd XLA attention does in the replay)
         _, pull = jax.vjp(
-            lambda q_, k_, v_: xla_attention(q_, k_, v_, attn_mask, causal)[0],
+            lambda q_, k_, v_: xla_attention(q_, k_, v_, attn_mask, causal,
+                                             window=window)[0],
             q, k, v)
         dq, dk, dv = pull(g)
     return dq, dk, dv, jnp.zeros_like(attn_mask)

@@ -28,6 +28,15 @@ width, ``n`` heads):
 * ``ATTN_LSE``     rows x n x 4   its fp32 log-sum-exp [G, 1, T]
 * ``POST_LN_SUM``  2 x rows x h   post-LN block: ``x + attn(x)`` and
                                   ``x + ffn(x)``, the two LayerNorms' inputs
+* ``MIXER_IN``     rows x E       a state-space mixer's input projections
+                                  (``u`` and ``z``, 2 x rows x E) and a
+                                  Gated Memory Unit's gate, before the
+                                  activation (``E`` state-space channels)
+* ``SCAN_OUT``     rows x E       the selective scan's output ``y``
+* ``SCAN_STATES``  4 x rows x E x N / chunk   its fp32 states at the chunk
+                                  boundaries: with ``y``, the scan's own
+                                  residuals — saved, the backward does not
+                                  run the forward scan a second time
 """
 
 QKV = "qkv"
@@ -35,6 +44,10 @@ FFN1 = "ffn1"
 ATTN_OUT = "attn_out"
 ATTN_LSE = "attn_lse"
 POST_LN_SUM = "post_ln_sum"
+MIXER_IN = "mixer_in"
+SCAN_OUT = "scan_out"
+SCAN_STATES = "scan_states"
 
 FULL_SAVES = (ATTN_OUT, ATTN_LSE)
-SELECTIVE_SAVES = (QKV, FFN1) + FULL_SAVES + (POST_LN_SUM,)
+SELECTIVE_SAVES = ((QKV, FFN1) + FULL_SAVES
+                   + (POST_LN_SUM, MIXER_IN, SCAN_OUT, SCAN_STATES))

@@ -318,7 +318,7 @@ def test_no_policy_replays_the_streaming_kernel(monkeypatch, policy):
     loss is that of recomputation off, bit for bit, and the gradients its
     gradients to test_selective_remat.py's tolerance."""
     monkeypatch.setattr(L, "attention_plan",
-                        lambda T, n, d, causal: ("stream", "stream"))
+                        lambda *shape, **kw: ("stream", "stream"))
     monkeypatch.setattr(pattn, "stream_attention", functools.partial(
         pattn.stream_attention, interpret=True))
     toks = np.random.default_rng(3).integers(
@@ -384,6 +384,10 @@ def test_the_step_holds_the_loop_scopes_in_every_phase(trained):
             ("dstpu/head", "backward")} <= phases
     assert ("dstpu/block", "backward") in phases
     assert ("dstpu/embed", "forward") in phases
+    # a hybrid stack's scopes (tests/test_step_scopes.py) are not this one's
+    assert not {s for s, _ in phases} & {
+        "dstpu/ssm", "dstpu/scan", "dstpu/conv", "dstpu/swa", "dstpu/xattn",
+        "dstpu/gmu"}
 
 
 def test_model_telemetry_group_reports_the_loop(trained):

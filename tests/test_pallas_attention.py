@@ -485,3 +485,169 @@ def test_calibrate_requires_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
     with pytest.raises(RuntimeError, match="TPU backend"):
         pattn.calibrate_stream_threshold()
+
+
+# ------------------------------ window, shared k/v heads, a wider value head
+# (PR 31).  The streaming kernels in interpret mode against ``xla_attention``
+# with the same mask.
+
+def _window_case(T_len, n_q, n_k, n_v, d, dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (1, T_len, n_q, d))
+    k = jax.random.normal(ks[1], (1, T_len, n_k, d))
+    v = jax.random.normal(ks[2], (1, T_len, n_v, dv))
+    weight = jax.random.normal(ks[3], (1, T_len, n_q, dv))
+    return q, k, v, weight, jnp.ones((1, T_len), jnp.float32)
+
+
+def _value_and_grads(fn, q, k, v, weight):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * weight),
+            argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_same(got, want):
+    (a, ga), (b, gb) = got, want
+    assert float(a) == pytest.approx(float(b), rel=1e-5, abs=1e-4)
+    for name, x, y in zip("qkv", ga, gb):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=2e-5, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("T_len,window,mode", [
+    (1024, 128, "auto"), (1024, 512, "split"), (1536, 640, "auto"),
+    (2048, 512, "split")])
+def test_stream_window_parity(monkeypatch, T_len, window, mode):
+    """Forward and the three gradients under a sliding window, fused and
+    split backward; 640 spans three kv tiles of 512."""
+    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    q, k, v, weight, mask = _window_case(T_len, 2, 2, 2, 64, 64, seed=window)
+    got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
+        q, k, v, mask, True, True, window), q, k, v, weight)
+    want = _value_and_grads(lambda q, k, v: pattn.xla_attention(
+        q, k, v, mask, True, window=window)[0], q, k, v, weight)
+    _assert_same(got, want)
+    # and the window does what it says: a dense reference
+    pos = jnp.arange(T_len)
+    seen = (pos[None] <= pos[:, None]) & (pos[None] > pos[:, None] - window)
+    scores = jnp.einsum("btnd,bsnd->bnts", q, k, precision="highest") / 8.0
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    dense = jnp.einsum("bnts,bsnd->btnd", probs, v, precision="highest")
+    np.testing.assert_allclose(
+        np.asarray(pattn.stream_attention(q, k, v, mask, True, True, window)),
+        np.asarray(dense), rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("window,mode", [(None, "auto"), (512, "split"),
+                                         (512, "fused")])
+def test_stream_shared_heads_and_a_wider_value_head(monkeypatch, window,
+                                                    mode):
+    """8 query heads over 4 key heads (group 2) and 2 value heads of twice
+    the width (group 4) — differential attention's call — against the
+    same call on materialised repeats."""
+    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    q, k, v, weight, mask = _window_case(1024, 8, 4, 2, 64, 128)
+    got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
+        q, k, v, mask, True, True, window), q, k, v, weight)
+    want = _value_and_grads(lambda q, k, v: pattn.stream_attention(
+        q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 4, axis=2), mask, True,
+        True, window), q, k, v, weight)
+    _assert_same(got, want)
+    assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
+    _assert_same(got, _value_and_grads(lambda q, k, v: pattn.xla_attention(
+        q, k, v, mask, True, window=window)[0], q, k, v, weight))
+
+
+def _pallas_grids(fn, *args):
+    """The grids of the ``pallas_call``s in ``fn``'s gradient, in order."""
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2)))(*args)
+    grids, pending = [], [jaxpr.jaxpr]
+    while pending:
+        for eqn in pending.pop().eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            pending.extend(jax.core.jaxprs_in_params(eqn.params))
+    return grids
+
+
+def test_a_windowed_call_walks_only_the_tiles_in_its_window(monkeypatch):
+    """T 8192, window 512, tiles of 512: the kv axis of the forward's grid
+    (and the inner axis of both backward kernels') is 2 long, not 16 — 31 of
+    the causal triangle's 136 tile pairs are visited, none fetched beyond."""
+    monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+    q = jnp.zeros((1, 8192, 4, 64), jnp.bfloat16)
+    k = jnp.zeros((1, 8192, 2, 64), jnp.bfloat16)
+    v = jnp.zeros((1, 8192, 1, 128), jnp.bfloat16)
+    mask = jnp.ones((1, 8192), jnp.float32)
+    run = lambda window: _pallas_grids(
+        lambda q, k, v: pattn.stream_attention(
+            q, k, v, mask, True, True, window).astype(jnp.float32), q, k, v)
+    assert run(512) == [(2, 16, 2), (2, 16, 2), (2, 16, 2)]
+    assert run(None) == [(2, 16, 16), (2, 16, 16), (2, 16, 16)]
+    # the first query of a tile sees window - 1 keys back: 513 still ends
+    # in the tile before, 514 reaches a third
+    assert run(513) == [(2, 16, 2)] * 3
+    assert run(514) == [(2, 16, 3)] * 3 and run(640) == [(2, 16, 3)] * 3
+    assert run(8192) == [(2, 16, 16)] * 3
+    assert pattn._window_tiles(512, 512, 16) == 2
+    assert pattn._window_tiles(1, 512, 16) == 1
+
+
+def test_window_and_shared_heads_are_refused_where_not_built():
+    q, k, v, _, mask = _window_case(256, 4, 4, 4, 16, 16)
+    with pytest.raises(ValueError, match="causal mask"):
+        pattn.stream_attention(q, k, v, mask, False, True, 64)
+    with pytest.raises(ValueError, match="whole-tile kernel"):
+        pattn.dispatch_attention(q, k, v, mask, True, "block", "block",
+                                 True, 64)
+    with pytest.raises(ValueError, match="whole groups"):
+        pattn.stream_attention(q, k[:, :, :3], v, mask, True, True)
+    from deepspeed_tpu.models import layers as L
+    assert L.attention_plan(128, 12, 64, True) == ("xla", "xla")
+
+
+def _normalised(jaxpr):
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    return re.sub(r" at [^\s:]+:\d+", " at FILE", text)
+
+
+#: sha256 (first 16 hex digits) of the gradient's jaxpr — addresses and
+#: source lines struck out — as the PARENT of PR 31 printed it (jax 0.9.0):
+#: a call with no window, as many k/v heads as query heads and one head
+#: size is the program the benchmark's five older cells were measured on
+PARENT_JAXPRS = {
+    "stream_causal_fused": "fd5abd514cfcc926",
+    "stream_noncausal": "9a760c4912ccbf2f",
+    "stream_causal_split": "083caf3254788b3d",
+    "xla": "784faf65c0c932fc",
+    "dispatch_xla_stream": "328cc465be1355ec",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_JAXPRS))
+def test_a_plain_call_is_the_parents_program(monkeypatch, name):
+    import hashlib
+    q = jnp.zeros((2, 1024, 4, 64), jnp.bfloat16)
+    mask = jnp.ones((2, 1024))
+    if name == "stream_causal_split":
+        monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+        q, mask = jnp.zeros((1, 4096, 2, 128), jnp.bfloat16), jnp.ones(
+            (1, 4096))
+    call = {
+        "stream_causal_fused": lambda q, k, v: pattn.stream_attention(
+            q, k, v, mask, True),
+        "stream_noncausal": lambda q, k, v: pattn.stream_attention(
+            q, k, v, mask, False),
+        "stream_causal_split": lambda q, k, v: pattn.stream_attention(
+            q, k, v, mask, True),
+        "xla": lambda q, k, v: pattn.xla_attention(q, k, v, mask, True)[0],
+        "dispatch_xla_stream": lambda q, k, v: pattn.dispatch_attention(
+            q, k, v, mask, True, "xla", "stream"),
+    }[name]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(call(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q)
+    digest = hashlib.sha256(_normalised(jaxpr).encode()).hexdigest()[:16]
+    assert digest == PARENT_JAXPRS[name]

@@ -230,9 +230,11 @@ def test_the_step_holds_every_scope_of_the_table(scoped_runs, case):
     assert names == scopes.parse(text)     # the remembered program is it
     found = {scope for scope, _ in names.values() if scope}
     # the pass loop, the rotary rotation and the exit gate are a looped
-    # model's (tests/test_looped_model.py finds them in its step)
+    # model's (tests/test_looped_model.py finds them in its step); the
+    # state-space mixer, the windowed and cross-decoder cores, the Gated
+    # Memory Unit and the hand-over a hybrid stack's (below)
     want = {scopes.PREFIX + s for s in scopes.SCOPES} - {
-        "dstpu/loop", "dstpu/rope", "dstpu/exit"}
+        "dstpu/loop", "dstpu/rope", "dstpu/exit"} - HYBRID_SCOPES
     if "zero0" in case:
         # nothing to gather: the cast to the compute dtype is the update's
         # last instruction there, and under its scope
@@ -246,6 +248,54 @@ def test_the_step_holds_every_scope_of_the_table(scoped_runs, case):
     # the boundary is no part of the differentiated program
     assert {p for s, p in phases if s.startswith("dstpu/boundary")} == {
         "forward"}
+
+
+HYBRID_SCOPES = {"dstpu/ssm", "dstpu/scan", "dstpu/conv", "dstpu/swa",
+                 "dstpu/xattn", "dstpu/gmu"}
+
+
+def hybrid_step_map(policy):
+    from deepspeed_tpu.models import HybridLM
+    engine, _, _, _ = ds.initialize(
+        model=HybridLM.from_size("tiny"),
+        mesh=make_mesh(devices=jax.devices()[:1]),
+        config={"train_batch_size": 2, "steps_per_print": 10 ** 9,
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "activation_checkpointing": {"enabled": True,
+                                             "policy": policy}})
+    doc = np.random.default_rng(7).integers(0, 512, size=(2, 33),
+                                            dtype=np.int32)
+    engine.train_batch((doc[:, :-1].copy(), doc[:, 1:].copy()))
+    return scopes.step_scope_map()
+
+
+def test_a_hybrid_step_holds_its_scopes_in_every_phase():
+    """A ``HybridLM`` step: the Mamba mixer with its scan and convolution,
+    the windowed and the cross-decoder attention cores and the Gated Memory
+    Unit run forward, replayed and backward.  (The hand-over of the memory
+    and the shared keys and values out of the source segment's scan is
+    slices of an axis of length one, which the compiler turns into
+    bitcasts: it costs no instruction and has no scope.)  The scan's backward runs each block of
+    steps again beside its backward steps (``replay`` under either policy);
+    ``selective`` keeps the scan's output and boundary states, so it replays
+    a whole forward scan less than ``full``."""
+    count = {}
+    for policy in ("full", "selective"):
+        names = hybrid_step_map(policy)
+        phases = {(s, p) for s, p in names.values() if s}
+        for scope in sorted(HYBRID_SCOPES):
+            assert {(scope, "forward"), (scope, "replay"),
+                    (scope, "backward")} <= phases, (policy, scope)
+        assert not {"dstpu/loop", "dstpu/rope", "dstpu/exit"} & {
+            s for s, _ in phases}
+        count[policy] = {
+            phase: sum(1 for v in names.values()
+                       if v == ("dstpu/scan", phase))
+            for phase in ("forward", "replay")}
+    assert HYBRID_SCOPES <= {scopes.PREFIX + s for s in scopes.SCOPES}
+    assert (count["full"]["replay"] - count["selective"]["replay"]
+            > 0.5 * count["full"]["forward"])
 
 
 _COLLECTIVE = re.compile(
