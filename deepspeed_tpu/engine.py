@@ -16,7 +16,8 @@ but the execution model is JAX-native:
 * ``step`` at a boundary runs the jitted update: DP gradient reduction
   (``psum`` with the fp32_allreduce / prescale knobs, reference :819-849),
   overflow check + dynamic loss scale FSM, optional ZeRO-1 partitioned update
-  (reduce-scatter → shard-local Adam → all-gather, see ``zero.py``), and the
+  (exchange of the unreduced gradient pieces, summed in fp32 on the owner →
+  shard-local Adam → all-gather, see ``zero.py``), and the
   skip-on-overflow semantics expressed as ``jnp.where`` instead of a host
   branch.
 * ``train_batch`` drives a full effective batch (gas micro-steps + update)
@@ -771,6 +772,9 @@ class DeepSpeedTpuEngine:
                                          self._named(P()))
         self._loss_treedefs = {}    # loss pytree structure per batch key
         self._acc = None            # accumulated local grads ([dp, ...] tree)
+        # ZeRO 1/2: what the last traced step program sends in the gradient
+        # exchange (_scatter_grads_local; the ``boundary`` gauges)
+        self._boundary_wire = {}
         self._cached_grads = None   # grads from the last forward
         self._pending = None        # latest train-mode forward not yet run
         self._pending_refs = []     # weakrefs to every unforced _PendingStep
@@ -1384,11 +1388,15 @@ class DeepSpeedTpuEngine:
             overflow = comm.overflow_any(overflow, ax)
         return overflow, sq_total
 
-    def _make_loss_and_grads(self):
-        """Local (per-shard) loss + fp32 gradient computation shared by the
+    def _make_loss_and_grads(self, widen: bool = True):
+        """Local (per-shard) loss + gradient computation shared by the
         split-API ``forward`` and the fused ``train_batch`` program.  Returns
         ``f(params, ls_scale, batch_args) -> (loss_out, grads)`` with grads
-        UNSTACKED; must run inside shard_map over the mesh."""
+        UNSTACKED; must run inside shard_map over the mesh.  The gradients
+        are fp32 (what an accumulator adds and stage 0's ``psum`` reduces)
+        unless ``widen=False``: a caller that hands them straight to the
+        flat ZeRO boundary keeps the dtype the backward wrote, which is then
+        the dtype of the wire (``_scatter_grads_local``)."""
         apply_fn = self._apply_fn()
         gas = float(self.gradient_accumulation_steps())
 
@@ -1439,19 +1447,24 @@ class DeepSpeedTpuEngine:
                 # 2x the pp=1 reference before this correction)
                 pp = float(self.pp_world_size)
                 grads = jax.tree_util.tree_map(lambda g: g / pp, grads)
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads)
+            if widen:
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads)
             return loss_out, grads
 
         return loss_and_grads
 
     def _scatter_grads_local(self, grads, rows: bool = None,
                              across_subgroups: bool = True):
-        """Flatten this shard's grad tree and reduce-scatter onto the
-        owned flat partition — the ZeRO boundary reduction, also run
-        per micro-step under stage 2 (linearity makes per-micro
-        scatter-then-accumulate equal accumulate-then-scatter; the
-        stage-2 path defers the cross-sub-group psum to the boundary).
+        """Flatten this shard's grad tree in its own dtype, send every
+        other rank its piece unreduced and sum the pieces received, in fp32,
+        onto the owned flat partition (``comm.reduce_scatter_grads``) — the
+        ZeRO boundary reduction, also run per micro-step under stage 2
+        (linearity makes per-micro scatter-then-accumulate equal
+        accumulate-then-scatter; the stage-2 path defers the
+        cross-sub-group psum to the boundary).  The wire is as wide as the
+        tree handed in: bf16/fp16 straight from a backward, fp32 from an
+        accumulator; the partition is fp32 either way.
         ``rows=True`` wraps the result in the [1, part] per-row layout
         (default: when MP/PP state axes exist)."""
         cfg = self.config
@@ -1465,6 +1478,14 @@ class DeepSpeedTpuEngine:
         # (stage 2 calls it per micro-step, outside step_local)
         with obs_scopes.scope("boundary"):
             flat = zero_mod.flatten_tree(grads, self.flat_meta)
+            # what the step program being traced puts on the wire (the
+            # ``boundary`` gauges; stage 2 sends once per micro-step)
+            sends = (self.gradient_accumulation_steps()
+                     if self.zero_stage == 2 else 1)
+            self._boundary_wire = {
+                "wire_bits": 8 * flat.dtype.itemsize,
+                "wire_bytes_per_step": sends * (self.zero_pps - 1)
+                * self.flat_meta.partition * flat.dtype.itemsize}
             with obs_scopes.scope("boundary/reduce"):
                 gpart = comm.reduce_scatter_grads(
                     flat, DATA_AXIS, self.dp_world_size, **knobs)
@@ -1724,9 +1745,10 @@ class DeepSpeedTpuEngine:
         return self._eval_fn
 
     def _build_fwdbwd(self, batch):
-        loss_and_grads = self._make_loss_and_grads()
         stage2 = self.zero_stage == 2
         zero3 = self.zero3
+        # stage 2 scatters what the backward wrote; ``_acc`` adds fp32
+        loss_and_grads = self._make_loss_and_grads(widen=not stage2)
 
         def local(params, ls_scale, batch_args):
             loss_out, grads = loss_and_grads(params, ls_scale, batch_args)
@@ -2539,9 +2561,13 @@ class DeepSpeedTpuEngine:
         batch_args) -> (params, master, opt_state, ls_state, overflow,
         total_norm, last_loss)``."""
         gas = self.gradient_accumulation_steps()
-        loss_and_grads = self._make_loss_and_grads()
-        step_local = self._make_step_local()
         stage2 = self.zero_stage == 2
+        # the gradients keep the backward's dtype where the flat boundary
+        # takes them as they come (ZeRO 2 per micro-step, ZeRO 1 at gas 1);
+        # an accumulator and stage 0/3's reductions take them in fp32
+        loss_and_grads = self._make_loss_and_grads(
+            widen=not (stage2 or (self.zero_flat and gas == 1)))
+        step_local = self._make_step_local()
         # (ZeRO-3 needs no special casing here: grads/acc live on local
         # shard shapes — partitioned leaves are already scattered by the
         # gather transpose — and step_local consumes them in place)

@@ -174,6 +174,11 @@ class Telemetry:
         # models that declare nothing have no group
         if callable(getattr(engine.module, "step_counts", None)):
             self.registry.register("model", self._model_source)
+        # the flat ZeRO boundary's gradient wire: how wide the step program
+        # that was built sends (16: the backward's bf16/fp16; 32: an fp32
+        # accumulator) and the bytes a chip sends a step
+        if engine.zero_flat:
+            self.registry.register("boundary", self._boundary_source)
 
         # spool (report_window >= 1)
         self.spool: Optional[MetricSpool] = None
@@ -264,6 +269,10 @@ class Telemetry:
             counts.pop("layer_applications")
             * engine.gradient_accumulation_steps())
         return counts
+
+    def _boundary_source(self) -> dict:
+        engine = self._engine_ref()
+        return dict(engine._boundary_wire) if engine is not None else {}
 
     def _samples_source(self) -> dict:
         engine = self._engine_ref()

@@ -11,8 +11,10 @@ Here the same layout is expressed through GSPMD sharding instead of offset
 bookkeeping: the fp32 master (and Adam moments) live in ONE flat padded global
 array with ``NamedSharding(mesh, P('data'))`` — XLA materialises exactly the
 reference's "each DP rank owns 1/N of the flat buffer".  Gradients are
-``psum_scatter`` (reduce-scatter) onto the owned partition — the upgrade the
-reference itself teased (docs/_posts/2020-03-17-reduce-scatter.md) — the
+flattened in the dtype the backward wrote them and reduce-scattered onto the
+owned partition — the upgrade the reference itself teased
+(docs/_posts/2020-03-17-reduce-scatter.md) — as an exchange of the unreduced
+pieces summed in fp32 on the owner (``comm.reduce_scatter_grads``); the
 update runs shard-locally, and the updated weights return to every rank via a
 tiled ``all_gather`` over ICI.
 
@@ -52,11 +54,13 @@ class FlatMeta(NamedTuple):
 
 #: Elements per tile of a 1-D array on the TPU, in every dtype the boundary
 #: moves (f32 ``T(1024)``, bf16/fp16 ``T(1024)(128)(2,1)``).  A partition of
-#: whole tiles is what lets each rank's piece of a collective land in place:
-#: with 128 (a lane, not a tile) libtpu 0.0.34 compiled the weight all-gather
-#: into ``[group, 1, partition]`` and reached the flat buffer from there
-#: through re-tiling copies and unaligned ``dynamic-update-slice`` loops
-#: (PERF.md, PR 25).
+#: whole tiles is what lets each rank's piece of a collective land in place
+#: and leave in place (the gradient exchange permutes ``[partition]`` slices
+#: of the 1-D buffer in its own tiling, PERF.md, PR 32): with 128 (a lane,
+#: not a tile) libtpu 0.0.34 compiled the weight all-gather into
+#: ``[group, 1, partition]`` and reached the flat buffer from there through
+#: re-tiling copies and unaligned ``dynamic-update-slice`` loops (PERF.md,
+#: PR 25).
 FLAT_ALIGN = 1024
 
 
@@ -249,11 +253,15 @@ def combine_local_trees(local_trees, specs, model_axis: str, lazy=False):
     return treedef.unflatten(out)
 
 
-def flatten_tree(tree, meta: FlatMeta, dtype=jnp.float32) -> jnp.ndarray:
-    """Concat + pad all leaves into one flat [padded] vector (jit-safe).
-    Equivalent of ``flatten_dense_tensors_aligned``
-    (zero_optimizer.py:20-41)."""
+def flatten_tree(tree, meta: FlatMeta) -> jnp.ndarray:
+    """Concat + pad all leaves into one flat [padded] vector (jit-safe), in
+    the leaves' common dtype: a bf16 gradient tree gives a bf16 buffer (half
+    the bytes to write here and to send), an fp32 tree (masters, an
+    accumulator) an fp32 one, and a tree that mixes dtypes promotes as
+    ``jnp.result_type`` says.  Equivalent of
+    ``flatten_dense_tensors_aligned`` (zero_optimizer.py:20-41)."""
     leaves = meta.treedef.flatten_up_to(tree)
+    dtype = jnp.result_type(*leaves)
     flat = jnp.concatenate(
         [jnp.reshape(l, (-1,)).astype(dtype) for l in leaves])
     pad = meta.padded - meta.total

@@ -4,10 +4,13 @@ One step program per stage, nothing a user can toggle:
 
 * stage 0: one ``psum`` per gradient leaf, the update on the whole tree,
   the cast to the compute dtype;
-* stage 1: flat gradient -> one reduction onto the owned partition ->
-  the update on the partition -> one all-gather in the compute dtype;
-* stage 2: the same, the reduction run per micro-step so that the
-  accumulator is the partition;
+* stage 1: flat gradient, in the dtype it comes in (the backward's at
+  gas 1, the fp32 accumulator's otherwise) -> every rank's unreduced piece
+  of the owned partition, widened to fp32 and summed in the order
+  ``comm.reduce_scatter_grads``' docstring states -> the update on the
+  partition -> one all-gather in the compute dtype;
+* stage 2: the same, the reduction run per micro-step (on the backward's
+  own dtype) so that the accumulator is the partition;
 * stage 3: every partitioned leaf gathered where it is used (the layer
   scan gathers one layer at a time), gradients scattered by the gather's
   transpose, the update on the shards.
@@ -30,6 +33,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu
+from deepspeed_tpu import analysis as graph_lint
+from deepspeed_tpu.analysis import commplan
+from deepspeed_tpu.analysis import graph as jaxpr_graph
 from deepspeed_tpu.ops import optim as optim_mod
 from deepspeed_tpu.models import GPT2
 from deepspeed_tpu.parallel import comm
@@ -131,27 +137,41 @@ def _plain_step(engine, batch):
             return None, tmap(
                 lambda g: (g / float(mp)).astype(jnp.float32), grads)
     else:
-        loss_and_grads = engine._make_loss_and_grads()
+        # the flat boundary takes a micro-batch's gradients in the dtype
+        # the backward wrote; stage 0 reduces them in fp32
+        loss_and_grads = engine._make_loss_and_grads(widen=stage == 0)
 
     def flatten(grads):
-        pieces = [g.reshape(-1).astype(jnp.float32)
-                  for g in meta.treedef.flatten_up_to(grads)]
-        pieces.append(jnp.zeros((meta.padded - meta.total,), jnp.float32))
+        """One buffer in the gradients' own dtype: what goes on the wire."""
+        leaves = meta.treedef.flatten_up_to(grads)
+        dtype = jnp.result_type(*leaves)
+        pieces = [g.reshape(-1).astype(dtype) for g in leaves]
+        pieces.append(jnp.zeros((meta.padded - meta.total,), dtype))
         return jnp.concatenate(pieces)
 
     # Sub-groups of pps consecutive ranks own the partitions (pps = world:
     # one group).  The sum over ranks runs within a sub-group, then across
     # the sub-groups; at stage 2 the accumulator sits between the two.
     def within(flat):
-        """This rank's partition of ``flat`` summed over its sub-group."""
+        """This rank's partition of ``flat`` summed over its sub-group, as
+        ``comm.reduce_scatter_grads``' docstring states it: every rank's
+        piece of the partition crosses unreduced, in ``flat``'s dtype, is
+        widened to fp32 and added left to right as own piece + the piece
+        heard in step 1 + in step 2 + ...: from rank me^1, me^2, ... of
+        the sub-group where pps is a power of two, from rank me-1, me-2,
+        ... (mod pps) where it is not."""
         rank, part = jax.lax.axis_index("data"), meta.partition
-        if pps == world:
-            total = jax.lax.psum(flat, "data")
-        else:
-            group = jax.lax.dynamic_slice_in_dim(
-                jax.lax.all_gather(flat, "data"), (rank // pps) * pps, pps)
-            total = sum(group[r] for r in range(pps))
-        return jax.lax.dynamic_slice_in_dim(total, (rank % pps) * part, part)
+        me, first = rank % pps, (rank // pps) * pps
+        every = jax.lax.all_gather(flat, "data")        # [world, padded]
+        assert every.dtype == flat.dtype                # nothing summed yet
+        total = None
+        for r in range(pps):
+            heard = me ^ r if pps & (pps - 1) == 0 else (me - r) % pps
+            piece = jax.lax.dynamic_slice(
+                every, (first + heard, me * part), (1, part))[0]
+            piece = piece.astype(jnp.float32)
+            total = piece if total is None else total + piece
+        return total
 
     def across(own):
         """``own`` summed over the ranks that hold the same partition."""
@@ -164,8 +184,12 @@ def _plain_step(engine, batch):
         return sum(same[g] for g in range(world // pps))
 
     def reduce_micro(grads):
-        """What a micro-step's gradients are before they are summed."""
-        return within(flatten(grads)) / world if stage == 2 else grads
+        """What a micro-step's gradients are before they are summed: at
+        stage 2 the reduced partition; an accumulator adds in fp32."""
+        if stage == 2:
+            return within(flatten(grads)) / world
+        return grads if gas == 1 else tmap(
+            lambda g: g.astype(jnp.float32), grads)
 
     def reduce_sum(acc):
         """The summed gradients, as the update takes them."""
@@ -380,12 +404,162 @@ def test_step_program_lints_clean(stage):
     assert not rep.errors, f"stage {stage}:\n" + rep.format()
 
 
+# ------------------------------ the wire: what crosses it, and how wide
+
+NARROW = (jnp.bfloat16, jnp.float16)
+SUMS = ("psum", "psum_invariant")       # what a pmean lowers to, too
+
+
+@pytest.mark.parametrize("stage,gas,bits", [(1, 1, 16), (1, 2, 32),
+                                            (2, 1, 16), (2, 2, 16)],
+                         ids=["zero1-gas1", "zero1-gas2", "zero2-gas1",
+                              "zero2-gas2"])
+def test_gradient_wire_is_as_wide_as_the_tree(stage, gas, bits):
+    """The step program's jaxpr, read: the gradient is flattened in the
+    dtype it comes in — no fp32 array of ``FlatMeta.padded`` elements
+    exists before the exchange where the backward's bf16 tree reaches the
+    boundary (ZeRO 1 at gas 1, ZeRO 2 per micro-step), and one does where
+    an fp32 accumulator does (ZeRO 1 at gas 2); the exchange is group - 1
+    permutes of one partition each, no ``psum_scatter`` is left, and no
+    summing collective over the data axis takes a bf16/fp16 operand (the
+    model's own tensor-parallel ``psum`` of activations is not the
+    boundary's).  The
+    ``boundary`` gauges and the capacity planner's wire count say the
+    same bytes."""
+    engine = make_engine(stage, gas=gas, fp16=False)
+    batch = lm_batch(8 * gas)
+    jaxpr = graph_lint.trace_train_batch(
+        engine, batch, fn=engine._build_train_batch(batch))
+    meta, group = engine.flat_meta, engine.zero_pps
+    # the per-rank program: inside the shard_map local shapes are what a
+    # chip holds (outside it the master itself is a global f32[padded])
+    [body] = [eqn.params["jaxpr"] for eqn, _ in jaxpr_graph.walk(jaxpr)
+              if eqn.primitive.name == "shard_map"]
+    eqns = [eqn for eqn, _ in jaxpr_graph.walk(body)]
+    names = [eqn.primitive.name for eqn in eqns]
+
+    sent = [eqn.invars[0].aval for eqn in eqns
+            if eqn.primitive.name == "ppermute"]
+    assert len(sent) == group - 1
+    assert all(a.shape == (meta.partition,) and 8 * a.dtype.itemsize == bits
+               for a in sent)
+    before = eqns[:names.index("ppermute")]
+    wide_flat = [v.aval for eqn in before for v in eqn.outvars
+                 if getattr(v.aval, "shape", None) == (meta.padded,)
+                 and v.aval.dtype == jnp.float32]
+    assert bool(wide_flat) == (bits == 32)
+
+    assert "psum_scatter" not in names and "reduce_scatter" not in names
+    for eqn in eqns:
+        if eqn.primitive.name in SUMS and "data" in eqn.params["axes"]:
+            assert not [v for v in eqn.invars
+                        if jaxpr_graph.dtype_of(v) in NARROW], eqn
+
+    sends = gas if stage == 2 else 1
+    wire = sends * (group - 1) * meta.partition * bits // 8
+    assert engine._telemetry.registry.collect()["boundary"] == {
+        "wire_bits": bits, "wire_bytes_per_step": wire}
+    plan = commplan.analyze_comm(jaxpr, dict(engine.mesh.shape))
+    assert sum(c.bytes_total for c in plan.costs
+               if c.primitive == "ppermute") == wire
+
+
+def test_boundary_gauges_only_where_the_boundary_is_flat():
+    """ZeRO 0 and 3 have no flat gradient buffer and no ``boundary``
+    group; a flat engine that has built no program yet reports nothing."""
+    for stage in (0, 3):
+        registry = make_engine(stage)._telemetry.registry
+        assert "boundary" not in registry.collect()
+    assert make_engine(1)._telemetry.registry.collect()["boundary"] == {}
+
+
+# ------------------------- the exchange alone: comm.reduce_scatter_grads
+
+@pytest.mark.parametrize("knobs", [
+    {"fp32_allreduce": True},
+    {"prescale_gradients": True},
+    {"prescale_gradients": True, "gradient_predivide_factor": 2.0}],
+    ids=["fp32_allreduce", "prescale", "prescale-predivide2"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("world,pps", [(4, 4), (4, 2), (6, 6), (6, 3)],
+                         ids=["dp4", "pps2of4", "dp6", "pps3of6"])
+def test_reduce_scatter_grads_is_the_written_out_fp32_mean(world, pps, dtype,
+                                                           knobs):
+    """``comm.reduce_scatter_grads`` on a dp=4 and a dp=6 mesh, whole and
+    in two sub-groups: every rank's partition is, bit for bit, the mean
+    written out on the host in fp32 in the order the docstring states
+    (own piece + the pieces heard in steps 1, 2, ...: from ranks me^1,
+    me^2, ... of a power-of-two group, from me-1, me-2, ... mod the group
+    otherwise; then the two sub-groups' sums, which have one order), with
+    the reference's scaling (deepspeed_light.py:819-849) applied to the
+    widened pieces.  The data make the order show far above the last bit:
+    of every element's values over the ranks one is +2**26, one -2**26 and
+    the others are standard normal, so a normal value survives only if it
+    is added while the running sum is small.  Where the world is no power
+    of two the division by it is one XLA rewrites into a multiplication by
+    the reciprocal: there the comparison allows the last bit."""
+    part = 2 * 1024
+    rng = np.random.default_rng(pps)
+    values = rng.standard_normal((world, pps * part))
+    big = rng.permuted(np.tile(np.arange(world)[:, None], (1, pps * part)),
+                       axis=0)[:2]
+    values[big[0], np.arange(pps * part)] = 2.0 ** 26
+    values[big[1], np.arange(pps * part)] = -2.0 ** 26
+    per_rank = jnp.asarray(values, dtype)
+    got = jax.jit(jax.shard_map(
+        lambda flat: comm.reduce_scatter_grads(
+            flat[0], "data", world, partition_group_size=pps, **knobs)[None],
+        mesh=make_mesh(devices=jax.devices()[:world]),
+        in_specs=P("data"), out_specs=P("data"), check_vma=False))(per_rank)
+    assert got.dtype == jnp.float32 and got.shape == (world, part)
+
+    x = np.asarray(per_rank).astype(np.float32)
+    pre = np.float32(knobs.get("gradient_predivide_factor", 1.0)
+                     if knobs.get("prescale_gradients") else 1.0)
+
+    def group_sum(rank, reverse=False):
+        me, first = rank % pps, (rank // pps) * pps
+        heard = [me ^ r if pps & (pps - 1) == 0 else (me - r) % pps
+                 for r in range(pps)]
+        if reverse:
+            heard.reverse()
+        pieces = [x[first + h, me * part:(me + 1) * part] / pre
+                  for h in heard]
+        total = pieces[0]
+        for piece in pieces[1:]:
+            total = total + piece
+        return total
+
+    for rank in range(world):
+        total = group_sum(rank)
+        if pps < world:
+            total = group_sum(rank % pps) + group_sum(rank % pps + pps)
+        want = total / np.float32(world / pre)
+        if world & (world - 1) == 0:
+            np.testing.assert_array_equal(np.asarray(got[rank]), want,
+                                          err_msg=f"rank {rank}")
+        else:
+            np.testing.assert_array_max_ulp(np.asarray(got[rank]), want,
+                                            maxulp=1)
+        # another order of the same pieces is another result (of more
+        # than two: fp32 addition commutes)
+        assert pps == 2 or (group_sum(rank, reverse=True)
+                            != group_sum(rank)).any()
+    if dtype == jnp.bfloat16:
+        # a sum in the wire's dtype would have lost bits
+        narrow = np.asarray(per_rank)[:, :part]
+        assert (narrow.sum(0).astype(np.float32)
+                != x[:, :part].sum(0)).any()
+
+
 # ------------------------------------------- geometry of the flat buffer
 
 def test_partitions_are_whole_tiles():
     """Each rank's partition is a whole number of the TPU's 1-D tiles
     (1024 elements in f32, bf16 and fp16): what lets each rank's piece
-    of the all-reduce and of the all-gather land in place (PERF.md, PR 25;
+    leave in place in the gradient exchange and land in place in the
+    all-gather (PERF.md, PR 25 and PR 32;
     with 128 the compiled boundary re-tiled full-size buffers in
     unaligned dynamic-update-slice loops)."""
     from deepspeed_tpu import zero as zero_mod
