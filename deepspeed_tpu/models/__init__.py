@@ -17,6 +17,8 @@ from deepspeed_tpu.models.bert import (BertForPreTraining,
                                        BertForQuestionAnswering, BERT_SIZES)
 from deepspeed_tpu.models.looped import LoopedConfig, LoopedLM, LOOPED_SIZES
 from deepspeed_tpu.models.hybrid import HybridConfig, HybridLM, HYBRID_SIZES
+from deepspeed_tpu.models.latent_moe import (LatentMoEConfig, LatentMoELM,
+                                             LATENT_MOE_SIZES)
 
 __all__ = [
     "TransformerConfig", "init_block_params", "block_partition_specs",
@@ -26,4 +28,5 @@ __all__ = [
     "BertForPreTraining", "BertForQuestionAnswering", "BERT_SIZES",
     "LoopedConfig", "LoopedLM", "LOOPED_SIZES",
     "HybridConfig", "HybridLM", "HYBRID_SIZES",
+    "LatentMoEConfig", "LatentMoELM", "LATENT_MOE_SIZES",
 ]

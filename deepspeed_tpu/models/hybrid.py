@@ -345,48 +345,19 @@ class HybridLM:
 
     # --------------------------------------------------------------- forward
     def _segment(self, index, carry, stacked, shared, z3_dims):
-        """One segment: ``scan_layers`` over its stacked periods.  Returns
-        ``(carry, made)``; ``made`` is what the source segment hands out
-        (None from any other)."""
+        """One segment (``transformer.scan_segment``).  Returns ``(carry,
+        made)``; ``made`` is what the source segment hands out (None from
+        any other)."""
         cfg = self.config
         kinds, _ = cfg.segments[index]
-        is_source = index == cfg.source_segment
-        layers = [T.remat_wrap(functools.partial(layer_apply, kind, cfg), cfg)
-                  for kind in kinds]
-
-        def period(carry, lp):
-            x, depth = carry
-            made = {}
-            for j, layer in enumerate(layers):
-                x, out = layer(x, lp[f"l{j}"], depth + j, shared)
-                made.update(out)           # the LAST layer of a kind wins
-            return (x, depth + len(kinds)), (made if is_source else None)
-
-        # each layer is rematerialised on its own: no second wrap round the
-        # period, which would replay every layer twice
-        return T.scan_layers(period, carry, stacked,
-                             dataclasses.replace(cfg, remat=False),
-                             z3_dims=z3_dims)
-
-    def _head_loss(self, x, wte, labels):
-        """Per-position cross-entropy [B, T] of the tied head, in blocks of
-        ``HEAD_BLOCK_ROWS`` positions under ``jax.checkpoint``."""
-        B, T_len, h = x.shape
-        rows = HEAD_BLOCK_ROWS
-
-        @jax.checkpoint
-        def block(xb, lb):
-            return L.vocab_parallel_cross_entropy(
-                L.vocab_parallel_logits(xb, wte), lb)
-
-        if T_len <= rows or T_len % rows:
-            return block(x, labels)
-        n = T_len // rows
-        _, ce = jax.lax.scan(
-            lambda _, b: (None, block(*b)), None,
-            (jnp.moveaxis(x.reshape(B, n, rows, h), 1, 0),
-             jnp.moveaxis(labels.reshape(B, n, rows), 1, 0)))
-        return jnp.moveaxis(ce, 0, 1).reshape(B, T_len)
+        # the source segment hands out what its layers made: the LAST layer
+        # of a kind wins
+        merge = lambda outs: {name: t for out in outs
+                              for name, t in out.items()}
+        return T.scan_segment(
+            [functools.partial(layer_apply, kind, cfg) for kind in kinds],
+            carry, stacked, cfg, shared=shared, z3_dims=z3_dims,
+            collect=merge if index == cfg.source_segment else None)
 
     def apply(self, params, tokens, labels):
         """tokens, labels: int32 [B, T]; labels < 0 are ignored.  Returns
@@ -409,7 +380,8 @@ class HybridLM:
         with S.scope("head"):
             x = L.layer_norm(carry[0], params["lnf_s"], params["lnf_b"],
                              cfg.ln_eps)
-            loss = self._head_loss(x, params["wte"], labels)
+            loss = T.blocked_cross_entropy(x, params["wte"], labels,
+                                           HEAD_BLOCK_ROWS)
             return L.masked_mean_loss(loss, labels >= 0)
 
     __call__ = apply

@@ -343,14 +343,18 @@ def gelu(x):
     return y.astype(x.dtype)
 
 
-@S.scoped("norm")
-def rms_norm(x, scale, eps=1e-6):
-    """RMSNorm: ``x / sqrt(mean(x^2) + eps) * scale``, the statistic in
-    fp32 (no mean subtraction, no offset)."""
+def _rms_norm(x, scale, eps):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + eps)
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@S.scoped("norm")
+def rms_norm(x, scale, eps=1e-6):
+    """RMSNorm: ``x / sqrt(mean(x^2) + eps) * scale``, the statistic in
+    fp32 (no mean subtraction, no offset)."""
+    return _rms_norm(x, scale, eps)
 
 
 def silu(x):
@@ -731,6 +735,56 @@ def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
     else:
         ctx = core_attention(q, k, v, causal=causal, attn_mask=attn_mask)
     return row_parallel_linear(ctx.reshape(B, T, -1), wo_local, axis=axis)
+
+
+# ------------------------------------------------------ latent attention
+
+@S.scoped("attn")
+def latent_attention(x, p, *, rope, nope_dim, rope_dim, v_dim, latent, eps):
+    """Multi-head latent attention (MLA, DeepSeek-V2 / V3; queries not
+    compressed), causal, in the EXPANDED form training runs: keys and values
+    are rebuilt per head from one latent per token (the absorbed form, which
+    attends in the latent space, is serving's).
+
+    ``q = x W_q`` -> heads of ``nope_dim + rope_dim`` = ``[q_nope | q_pe]``;
+    ``[c | k_pe] = x W_kv_a``, ``c`` ``latent`` wide, ``k_pe`` ``rope_dim``
+    wide and ONE per token, shared by all heads; ``c <- RMSNorm(c)``;
+    ``[k_nope | v] = c W_kv_b`` -> heads of ``nope_dim + v_dim``; rotary on
+    ``q_pe`` and ``k_pe``; ``k_h = [k_nope_h | k_pe]``; softmax of ``q_h
+    k_h^T / sqrt(nope_dim + rope_dim)`` times ``v_h``; heads concatenated
+    times ``W_o``.  The core is the one ``core_attention`` dispatch, at a
+    query / key head of ``nope_dim + rope_dim`` and a value head of
+    ``v_dim``.
+
+    x [B, T, h] replicated over ``model``; ``p``: ``q_w`` [h, n (nope +
+    rope) / mp] and ``kv_b_w`` [latent, n (nope + v) / mp] column-parallel,
+    heads contiguous (a shard holds whole heads), ``kv_a_w`` [h, latent +
+    rope] and ``kv_norm_s`` [latent] replicated (every shard needs the whole
+    latent), ``o_w`` [n v / mp, h] row-parallel; ``rope`` =
+    ``rotary_tables(T, rope_dim, theta)``.  The three projections and the
+    latent's norm run under ``dstpu/mla``."""
+    if axis_size_or_1(SEQ_AXIS) > 1:
+        raise ValueError(
+            "latent_attention is not built for context parallelism: the "
+            "ring and the all-to-all paths take one head size for keys and "
+            "values, and this core has a key head wider than its value head")
+    B, T, _ = x.shape
+    with S.scope("mla"):
+        # named for the "selective" policy, like q, k, v of the other blocks
+        q = checkpoint_name(column_parallel_linear(x, p["q_w"]), QKV)
+        down = checkpoint_name(column_parallel_linear(x, p["kv_a_w"]), QKV)
+        c = _rms_norm(down[..., :latent], p["kv_norm_s"], eps)
+        kv = checkpoint_name(column_parallel_linear(c, p["kv_b_w"]), QKV)
+    q = q.reshape(B, T, -1, nope_dim + rope_dim)
+    kv = kv.reshape(B, T, -1, nope_dim + v_dim)
+    q_pe = apply_rotary(q[..., nope_dim:], rope)
+    k_pe = apply_rotary(down[:, :, None, latent:], rope)
+    q = jnp.concatenate([q[..., :nope_dim], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope_dim],
+         jnp.broadcast_to(k_pe, (B, T, kv.shape[2], rope_dim))], axis=-1)
+    ctx = core_attention(q, k, kv[..., nope_dim:], causal=True)
+    return row_parallel_linear(ctx.reshape(B, T, -1), p["o_w"])
 
 
 # ------------------------------------------------ hybrid (SSM / attention)

@@ -341,6 +341,62 @@ def scan_layers(body, carry, stacked_params, cfg, z3_dims=None):
                         carry, stacked_params)
 
 
+def scan_segment(layers, carry, stacked, cfg, *, shared=None, collect=None,
+                 z3_dims=None):
+    """One segment ``(kinds, repeats)`` of a stack made of segments:
+    ``repeats`` stacked copies of a whole period of layers run by ONE
+    ``scan_layers`` whose body applies the period's layers in order, each
+    under ``cfg``'s recomputation policy on its own (no second wrap round
+    the period, which would replay every layer twice).
+
+    ``layers[j](x, p, depth, shared) -> (x, out)`` is the period's ``j``-th
+    layer; ``stacked[f"l{j}"]`` holds its parameters ``[repeats, ...]``;
+    ``carry`` = ``(x, depth)``, ``depth`` the published depth of the next
+    layer (an int32 scalar: a layer whose equations read its depth gets it
+    here); ``shared``: what every layer may read beside its own parameters,
+    closed over as loop invariants.  Returns ``(carry, collected)``:
+    ``collect(outs)`` of each period's list of second results, stacked
+    ``[repeats, ...]``; None without ``collect``.  Shared by the hybrid and
+    the latent-attention / expert stacks."""
+    wrapped = [remat_wrap(layer, cfg) for layer in layers]
+
+    def period(carry, lp):
+        x, depth = carry
+        outs = []
+        for j, layer in enumerate(wrapped):
+            x, out = layer(x, lp[f"l{j}"], depth + j, shared)
+            outs.append(out)
+        return (x, depth + len(wrapped)), (collect(outs) if collect else None)
+
+    return scan_layers(period, carry, stacked,
+                       dataclasses.replace(cfg, remat=False),
+                       z3_dims=z3_dims)
+
+
+def blocked_cross_entropy(x, table, labels, block_rows):
+    """Per-position cross-entropy ``[B, T]`` of the vocabulary projection
+    ``x @ table.T`` (``table`` ``[vocab / mp, h]``, vocabulary-parallel: a
+    tied embedding or a head of its own), in blocks of ``block_rows``
+    positions under ``jax.checkpoint``: the fp32 logits of one block are
+    live at a time, forward and backward.  One block where ``T`` is no
+    whole multiple of ``block_rows``."""
+    B, T_len, h = x.shape
+
+    @jax.checkpoint
+    def block(xb, lb):
+        return L.vocab_parallel_cross_entropy(
+            L.vocab_parallel_logits(xb, table), lb)
+
+    if T_len <= block_rows or T_len % block_rows:
+        return block(x, labels)
+    n = T_len // block_rows
+    _, ce = jax.lax.scan(
+        lambda _, b: (None, block(*b)), None,
+        (jnp.moveaxis(x.reshape(B, n, block_rows, h), 1, 0),
+         jnp.moveaxis(labels.reshape(B, n, block_rows), 1, 0)))
+    return jnp.moveaxis(ce, 0, 1).reshape(B, T_len)
+
+
 # ------------------------------------------------------------- serving
 # KV-cached prefill/decode blocks (deepspeed_tpu/inference/).  The block
 # math is the training block's (same LayerNorm/GELU/projection helpers,

@@ -50,6 +50,11 @@ SCOPES = (
     # Unit (the hand-over of the memory and the shared keys and values is
     # slices of an axis of length one: no instruction, so no scope)
     "ssm", "scan", "conv", "swa", "xattn", "gmu",
+    # a latent-attention / expert stack: the latent projections and the
+    # latent's norm inside ``attn``; the whole expert layer, with its
+    # routing (scores, top-k, gates, sort, the two gathers, balance loss)
+    # and its grouped matmuls inside (the shared experts run under ``ffn``)
+    "mla", "moe", "route", "experts",
 )
 
 FORWARD, BACKWARD, REPLAY = "forward", "backward", "replay"

@@ -19,9 +19,16 @@ no ``POST_LN_SUM``, an XLA attention plan no ``ATTN_OUT`` / ``ATTN_LSE``
 compute dtype (``rows`` = micro-batch x sequence, ``h`` hidden, ``ffn`` FFN
 width, ``n`` heads):
 
-* ``QKV``          3 x rows x h   packed q/k/v projection output
+* ``QKV``          3 x rows x h   packed q/k/v projection output (latent
+                                  attention: the queries, the down
+                                  projection and the up projection's
+                                  per-head keys and values)
 * ``FFN1``         rows x ffn     first FFN matmul, before the activation
-                                  (SwiGLU: gate and up, 2 x rows x ffn)
+                                  (SwiGLU: gate and up, 2 x rows x ffn; a
+                                  dropless expert layer's grouped gate and
+                                  up products over EVERY (token, choice)
+                                  pair, its static worst case: 2 x rows x k
+                                  x expert width)
 * ``ATTN_OUT``     rows x h       streaming kernel's output, unfolded
                                   [B, T, n, d] (folded [G, T, d] a head
                                   size of 64 is lane-padded to twice that)

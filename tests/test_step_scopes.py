@@ -232,9 +232,12 @@ def test_the_step_holds_every_scope_of_the_table(scoped_runs, case):
     # the pass loop, the rotary rotation and the exit gate are a looped
     # model's (tests/test_looped_model.py finds them in its step); the
     # state-space mixer, the windowed and cross-decoder cores, the Gated
-    # Memory Unit and the hand-over a hybrid stack's (below)
+    # Memory Unit and the hand-over a hybrid stack's, the latent
+    # projections and the expert layer's three a latent-attention / expert
+    # stack's (both below)
     want = {scopes.PREFIX + s for s in scopes.SCOPES} - {
-        "dstpu/loop", "dstpu/rope", "dstpu/exit"} - HYBRID_SCOPES
+        "dstpu/loop", "dstpu/rope", "dstpu/exit"} - HYBRID_SCOPES - (
+            LATENT_MOE_SCOPES)
     if "zero0" in case:
         # nothing to gather: the cast to the compute dtype is the update's
         # last instruction there, and under its scope
@@ -296,6 +299,56 @@ def test_a_hybrid_step_holds_its_scopes_in_every_phase():
     assert HYBRID_SCOPES <= {scopes.PREFIX + s for s in scopes.SCOPES}
     assert (count["full"]["replay"] - count["selective"]["replay"]
             > 0.5 * count["full"]["forward"])
+
+
+LATENT_MOE_SCOPES = {"dstpu/mla", "dstpu/moe", "dstpu/route",
+                     "dstpu/experts"}
+
+
+def latent_moe_step_map(policy):
+    from deepspeed_tpu.models import LatentMoELM
+    engine, _, _, _ = ds.initialize(
+        model=LatentMoELM.from_size("tiny", experts_held=(4, 4)),
+        mesh=make_mesh(devices=jax.devices()[:1]),
+        config={"train_batch_size": 2, "steps_per_print": 10 ** 9,
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "activation_checkpointing": {"enabled": True,
+                                             "policy": policy}})
+    doc = np.random.default_rng(7).integers(0, 512, size=(2, 65),
+                                            dtype=np.int32)
+    engine.train_batch((doc[:, :-1].copy(), doc[:, 1:].copy()))
+    return scopes.step_scope_map(), engine
+
+
+@pytest.mark.parametrize("policy", ["full", "selective"])
+def test_a_latent_attention_expert_step_holds_its_four_scopes(policy):
+    """A ``LatentMoELM`` step: the latent projections (``mla``, inside
+    ``attn``), the expert layer's own glue (``moe``), its routing
+    (``route``) and its grouped matmuls (``experts``) run forward and
+    backward — the custom backward passes of the two gathers under
+    ``route`` too — and replayed.  The rotation keeps ``rope``, the shared
+    experts and the dense MLP ``ffn``."""
+    names, engine = latent_moe_step_map(policy)
+    phases = {(s, p) for s, p in names.values() if s}
+    for scope in sorted(LATENT_MOE_SCOPES):
+        assert {(scope, "forward"), (scope, "backward")} <= phases, scope
+    assert {("dstpu/rope", "forward"), ("dstpu/ffn", "forward"),
+            ("dstpu/attn", "forward"), ("dstpu/head", "backward")} <= phases
+    assert LATENT_MOE_SCOPES <= {scopes.PREFIX + s for s in scopes.SCOPES}
+    replayed = {s for s, p in phases if p == "replay"}
+    # the layer's own glue, one sum, feeds no gradient: never replayed;
+    # the rest is, under either policy — ``selective`` replays the sort,
+    # the latent's norm and the experts' activation, no product (the jaxpr
+    # counts them: tests/test_latent_moe_model.py)
+    assert {"dstpu/mla", "dstpu/route", "dstpu/experts"} <= replayed
+    assert not {"dstpu/ssm", "dstpu/loop", "dstpu/exit"} & {
+        s for s, _ in phases}
+    assert engine._telemetry.registry.collect()["model"] == {
+        "layers_dense": 1, "layers_moe": 2, "experts_total": 16,
+        "experts_held": 4, "experts_per_token": 3, "latent_rank": 32,
+        "qk_head_dim": 32, "v_head_dim": 16,
+        "layer_applications_per_step": 3}
 
 
 _COLLECTIVE = re.compile(

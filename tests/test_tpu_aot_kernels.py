@@ -1,0 +1,87 @@
+"""The kernels of the latent-attention / expert path at their REAL widths,
+compiled here for a described TPU v5e (no chip attached): what interpret
+mode cannot show — whether Mosaic takes a 192-wide query / key head beside
+a 128-wide value head, and whether the grouped matmuls become kernels and
+not dense products.  A compile that passes is not a chip run: nothing here
+is a time.  The topology is described inside a fixture (never while a module
+is imported), and every such test lives in this one file, so one worker
+loads the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.models import moe as M
+from deepspeed_tpu.ops import pallas_attention as pattn
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compiled_text(fn, *shapes):
+    return jax.jit(fn).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+
+def test_mosaic_takes_the_streaming_kernel_at_192_and_128(one_chip):
+    """Forward, and the backward (split at T 8192: the fused one's dQ
+    scratch is past VMEM), 2 x 8192 tokens, 16 heads, bf16: three Pallas
+    calls, no padding of 192 to 256 anywhere in the wrapper."""
+    B, T, n, d, dv = 2, 8192, 16, 192, 128
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    args = (S(B, T, n, d), S(B, T, n, d), S(B, T, n, dv),
+            S(B, T, dt=jnp.float32))
+
+    def loss(q, k, v, mask):
+        return jnp.sum(pattn.stream_attention(q, k, v, mask, True)
+                       .astype(jnp.float32))
+
+    assert compiled_text(loss, *args).count("tpu_custom_call") == 1
+    text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert text.count("tpu_custom_call") == 3
+    assert "bf16[32,8192,256]" not in text
+
+
+def test_the_grouped_matmuls_compile_to_kernels(one_chip, monkeypatch):
+    """The expert SwiGLU over ragged groups at the cell's sizes (98,304
+    sorted rows, 8 experts 2048 x 1408), forward and backward: every product
+    — the three forward ones, their input gradients and their weight
+    gradients — is a Pallas kernel under jax's own name (the tiles of
+    ``ops/grouped_matmul._tiles`` fit Mosaic's VMEM), none a dense masked
+    product and none the compiler's ``ragged-dot`` kernel, which carries no
+    scope into a trace."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    R, h, f, e = 98304, 2048, 1408, 8
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    p = {"exp_gate_w": S(e, h, f), "exp_up_w": S(e, h, f),
+         "exp_down_w": S(e, f, h)}
+
+    def loss(rows, p, sizes):
+        out = M.grouped_swiglu(rows, p, sizes, jnp.sum(sizes))
+        return jnp.sum(out.astype(jnp.float32))
+
+    def kernels(text):
+        return [line for line in text.splitlines()
+                if "custom-call(" in line and "tpu_custom_call" in line]
+
+    args = (S(R, h), p, S(e, dt=jnp.int32))
+    assert len(kernels(compiled_text(loss, *args))) == 3
+    text = compiled_text(jax.grad(loss, argnums=(0, 1)), *args)
+    # gate and up again (the gradient of a sum does not need the down
+    # product), then two gradients a product: 2 + 3 x 2
+    assert len(kernels(text)) == 8
+    assert "ragged-dot" not in text
+    assert all("dstpu/experts" in line for line in kernels(text))
+    # a dense expansion would hold a product over all rows for every expert
+    assert f"bf16[{e},{R}," not in text
