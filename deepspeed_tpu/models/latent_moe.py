@@ -206,6 +206,11 @@ class LatentMoELM:
     #: ZeRO-3 partition dims (set by the engine at stage 3): a segment's
     #: scan gathers one period of layers at a time, the rest at apply entry
     zero3_dims: object = None
+    #: (prefix, all): the sorted (token, choice) rows an expert layer's
+    #: routed part works on when the pairs held fit the prefix, and when
+    #: they do not (``moe.prefix_rows``; per shard and micro-batch), of the
+    #: program ``apply`` last traced; zeros before any
+    routed_rows: tuple = (0, 0)
 
     @classmethod
     def from_size(cls, size: str, **overrides) -> "LatentMoELM":
@@ -247,6 +252,8 @@ class LatentMoELM:
             "latent_rank": cfg.latent_rank,
             "qk_head_dim": cfg.qk_head_dim,
             "v_head_dim": cfg.v_dim,
+            "routed_rows_prefix": self.routed_rows[0],
+            "routed_rows_all": self.routed_rows[1],
         }
 
     # ------------------------------------------------------------------ init
@@ -292,6 +299,10 @@ class LatentMoELM:
         the mean per-token LM loss plus the balance loss of every expert
         layer held (fp32 scalar, local to the DP shard)."""
         cfg = self.config
+        pairs = tokens.size * cfg.experts_per_token
+        self.routed_rows = (M.prefix_rows(
+            pairs, cfg.experts_held[1] // L.axis_size_or_1(MODEL_AXIS),
+            cfg.num_experts), pairs)
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         z3_blocks = z3_deferred.get("blocks") or [None] * len(cfg.segments)
         with S.scope("embed"):

@@ -19,7 +19,9 @@ different jobs; do not take one for the other.
    sorted by expert, run through grouped matmuls over ragged groups
    (``ops/grouped_matmul.py``: Pallas kernels on a TPU) and gathered back
    weighted.  No capacity, no drop: the group sizes are what the routing
-   gives.  What the absent experts would add is left out — the partial
+   gives, and the rows worked on are a static prefix of the sorted pairs
+   sized from the share, or all of them when more landed here (a branch on
+   the device, both exact).  What the absent experts would add is left out — the partial
    result is this chip's part of an expert-parallel layer, and nothing
    stands in for the other chips or their exchange.
 
@@ -57,6 +59,7 @@ behavior).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +69,8 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.observability import scopes as S
-from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+from deepspeed_tpu.ops.grouped_matmul import (TILE_ROWS, _tile_rows,
+                                              grouped_matmul)
 from deepspeed_tpu.ops.remat_names import FFN1
 from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
@@ -306,17 +310,25 @@ def _held_rows(rows, pos, n_held):
 
 @jax.custom_vjp
 def dispatch(x, order, pos, n_held):
-    """``x`` [S, h] -> the sorted pairs' rows [R, h] (``order``, ``pos`` of
-    ``sort_share``; a token appears once per choice).  The transpose of a
-    gather is a scatter-add, serial on a TPU; a pair's sorted row is known
-    (``pos``), so the backward is a gather too: ``dx_t = sum_j
-    d_rows[pos[t, j]]`` over the pairs held.  It also leaves out what a
-    transpose would add: the rows past ``n_held`` of a grouped matmul's
-    gradient were never written.  Measured against ``jnp.take`` and a
-    weighted sum left to autodiff (with the mask on the rows that form then
-    needs) at 2 x 8,192 tokens, 8 of 64 experts, on a v5e: routing 173 ms a
-    step here, 192 there, the experts' products 63 against 74, 22,053
-    against 21,094 tokens/s (PERF.md, PR 33)."""
+    """``x`` [S, h] -> the rows of the sorted pairs ``order`` [rows] (the
+    first ``rows`` of ``sort_share``'s order: all ``R``, or a prefix that
+    holds every pair held; ``pos`` [S, k] is over all pairs; a token
+    appears once per choice).  The transpose of a gather is a scatter-add,
+    serial on a TPU; a pair's sorted row is known (``pos``), so the
+    backward is a gather too: ``dx_t = sum_j d_rows[pos[t, j]]`` over the
+    pairs held.  It also leaves out what a transpose would add: the rows
+    past ``n_held`` of a grouped matmul's gradient were never written.
+
+    Measured on a v5e at 2 x 8,192 tokens, 8 of 64 experts.  Over all ``R``
+    = 98,304 rows, against ``jnp.take`` and a weighted sum left to autodiff
+    (with the mask on the rows that form then needs): routing 173 ms a step
+    here, 192 there, the experts' products 63 against 74, 22,053 against
+    21,094 tokens/s (PERF.md, PR 33, 2026-10).  Over the 24,576-row prefix
+    (PERF.md, PR 34, 2026-10; one layer's forward and backward, 32.30 ms
+    with every form a gather): a pair-keyed gather out of the short table
+    costs 2.1 ms (1.47 of it the copy into ``[S, k, h]``), the fp32
+    scatter-add over the prefix rows that could replace it 2.45 — here
+    32.49 ms, in ``combine``'s forward 32.64: both stay gathers."""
     return jnp.take(x, order // pos.shape[1], axis=0)
 
 
@@ -336,8 +348,16 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def combine(rows, gates, order, pos, n_held):
     """``y_t = sum_j gates[t, j] * rows[pos[t, j]]`` over the pairs held
-    (fp32 sum, ``rows``' dtype): the experts' outputs [R, h] back in token
-    order [S, h], weighted.  Backward by gathers, as ``dispatch``."""
+    (fp32 sum, ``rows``' dtype): the experts' outputs [rows, h], in the
+    order ``order`` [rows] of ``dispatch``, back in token order [S, h],
+    weighted.  Backward by gathers, as ``dispatch`` — but for the gates'
+    gradient on a prefix, ``d_gates[t, j] = rows[pos[t, j]] . g[t]``: the
+    rows' gradient has already gathered ``g`` by sorted row, so the dot is
+    taken per sorted row and ONE NUMBER a held pair is put back in pair
+    order, where the gather form fetches the pair's whole row again (one
+    layer's forward and backward on a v5e at the 24,576-row prefix: 29.82
+    ms against 32.30, PERF.md, PR 34; over all ``R`` rows the form the
+    worst case always had is kept, so that branch is that program)."""
     picked = _held_rows(rows, pos, n_held).astype(jnp.float32)
     return jnp.sum(picked * gates[..., None], axis=1).astype(rows.dtype)
 
@@ -350,12 +370,20 @@ def _combine_fwd(rows, gates, order, pos, n_held):
 def _combine_bwd(res, g):
     rows, gates, order, pos, n_held = res
     gf = g.astype(jnp.float32)
-    picked = _held_rows(rows, pos, n_held).astype(jnp.float32)
-    d_gates = jnp.sum(picked * gf[:, None, :], axis=-1)
+    on_prefix = rows.shape[0] < pos.size
+    if not on_prefix:
+        picked = _held_rows(rows, pos, n_held).astype(jnp.float32)
+        d_gates = jnp.sum(picked * gf[:, None, :], axis=-1)
     held = jnp.arange(rows.shape[0]) < n_held
-    weight = jnp.where(held, jnp.take(gates.reshape(-1), order), 0.0)
-    d_rows = (weight[:, None]
-              * jnp.take(gf, order // pos.shape[1], axis=0))
+    weight = jnp.where(held, jnp.take(gates.reshape(-1), order),
+                       0.0)[:, None]
+    by_row = jnp.take(gf, order // pos.shape[1], axis=0)
+    if on_prefix:
+        per_row = jnp.sum(rows.astype(jnp.float32) * by_row, axis=-1)
+        d_gates = jnp.zeros((pos.size,), jnp.float32).at[
+            jnp.where(held, order, pos.size)].set(
+                per_row, mode="drop", unique_indices=True).reshape(pos.shape)
+    d_rows = weight * by_row
     return d_rows.astype(rows.dtype), d_gates, None, None, None
 
 
@@ -364,8 +392,8 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 @S.scoped("experts")
 def grouped_swiglu(rows, p, sizes, n_held):
-    """``SwiGLU_e`` of each group of sorted ``rows`` [R, h] through its own
-    expert: three grouped matmuls over the ragged groups ``sizes`` [e]
+    """``SwiGLU_e`` of each group of sorted ``rows`` [rows, h] through its
+    own expert: three grouped matmuls over the ragged groups ``sizes`` [e]
     (``p``: ``exp_gate_w``, ``exp_up_w`` [e, h, f], ``exp_down_w`` [e, f,
     h]).  The rows past ``n_held`` belong to no group: a grouped matmul
     neither reads nor writes them, so the two pre-activations are zeroed
@@ -378,6 +406,77 @@ def grouped_swiglu(rows, p, sizes, n_held):
     up = checkpoint_name(jnp.where(
         held, grouped_matmul(rows, p["exp_up_w"], sizes), 0), FFN1)
     return grouped_matmul(L.silu(gate) * up, p["exp_down_w"], sizes)
+
+
+#: The held-row prefix is this many times the rows that land on the held
+#: experts when every expert draws the same load.  The routing's own noise
+#: is small beside it (uniform choices at the cell's 98,304 pairs: 12,288 +-
+#: about 100), so what the room is for is a router that favours the experts
+#: held: at 2 a share whose experts are ALL twice as popular as the mean
+#: still runs in the prefix, which a balance-loss-trained router does not
+#: reach over a whole share (single experts do).  More room costs in
+#: proportion (every gather, mask and activation of the prefix runs over
+#: its rows, held or not); less room sends steps of an unbalanced router to
+#: the overflow branch, at the full worst-case price.  A constant, not a
+#: setting: the overflow branch makes any value exact.
+HEADROOM = 2
+
+
+def prefix_rows(pairs, count, num_experts):
+    """Rows of the static prefix of the sorted pairs that the routed part
+    works on when the ``count`` experts held of ``num_experts`` took no
+    more: ``HEADROOM`` times their even share of all ``pairs``, rounded up
+    to the row tile the grouped matmuls walk ``pairs`` rows in (128 where
+    they have none), at most ``pairs`` — the whole layer, and shapes too
+    small to round under it, have no prefix."""
+    tile = _tile_rows(pairs) or TILE_ROWS[-1]
+    even = HEADROOM * pairs * count
+    return min(pairs, -(-even // (num_experts * tile)) * tile)
+
+
+def routed_part(rows, flat, p, gates, order, pos, sizes, n_held):
+    """The held experts' part of the layer on the first ``rows`` (static)
+    of the sorted pairs, which must hold every pair held (``n_held <=
+    rows``): ``dispatch`` of ``order[:rows]``, ``grouped_swiglu`` on
+    ``[rows, h]``, ``combine`` back to ``[S, h]``.  ``rows = R`` is the
+    worst case, every pair of every token."""
+    order = order[:rows]
+    with S.scope("route"):
+        sorted_rows = dispatch(flat, order, pos, n_held)
+    out = grouped_swiglu(sorted_rows, p, sizes, n_held)
+    with S.scope("route"):
+        return combine(out, gates, order, pos, n_held)
+
+
+def held_experts(flat, p, chosen, gates, first, num_experts):
+    """``sum_j gates[t, j] SwiGLU_e(flat[t])`` [S, h] over the choices
+    ``e = chosen[t, j]`` that fall on the experts held, ``[first, first +
+    e_local)`` of ``num_experts`` (``p``'s ``exp_*_w``): the pairs sorted
+    (``sort_share``), then ``routed_part`` on the prefix if the ``n_held``
+    pairs that landed here fit it, else on all of them — chosen on the
+    device, both exact (``dropless_moe_ffn``)."""
+    e_local = p["exp_gate_w"].shape[0]
+    with S.scope("route"):
+        order, pos, sizes = sort_share(chosen, first, e_local)
+        n_held = jnp.sum(sizes)
+    # the branches' operands: of ``p`` only what they read
+    experts = {name: p[name]
+               for name in ("exp_gate_w", "exp_up_w", "exp_down_w")}
+    operands = (flat, experts, gates, order, pos, sizes, n_held)
+    pairs = chosen.size
+    prefix = prefix_rows(pairs, e_local, num_experts)
+    if prefix == pairs:
+        return routed_part(pairs, *operands)
+    # Each branch under ``jax.checkpoint``: what it hands its backward is
+    # its operands alone, which the branches share.  Autodiff of a bare
+    # ``cond`` makes every intermediate either backward reads an output of
+    # the forward ``cond`` — the worst case's fp32 activation pieces among
+    # them, zero-filled whenever the prefix runs — and the cell's step then
+    # needs 16.57 GB of a v5e's 15.75 (PERF.md, PR 34).  An outer policy
+    # still finds the names inside (``selective`` keeps ``ffn1``).
+    part = jax.checkpoint(routed_part, static_argnums=0)
+    return jax.lax.cond(n_held <= prefix, functools.partial(part, prefix),
+                        functools.partial(part, pairs), *operands)
 
 
 @S.scoped("moe")
@@ -395,13 +494,25 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
         + SwiGLU_shared(x_t)``
 
     with ``K_t`` and ``g`` of ``route_tokens`` over all ``num_experts``.
+
+    The rows worked on.  The pairs held come first in ``sort_share``'s
+    order, so the routed part (``routed_part``) runs on a static prefix of
+    ``prefix_rows`` rows (a quarter of all ``R`` pairs for an eighth of the
+    experts) whenever the ``n_held`` pairs that landed here fit it, and on
+    all ``R`` rows — the worst case, the one program there was before the
+    prefix — when they do not: a ``lax.cond`` on the device, both branches
+    exact.  NO PAIR IS DROPPED AND THERE IS NO CAPACITY: an overflowing
+    step costs the worst case's time and gives its numbers.  A share with
+    no prefix under ``R`` (the whole layer) has no ``cond``.
+
     Under expert parallelism over the ``model`` axis the held experts are
-    split evenly over the shards (``e = count / size``, expert dim sharded like
-    ``moe_ffn``'s), each shard computes its own part and a ``psum`` adds
-    them; on one chip there is no exchange and none is emulated.  Scopes:
-    ``dstpu/route`` (scores, top-k, gates, sort, the two gathers, balance
-    loss), ``dstpu/experts`` (the grouped matmuls), ``dstpu/ffn`` (the
-    shared experts), all inside ``dstpu/moe``."""
+    split evenly over the shards (``e = count / size``, expert dim sharded
+    like ``moe_ffn``'s), each shard computes its own part — on its own
+    ``n_held``, so in its own branch — and a ``psum`` outside the branch
+    adds them; on one chip there is no exchange and none is emulated.
+    Scopes: ``dstpu/route`` (scores, top-k, gates, sort, the two gathers,
+    balance loss), ``dstpu/experts`` (the grouped matmuls), ``dstpu/ffn``
+    (the shared experts), all inside ``dstpu/moe``, in either branch."""
     B, T_len, h = x.shape
     first, count = held
     ep = L.axis_size_or_1(MODEL_AXIS)
@@ -418,12 +529,8 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
             scale=route_scale)
         aux = balance_loss(scores.reshape(B, T_len, num_experts),
                            chosen.reshape(B, T_len, top_k), balance_alpha)
-        order, pos, sizes = sort_share(chosen, first, e_local)
-        n_held = jnp.sum(sizes)
-        rows = dispatch(flat, order, pos, n_held)
-    rows = grouped_swiglu(rows, p, sizes, n_held)
-    with S.scope("route"):
-        routed = combine(rows, gates, order, pos, n_held)
-        if ep > 1:
+    routed = held_experts(flat, p, chosen, gates, first, num_experts)
+    if ep > 1:
+        with S.scope("route"):
             routed = jax.lax.psum(routed, MODEL_AXIS)
     return routed.reshape(B, T_len, h) + T._gated_mlp(x, p), aux

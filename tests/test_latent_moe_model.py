@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 from benchmark import cell as cells
 from deepspeed_tpu.models import LatentMoEConfig, LatentMoELM
+from deepspeed_tpu.models import moe as M
 from deepspeed_tpu.parallel.topology import make_mesh
 
 SEQ = 128
@@ -123,7 +124,11 @@ def test_bf16_stays_in_a_band_round_the_float32_reference(family):
 @pytest.mark.parametrize("policy", ["selective", "full", "dots"])
 def test_recomputation_policies_give_the_unrecomputed_gradients(family,
                                                                 policy):
+    """On a share whose prefix (512 rows) is under its 768 pairs, so with
+    the branch — and each branch's own ``jax.checkpoint`` — inside the
+    layer's."""
     _, _, model, params, batch = setting(family, (4, 4))
+    assert M.prefix_rows(2 * SEQ * 3, 4, 16) == 512
     plain = dataclasses.replace(model, config=dataclasses.replace(
         model.config, remat=False))
     again = dataclasses.replace(model, config=dataclasses.replace(
@@ -152,14 +157,30 @@ def test_selective_saves_the_grouped_matmuls_and_the_latent_projections(
                 params, *batch))
         return text.count(primitive + "[")
 
-    # per expert layer: 3 forward products and 6 in the backward (input and
-    # weight gradients) under either policy; "full" also replays the gate
-    # and the up product (the down product's output feeds no gradient),
-    # "selective" keeps both
+    # per expert layer and branch (the prefix's, the overflow's): 3
+    # forward products and 6 in the backward (input and weight gradients)
+    # under either policy; "full" also replays the three products,
+    # "selective" keeps the gate and the up one — the policy finds their
+    # names inside the branch and inside the branch's own checkpoint
     full, selective = (count(p, "ragged_dot_general")
                        for p in ("full", "selective"))
-    assert full - selective == 2      # one traced expert layer's gate and up
+    assert (full, selective) == (2 * 12, 2 * 10)
     assert count("full", "dot_general") > count("selective", "dot_general")
+
+
+def test_the_step_holds_no_host_callback(family):
+    """The branch is taken on the device: no ``debug_callback``,
+    ``io_callback`` or ``pure_callback`` (a ``debug.print`` is one) in the
+    gradient's jaxpr — a program with a host callback is not written to
+    the persistent compile cache."""
+    _, _, model, params, batch = setting(family, (4, 4))
+    mesh = make_mesh(devices=jax.devices()[:1])
+    text = str(jax.make_jaxpr(jax.shard_map(
+        jax.grad(lambda p, t, l: model.apply(p, t, l)), mesh=mesh,
+        in_specs=(P(),) * 3, out_specs=P(), check_vma=False))(
+            params, *batch))
+    assert " cond[" in text
+    assert "callback" not in text
 
 
 # ------------------------------------------------------- what is refused
@@ -189,7 +210,19 @@ def test_step_counts_and_the_published_sizes():
     assert counts == {
         "layers_dense": 1, "layers_moe": 26, "layer_applications": 27,
         "experts_total": 64, "experts_held": 64, "experts_per_token": 6,
-        "latent_rank": 512, "qk_head_dim": 192, "v_head_dim": 128}
+        "latent_rank": 512, "qk_head_dim": 192, "v_head_dim": 128,
+        "routed_rows_prefix": 0, "routed_rows_all": 0}    # nothing traced
+    # the cell's share and micro-batch: a quarter of the 98,304 pairs
+    cell = LatentMoELM(LatentMoEConfig(
+        experts_held=(0, 8), vocab_size=512,
+        segments=((("dense",), 1), (("moe",), 1))))
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    jax.eval_shape(lambda p, t, l: on_one_device(cell.apply, p, t, l),
+                   jax.eval_shape(cell.init_params, jax.random.PRNGKey(0)),
+                   ids, ids)
+    counts = cell.step_counts()
+    assert (counts["routed_rows_prefix"], counts["routed_rows_all"]) == (
+        24576, 98304)
     shapes = jax.eval_shape(
         LatentMoELM.from_size("tiny", experts_held=(4, 4)).init_params,
         jax.random.PRNGKey(0))

@@ -4,6 +4,8 @@ which experts it holds.  Tiny sizes, CPU, float32.  The plain reference is
 dense mask, no sort), which imports nothing of the program.  (The whole
 model against the reference: tests/test_latent_moe_model.py.)"""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,11 +124,13 @@ def test_the_four_shares_add_up_to_the_whole_layer():
     assert landed == int(pairs)
 
 
-def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
+def test_no_token_is_dropped_when_every_token_picks_the_same_experts(
+        monkeypatch):
     """A bias that sends EVERY token to experts 4, 5, 6: groups of
     ``rows x seq`` pairs each, no capacity anywhere, the reference's
     result; the fourth expert of the share gets no token, contributes
-    zeros and gets a zero gradient."""
+    zeros and gets a zero gradient.  (With the bias at zero the same share
+    sees about a quarter of the pairs and runs in its prefix.)"""
     x = tokens(rows=2, seq=64)
     p = weights(held=(4, 4))
     p["router_b"] = jnp.zeros((E,)).at[jnp.array([4, 5, 6])].set(10.0)
@@ -147,6 +151,17 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     # the same layer with the idle expert's weights changed: nothing moves
     q = dict(p, exp_down_w=p["exp_down_w"].at[3].set(7.0))
     same(layer(x, q, (4, 4))[0], y, 0)
+    # 384 pairs held, every one of them: past the share's prefix of 256
+    # rows, so the layer took the overflow branch — shown by a prefix
+    # branch that returns NaN and is not seen here, and is with the bias
+    # at zero
+    assert M.prefix_rows(384, 4, E) == 256
+    part = M.routed_part
+    monkeypatch.setattr(M, "routed_part", lambda rows, *a: (
+        part(rows, *a) if rows == 384 else jnp.nan * part(rows, *a)))
+    same(layer(x, p, (4, 4))[0], y, 0)
+    assert bool(jnp.all(jnp.isnan(layer(tokens(seed=5, rows=2, seq=64),
+                                        weights(held=(4, 4)), (4, 4))[0])))
 
 
 def test_the_correction_bias_changes_the_chosen_set_and_not_the_gates():
@@ -234,6 +249,169 @@ def test_expert_parallel_shards_add_up_to_the_one_device_layer():
     same(aux, want_aux, 1e-6)
     with pytest.raises(ValueError, match="experts held over"):
         layer(x, p, (4, 3))
+
+
+# ------------------------------------------ the prefix and the overflow
+
+@pytest.mark.parametrize("pairs, count, experts, want", [
+    (2 * 8192 * 6, 8, 64, 24576),   # the cell: a quarter of 98,304, 48 tiles
+    (2 * 8192 * 6, 64, 64, 98304),  # the whole layer: every row
+    (384, 4, 16, 256),              # 2 x 192 rounded up to 128-row tiles
+    (240, 4, 16, 128),              # no tile divides 240: 128-row steps
+    (240, 16, 16, 240),
+    (98304, 1, 64, 3072),           # one expert held: 2 x 1,536
+    (128, 4, 16, 128),              # too small to round under all rows
+])
+def test_prefix_rows_by_hand(pairs, count, experts, want):
+    assert M.prefix_rows(pairs, count, experts) == want
+    assert M.HEADROOM == 2
+
+
+def hand_chosen(n_held, n_tokens=128, first=4, count=4, seed=0):
+    """``chosen`` [tokens, K] with EXACTLY ``n_held`` pairs on the experts
+    ``[first, first + count)``, scattered over the tokens; a token's
+    choices are distinct experts, as a top-k's are."""
+    pairs = n_tokens * K
+    on_share = np.zeros(pairs, bool)
+    on_share[np.random.default_rng(seed).permutation(pairs)[:n_held]] = True
+    others = [e for e in range(E) if not first <= e < first + count]
+    t, j = np.divmod(np.arange(pairs), K)
+    chosen = np.where(on_share, first + (t + j) % count,
+                      np.asarray(others)[(5 * t + j) % len(others)])
+    return jnp.asarray(chosen.reshape(n_tokens, K), jnp.int32)
+
+
+@pytest.mark.parametrize("n_held", [60, 256, 257, 384])
+def test_either_branch_is_the_routed_part_on_all_rows(n_held):
+    """384 pairs, the share (4, 4), a prefix of 256 rows: with 60 and with
+    exactly 256 pairs held the layer runs ``routed_part`` on the prefix,
+    with 257 and with all 384 on every row.  Against ``routed_part`` on
+    all rows without a branch: the output and the gradient of x, of the
+    router (through the gates) and of the three expert matrices — equal
+    bit for bit where the overflow branch ran (the same program), to 1e-6
+    of the largest entry where the prefix did."""
+    chosen = hand_chosen(n_held)
+    x = tokens(rows=1, seq=128).reshape(-1, H)
+    p = weights(held=(4, 4))
+    weight = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def branched(x, p, gates):
+        return M.held_experts(x, p, chosen, gates, 4, E)
+
+    assert int(jnp.sum(M.sort_share(chosen, 4, 4)[2])) == n_held
+
+    def all_rows(x, p, gates):
+        order, pos, sizes = M.sort_share(chosen, 4, 4)
+        return M.routed_part(chosen.size, x, p, gates, order, pos, sizes,
+                             jnp.sum(sizes))
+
+    def run(part):
+        def loss(x, p):
+            scores = jax.nn.sigmoid(x @ p["router_w"])
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            y = part(x, p, SCALE * picked / jnp.sum(picked, -1,
+                                                     keepdims=True))
+            return jnp.sum(y * weight), y
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                              has_aux=True))(x, p)
+
+    (_, got_y), got = run(branched)
+    (_, want_y), want = run(all_rows)
+    assert float(jnp.max(jnp.abs(want_y))) > 0.1
+    tol = 0 if n_held > M.prefix_rows(chosen.size, 4, E) else 1e-6
+    for name, a, b in [("y", got_y, want_y), ("x", got[0], want[0])] + [
+            (k, got[1][k], want[1][k]) for k in
+            ("router_w", "exp_gate_w", "exp_up_w", "exp_down_w")]:
+        assert float(jnp.max(jnp.abs(b))) > 0, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=tol * float(jnp.max(jnp.abs(b))), err_msg=name)
+
+
+def test_the_whole_layer_has_no_branch_and_a_share_has_one():
+    """A layer that holds every expert has no prefix under all its rows:
+    its program is ``routed_part`` on all rows, the one there was before
+    the prefix (the gradient's jaxpr, restated here, letter for letter); a
+    share's has the one ``cond``, both branches with grouped matmuls."""
+    x = tokens()
+
+    def before_the_prefix(x, p, held):
+        flat = x.reshape(-1, H)
+        scores, chosen, gates = M.route_tokens(
+            flat, p["router_w"], p["router_b"], top_k=K, scale=SCALE)
+        aux = M.balance_loss(scores.reshape(*x.shape[:2], E),
+                             chosen.reshape(*x.shape[:2], K), ALPHA)
+        order, pos, sizes = M.sort_share(chosen, *held)
+        n_held = jnp.sum(sizes)
+        rows = M.dispatch(flat, order, pos, n_held)
+        rows = M.grouped_swiglu(rows, p, sizes, n_held)
+        routed = M.combine(rows, gates, order, pos, n_held)
+        return routed.reshape(x.shape) + M.T._gated_mlp(x, p), aux
+
+    def text(fn, held):
+        mesh = make_mesh(devices=jax.devices()[:1])
+        jaxpr = jax.make_jaxpr(jax.shard_map(
+            jax.grad(lambda x, p: jnp.sum(fn(x, p, held)[0]),
+                     argnums=(0, 1)),
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(x, weights(held=held))
+        # a frozenset (shard_map's manual axes) prints in any order
+        return re.sub(r"frozenset\(\{[^}]*\}\)", "frozenset", str(jaxpr))
+
+    whole = text(ffn, (0, E))
+    assert " cond[" not in whole
+    assert whole == text(before_the_prefix, (0, E))
+    share = text(ffn, (4, 4))
+    assert share.count(" cond[") == 2          # forward, backward
+    assert share.count("ragged_dot_general[") == 2 * whole.count(
+        "ragged_dot_general[") + 2 * 3         # a branch recomputes its own
+
+
+def test_one_shard_overflows_and_the_other_does_not():
+    """Expert parallelism over a ``model`` axis of 2 with a bias that sends
+    every token to experts 4 and 5: the shard holding (4, 2) gets 256 +
+    pairs, past its prefix of 128 rows, the shard holding (6, 2) a handful;
+    each takes its own branch, the ``psum`` outside adds them, and the sum
+    is the one-device layer's and the reference's."""
+    held = (4, 4)
+    x, p = tokens(rows=2, seq=64), weights(held=held)
+    p["router_b"] = jnp.zeros((E,)).at[jnp.array([4, 5])].set(10.0)
+    with jax.default_matmul_precision("highest"):
+        _, chosen, _ = M.route_tokens(x.reshape(-1, H), p["router_w"],
+                                      p["router_b"], top_k=K, scale=SCALE)
+    prefix = M.prefix_rows(chosen.size, 2, E)
+    landed = [int(jnp.sum(M.sort_share(chosen, first, 2)[2]))
+              for first in (4, 6)]
+    assert prefix == 128 and landed[0] >= 256 and 0 < landed[1] <= prefix
+    mesh = make_mesh(model_parallel_size=2, devices=jax.devices()[:2])
+    by_expert, col, row = (P(MODEL_AXIS, None, None), P(None, MODEL_AXIS),
+                           P(MODEL_AXIS, None))
+    specs = {"router_w": P(), "router_b": P(), "exp_gate_w": by_expert,
+             "exp_up_w": by_expert, "exp_down_w": by_expert, "gate_w": col,
+             "up_w": col, "down_w": row}
+
+    sharded = jax.shard_map(
+        lambda x, p: ffn(x, p, held)[0], mesh=mesh, in_specs=(P(), specs),
+        out_specs=P(), check_vma=False)
+
+    def total(fn):
+        return lambda x, p: jnp.sum(jnp.square(fn(x, p)))
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(sharded)(x, p)
+        got_grads = jax.jit(jax.grad(total(sharded), argnums=(0, 1)))(x, p)
+        want_grads = jax.grad(total(lambda x, p: plain(x, p, held)[0]),
+                              argnums=(0, 1))(x, p)
+    same(got, layer(x, p, held)[0])
+    same(got, plain(x, p, held)[0])
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(got_grads)[0],
+            jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4,
+            atol=1e-5 * float(jnp.max(jnp.abs(b))) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_the_pallas_grouped_matmul_is_ragged_dot_inside_the_groups():

@@ -348,6 +348,9 @@ def test_a_latent_attention_expert_step_holds_its_four_scopes(policy):
         "layers_dense": 1, "layers_moe": 2, "experts_total": 16,
         "experts_held": 4, "experts_per_token": 3, "latent_rank": 32,
         "qk_head_dim": 32, "v_head_dim": 16,
+        # 2 x 64 tokens x 3 choices; the share's prefix, written when the
+        # step program was traced
+        "routed_rows_prefix": 256, "routed_rows_all": 384,
         "layer_applications_per_step": 3}
 
 

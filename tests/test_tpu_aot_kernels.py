@@ -85,3 +85,34 @@ def test_the_grouped_matmuls_compile_to_kernels(one_chip, monkeypatch):
     assert all("dstpu/experts" in line for line in kernels(text))
     # a dense expansion would hold a product over all rows for every expert
     assert f"bf16[{e},{R}," not in text
+
+
+def test_both_branches_of_the_expert_layer_hold_the_kernels(one_chip,
+                                                            monkeypatch):
+    """The held experts' part at the cell's shapes (2 x 8,192 tokens, top-6,
+    8 of 64 experts): a ``conditional`` on the device whose prefix branch
+    runs the grouped-matmul kernels over 24,576 sorted rows and whose
+    overflow branch runs them over all 98,304 — the same nine products in
+    either (each branch recomputes its three and takes six gradients), all
+    Pallas kernels under ``dstpu/experts``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S_, k, h, f, e, E = 2 * 8192, 6, 2048, 1408, 8, 64
+    assert M.prefix_rows(S_ * k, e, E) == 24576
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    p = {"exp_gate_w": S(e, h, f), "exp_up_w": S(e, h, f),
+         "exp_down_w": S(e, f, h)}
+
+    def loss(flat, p, chosen, gates):
+        out = M.held_experts(flat, p, chosen, gates, 0, E)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = compiled_text(
+        jax.grad(loss, argnums=(0, 1, 3)), S(S_, h), p,
+        S(S_, k, dt=jnp.int32), S(S_, k, dt=jnp.float32))
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert " conditional(" in text and "ragged-dot" not in text
+    assert all("dstpu/experts" in line for line in kernels)
+    on = lambda rows: sum(f"bf16[{rows}," in line for line in kernels)
+    assert on(24576) == on(98304) == 9 and len(kernels) == 18
