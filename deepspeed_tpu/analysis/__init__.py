@@ -62,7 +62,7 @@ __all__ = [
     "concurrency", "lockwatch", "analyze_jaxpr",
     "analyze_step", "analyze_engine", "analyze_engine_train_batch",
     "analyze_engine_train_many", "trace_train_batch", "lower_train_batch",
-    "train_batch_args",
+    "train_batch_args", "fused_tail",
     "train_many_args", "step_args",
     "check_shard_specs",
     "validate_specs_or_raise", "dispatch_report",
@@ -183,19 +183,37 @@ def train_batch_args(engine, batch):
     THE single owner of the step-function call protocol.  Every caller
     that needs the tuple (the tracer below, the capacity planner, the
     XLA-parity tests, the engine itself) marshals through here;
-    hand-rolled copies drift silently when the signature changes.  With
-    the metric spool on (``observability.report_window``) the tuple grows
-    a trailing spool-state argument — the device ring buffer the compiled
-    step appends this boundary's metrics into."""
+    hand-rolled copies drift silently when the signature changes.  Two
+    optional trailing arguments, in this order: for a model that declares
+    step scalars, the device-side totals since the last read
+    (``observability.scalars.Channel.device``), which the step returns
+    with itself added; with the metric spool on
+    (``observability.report_window``), the spool state — the device ring
+    buffer the compiled step appends this boundary's metrics into."""
     batch = tuple(batch) if isinstance(batch, (tuple, list)) else (batch,)
     master = engine.master_flat if engine.zero_flat else engine.master
     args = (engine.params, master, engine.opt_state,
             engine.loss_scale_state, engine._current_hypers(),
             engine._zero_norm_w, engine._zero_gid_flat, batch)
+    return args + _trailing_args(engine)
+
+
+def fused_tail(engine):
+    """``((label, argument), ...)``: the optional tail both fused call
+    tuples share, in order — the step scalars' totals, then the spool
+    state."""
+    tail = ()
+    channel = getattr(engine, "_scalars", None)
+    if channel is not None:
+        tail += (("step_scalars", channel.device),)
     spool = getattr(engine, "_spool", None)
     if spool is not None:
-        args = args + (spool.state,)
-    return args
+        tail += (("spool", spool.state),)
+    return tail
+
+
+def _trailing_args(engine):
+    return tuple(arg for _, arg in fused_tail(engine))
 
 
 def train_many_args(engine, batches):
@@ -203,8 +221,9 @@ def train_many_args(engine, batches):
     state — single owner like :func:`train_batch_args`.  ``batches`` is
     the sequence of K per-step batch tuples (separate program arguments,
     NOT a stacked tree — see ``engine._build_train_many`` for why); the
-    hyper slot carries the staged ``[K, 4, G]`` block, and with the
-    metric spool on the tuple grows the trailing ring state."""
+    hyper slot carries the staged ``[K, 4, G]`` block, and the tuple ends
+    with :func:`train_batch_args`' optional tail (step-scalar totals, spool
+    state)."""
     batches = tuple(tuple(b) if isinstance(b, (tuple, list)) else (b,)
                     for b in batches)
     k = len(batches)
@@ -213,10 +232,7 @@ def train_many_args(engine, batches):
             engine.loss_scale_state, engine._stage_hypers_many(k),
             engine._zero_norm_w, engine._zero_gid_flat,
             engine._live_flag, batches)
-    spool = getattr(engine, "_spool", None)
-    if spool is not None:
-        args = args + (spool.state,)
-    return args
+    return args + _trailing_args(engine)
 
 
 def analyze_engine_train_many(engine, batches) -> Report:

@@ -77,6 +77,7 @@ CONTROL_PLANE = (
     "observability/fleet.py",
     "observability/flightrec.py",
     "observability/health.py",
+    "observability/scalars.py",
     "observability/spool.py",
     "observability/tracing.py",
     "observability/detectors.py",

@@ -532,19 +532,25 @@ def _engine_step_args(engine, grads):
 
 
 #: argument labels of the fused call protocol (analysis.train_batch_args).
-#: The optional metric-spool state is appended LAST — argument offsets 0..7
-#: stay aligned with the shard_map body invars whether or not it is there
-#: (the spool append runs OUTSIDE the shard_map, at the jit level).
+#: The optional tail (step-scalar totals, metric-spool state) comes LAST —
+#: argument offsets 0..7 stay aligned with the shard_map body invars
+#: whether or not it is there (the spool append runs OUTSIDE the
+#: shard_map, at the jit level).
 _TRAIN_BATCH_LABELS = ("params", "master", "opt_state", "loss_scale",
-                       "hypers", "zero_norm_w", "zero_gid", "batch",
-                       "spool")
+                       "hypers", "zero_norm_w", "zero_gid", "batch")
 
 #: K-fused call protocol (analysis.train_many_args): the hyper slot is
 #: the [K, 4, G] block, "live" the cond predicate input, "batch" the
 #: tuple of K per-step batch trees
 _TRAIN_MANY_LABELS = ("params", "master", "opt_state", "loss_scale",
                       "hypers", "zero_norm_w", "zero_gid", "live",
-                      "batch", "spool")
+                      "batch")
+
+
+def _tail_labels(engine):
+    """Labels of the optional trailing arguments, in the protocol's order."""
+    from deepspeed_tpu import analysis
+    return tuple(label for label, _ in analysis.fused_tail(engine))
 
 
 def plan_engine(engine, batch, train: bool = True,
@@ -597,7 +603,8 @@ def plan_engine(engine, batch, train: bool = True,
         closed = jax.make_jaxpr(fn)(*args)
         programs.append(analyze_program(
             fn, args, donate_argnums=donate,
-            arg_labels=_TRAIN_MANY_LABELS, subject="train_many",
+            arg_labels=_TRAIN_MANY_LABELS + _tail_labels(engine),
+            subject="train_many",
             profile=profile, closed=closed))
         if with_comm:
             comm = commplan.analyze_comm(
@@ -613,7 +620,8 @@ def plan_engine(engine, batch, train: bool = True,
         closed = jax.make_jaxpr(fn)(*args)
         programs.append(analyze_program(
             fn, args, donate_argnums=donate,
-            arg_labels=_TRAIN_BATCH_LABELS, subject="train_batch",
+            arg_labels=_TRAIN_BATCH_LABELS + _tail_labels(engine),
+            subject="train_batch",
             profile=profile, closed=closed))
         if with_comm:
             comm = commplan.analyze_comm(

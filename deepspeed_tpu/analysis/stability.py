@@ -414,10 +414,6 @@ def predict_executables_serve(engine) -> ExecutablePrediction:
 
 # ----------------------------------------------------------- engine surface
 
-#: fused-call argument labels (mirrors memplan._TRAIN_BATCH_LABELS; the
-#: trailing spool state is optional)
-_TRAIN_LABELS = ("params", "master", "opt_state", "loss_scale", "hypers",
-                 "zero_norm_w", "zero_gid", "batch", "spool")
 _STEP_LABELS = ("master", "opt_state", "grads", "loss_scale", "hypers",
                 "zero_norm_w", "zero_gid")
 
@@ -458,11 +454,21 @@ def check_engine(engine, batch, fused: bool = True,
                                        spool.state)
         check_tree_shardings(engine.mesh, spool.state, specs, "spool",
                              rep)
+    channel = getattr(engine, "_scalars", None)
+    if channel is not None:
+        # so are the step scalars' totals (zeros after every read)
+        from jax.sharding import PartitionSpec
+        specs = jax.tree_util.tree_map(lambda _: PartitionSpec(),
+                                       channel.device)
+        check_tree_shardings(engine.mesh, channel.device, specs,
+                             "step_scalars", rep)
 
     from deepspeed_tpu import analysis
+    from deepspeed_tpu.analysis import memplan
     if fused:
         args = analysis.train_batch_args(engine, batch)
-        labels = _TRAIN_LABELS
+        # the fused call protocol's labels have one owner
+        labels = memplan._TRAIN_BATCH_LABELS + memplan._tail_labels(engine)
         subject = "train_batch"
     else:
         _, grad_shapes = jax.eval_shape(

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deepspeed_tpu import analysis as graph_lint
 from deepspeed_tpu import constants as C
 from deepspeed_tpu.observability import fences as obs_fences
+from deepspeed_tpu.observability import scalars as obs_scalars
 from deepspeed_tpu.observability import scopes as obs_scopes
 from deepspeed_tpu.observability.flightrec import RECORDER as _flightrec
 from deepspeed_tpu.observability.tracing import annotate as _annotate
@@ -775,6 +776,22 @@ class DeepSpeedTpuEngine:
         # ZeRO 1/2: what the last traced step program sends in the gradient
         # exchange (_scatter_grads_local; the ``boundary`` gauges)
         self._boundary_wire = {}
+        # the ``model`` / ``boundary`` gauges of the FUSED step program
+        # this engine runs, recorded when that program is traced
+        # (_make_fused_local): a later trace of another program (the split
+        # API's, a lint's, a capacity plan's) writes the live values above
+        # and on the module, and leaves these alone
+        self._step_gauges = {}
+        # step scalars (observability/scalars.py): counts the model takes
+        # on the device, one more operand and result of the fused step; a
+        # model that declares none has no channel and the program it had
+        declared = obs_scalars.declared_by(self.module)
+        self._scalars = obs_scalars.Channel(
+            declared, batch_shards=self.dp_world_size * self.sp_world_size,
+            model_shards=self.mp_world_size,
+            place=lambda t: jax.device_put(t, self._named(P()))
+        ) if declared else None
+        obs_scalars.remember_channel(self._scalars)
         self._cached_grads = None   # grads from the last forward
         self._pending = None        # latest train-mode forward not yet run
         self._pending_refs = []     # weakrefs to every unforced _PendingStep
@@ -1202,6 +1219,20 @@ class DeepSpeedTpuEngine:
         unset) — the gate every spooled code path checks."""
         return self._telemetry.spool
 
+    def read_step_scalars(self):
+        """The model's step scalars since ``initialize`` (counts it takes
+        on the device and returns beside its loss,
+        observability/scalars.py): ``{"steps", "micro_steps",
+        "batch_shards", "model_shards", "values": {name: number, or a list
+        for a vector entry}, "gauges"}`` covering every fused step
+        dispatched so far; None for a model that declares none.  ONE
+        counted fence (none where no step ran since the last read): the
+        device-side totals are folded into host-side Python numbers and
+        the next step starts from zeros, which is what keeps a long count
+        exact.  Never called by the step path; the split API
+        (``forward`` / ``backward`` / ``step``) reports nothing here."""
+        return self._scalars.read() if self._scalars is not None else None
+
     def flush_telemetry(self, local_only=False, fleet_timeout=None):
         """Synchronously drain the final (possibly partial) metric window
         — THE one deliberate telemetry fence.  Called by the resilience
@@ -1388,7 +1419,8 @@ class DeepSpeedTpuEngine:
             overflow = comm.overflow_any(overflow, ax)
         return overflow, sq_total
 
-    def _make_loss_and_grads(self, widen: bool = True):
+    def _make_loss_and_grads(self, widen: bool = True,
+                             scalars: bool = False):
         """Local (per-shard) loss + gradient computation shared by the
         split-API ``forward`` and the fused ``train_batch`` program.  Returns
         ``f(params, ls_scale, batch_args) -> (loss_out, grads)`` with grads
@@ -1396,13 +1428,23 @@ class DeepSpeedTpuEngine:
         are fp32 (what an accumulator adds and stage 0's ``psum`` reduces)
         unless ``widen=False``: a caller that hands them straight to the
         flat ZeRO boundary keeps the dtype the backward wrote, which is then
-        the dtype of the wire (``_scatter_grads_local``)."""
+        the dtype of the wire (``_scatter_grads_local``).
+
+        A model's step scalars (``observability.scalars.WithScalars``) leave
+        ``loss_fn`` through the same ``has_aux`` as the loss.  With
+        ``scalars=True`` (the fused step of an engine with a channel) the
+        result grows a third item, this shard's packed vectors of the
+        micro-step, ``{kind: f32[n]}``; otherwise they are dropped here —
+        the split API reports none — and the program is the one the model
+        has without them."""
         apply_fn = self._apply_fn()
         gas = float(self.gradient_accumulation_steps())
+        channel = self._scalars if scalars else None
 
         def loss_and_grads(params, ls_scale, batch_args):
             def loss_fn(p):
-                out = apply_fn(p, *batch_args)
+                # before the tuple test: WithScalars is not several losses
+                out, declared = obs_scalars.split(apply_fn(p, *batch_args))
                 # multi-output models return a tuple of losses; grads are of
                 # the sum (the reference user sums before backward —
                 # tests/unit/test_multi_output_model.py), each loss is
@@ -1411,11 +1453,20 @@ class DeepSpeedTpuEngine:
                     total = sum(jnp.asarray(l, jnp.float32) for l in out)
                 else:
                     total = jnp.asarray(out, jnp.float32)
+                if channel is not None:
+                    out = (out, channel.pack(declared))
+                elif declared is not None and self._scalars is None:
+                    raise TypeError(
+                        f"the model returned step scalars "
+                        f"{sorted(declared)} and has no step_scalars() "
+                        f"that declares them (observability/scalars.py)")
                 # loss scaling + grad-accum prescale in one multiply
                 # (reference _scale_loss :583 + loss_scaler backward :176-178)
                 return total * (ls_scale / gas), out
             (_, raw_out), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
+            if channel is not None:
+                raw_out, vecs = raw_out
             loss_out = jax.tree_util.tree_map(
                 lambda l: jax.lax.pmean(jnp.asarray(l, jnp.float32),
                                         self._loss_axes()), raw_out)
@@ -1450,6 +1501,8 @@ class DeepSpeedTpuEngine:
             if widen:
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32), grads)
+            if channel is not None:
+                return loss_out, grads, vecs
             return loss_out, grads
 
         return loss_and_grads
@@ -1773,7 +1826,7 @@ class DeepSpeedTpuEngine:
         apply_fn = self._apply_fn()
 
         def local(params, batch_args):
-            out = apply_fn(params, *batch_args)
+            out, _ = obs_scalars.split(apply_fn(params, *batch_args))
             return jax.tree_util.tree_map(
                 lambda l: jax.lax.pmean(jnp.asarray(l, jnp.float32),
                                         self._loss_axes()), out)
@@ -2559,24 +2612,36 @@ class DeepSpeedTpuEngine:
         ``_build_train_many`` (K steps unrolled per dispatch).  Returns
         ``f(params, master, opt_state, ls_state, hypers, normw, gids,
         batch_args) -> (params, master, opt_state, ls_state, overflow,
-        total_norm, last_loss)``."""
+        total_norm, last_loss)``.
+
+        With a step-scalar channel (a model that declares some) ``f`` takes
+        one more operand after ``batch_args`` and returns one more result:
+        the device-side totals since the last read, ``{kind: f32[n]}``,
+        with this step's reduced into them — over the accumulation scan,
+        then over the mesh in ONE collective per reduction kind
+        (``Channel.over_mesh``).  Without a channel ``f`` is what it was.
+
+        Tracing ``f`` records the ``model`` / ``boundary`` gauges of the
+        program into ``_step_gauges``."""
         gas = self.gradient_accumulation_steps()
         stage2 = self.zero_stage == 2
+        channel = self._scalars
         # the gradients keep the backward's dtype where the flat boundary
         # takes them as they come (ZeRO 2 per micro-step, ZeRO 1 at gas 1);
         # an accumulator and stage 0/3's reductions take them in fp32
         loss_and_grads = self._make_loss_and_grads(
-            widen=not (stage2 or (self.zero_flat and gas == 1)))
+            widen=not (stage2 or (self.zero_flat and gas == 1)),
+            scalars=channel is not None)
         step_local = self._make_step_local()
         # (ZeRO-3 needs no special casing here: grads/acc live on local
         # shard shapes — partitioned leaves are already scattered by the
         # gather transpose — and step_local consumes them in place)
 
         def local(params, master, opt_state, ls_state, hypers,
-                  normw, gids, batch_args):
+                  normw, gids, batch_args, *totals):
             if gas == 1:
                 # no accumulator buffer, no scan machinery
-                last_loss, acc = loss_and_grads(
+                last_loss, acc, *counted = loss_and_grads(
                     params, ls_state.cur_scale, batch_args)
                 if stage2:
                     acc = self._scatter_grads_local(
@@ -2590,7 +2655,7 @@ class DeepSpeedTpuEngine:
                     batch_args)
 
                 def body(acc, micro):
-                    loss_out, grads = loss_and_grads(
+                    loss_out, grads, *counted = loss_and_grads(
                         params, ls_state.cur_scale, micro)
                     if stage2:
                         # ZeRO-2: scatter per micro — the accumulator is
@@ -2599,7 +2664,7 @@ class DeepSpeedTpuEngine:
                         grads = self._scatter_grads_local(
                             grads, across_subgroups=False)
                     acc = jax.tree_util.tree_map(jnp.add, acc, grads)
-                    return acc, loss_out
+                    return acc, (loss_out, *counted)
 
                 if stage2:
                     part = self.flat_meta.partition
@@ -2609,15 +2674,37 @@ class DeepSpeedTpuEngine:
                 else:
                     zeros = jax.tree_util.tree_map(
                         lambda p: jnp.zeros(p.shape, jnp.float32), params)
-                acc, losses = jax.lax.scan(body, zeros, mb)
+                acc, (losses, *counted) = jax.lax.scan(body, zeros, mb)
                 last_loss = jax.tree_util.tree_map(lambda l: l[-1], losses)
+                counted = [channel.over_steps(c) for c in counted]
             (params_new, master_new, opt_new, ls_new, overflow,
              total_norm) = step_local(master, opt_state, acc, ls_state,
                                       hypers, normw, gids)
-            return (params_new, master_new, opt_new, ls_new, overflow,
+            self._record_step_gauges()
+            outs = (params_new, master_new, opt_new, ls_new, overflow,
                     total_norm, last_loss)
+            if channel is None:
+                return outs
+            step = channel.over_mesh(
+                counted[0], self._loss_axes(),
+                MODEL_AXIS if self.mp_world_size > 1 else None)
+            return outs + (channel.add(totals[0], step),)
 
         return local
+
+    def _record_step_gauges(self):
+        """Called while the fused step program is being traced, after its
+        forward, backward and boundary: what the module and the boundary
+        wrote while THIS program was traced are the gauges of the program
+        the engine runs (the ``model`` and ``boundary`` groups of the
+        registry, and the denominators of the step scalars' readers)."""
+        counts = getattr(self.module, "step_counts", None)
+        if callable(counts):
+            self._step_gauges["model"] = dict(counts())
+            if self._scalars is not None:
+                self._scalars.gauges = self._step_gauges["model"]
+        if self._boundary_wire:
+            self._step_gauges["boundary"] = dict(self._boundary_wire)
 
     def _build_train_batch(self, batch):
         """ONE jitted XLA program for the full effective batch: ``lax.scan``
@@ -2628,13 +2715,16 @@ class DeepSpeedTpuEngine:
         here needed gas fwd dispatches + an accumulate + a step dispatch)."""
         local = self._make_fused_local()
         master_spec, opt_spec, ls_spec = self._step_specs()
+        # step scalars: the totals since the last read, one more replicated
+        # operand and result (none for a model that declares nothing)
+        totals = (P(),) if self._scalars is not None else ()
         fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._param_specs, master_spec, opt_spec, ls_spec,
                       P(), P(DATA_AXIS), P(DATA_AXIS),
-                      self._checked_batch_specs(batch)),
+                      self._checked_batch_specs(batch)) + totals,
             out_specs=(self._param_specs, master_spec, opt_spec, ls_spec,
-                       P(), P(), P()),
+                       P(), P(), P()) + totals,
             check_vma=False)
         if self._spool is not None:
             # MetricSpool: append this boundary's (loss, grad norm, loss
@@ -2648,10 +2738,11 @@ class DeepSpeedTpuEngine:
             shard_fn = fn
 
             def fn(params, master, opt_state, ls_state, hypers, normw,
-                   gids, batch_args, spool_state):
+                   gids, batch_args, *totals_and_spool):
+                *totals, spool_state = totals_and_spool
                 outs = shard_fn(params, master, opt_state, ls_state,
-                                hypers, normw, gids, batch_args)
-                (_, _, _, _, overflow, total_norm, last_loss) = outs
+                                hypers, normw, gids, batch_args, *totals)
+                overflow, total_norm, last_loss = outs[4:7]
                 new_spool = spool_mod.append(
                     spool_state, last_loss, total_norm,
                     ls_state.cur_scale, overflow)
@@ -2719,7 +2810,8 @@ class DeepSpeedTpuEngine:
             self._maybe_graph_lint(
                 "spool_drain", "spool",
                 lambda: graph_lint.analyze_jaxpr(
-                    jax.make_jaxpr(spool.drain_program())(spool.state),
+                    jax.make_jaxpr(spool.drain_program())(
+                        *spool.drain_args()),
                     subject="spool_drain"))
         # call tuple via the single protocol owner (analysis.train_batch
         # _args appends the spool state when the spool is on)
@@ -2742,6 +2834,10 @@ class DeepSpeedTpuEngine:
             outs = self._train_batch_fn(*args)
             if spool is not None:
                 outs, new_spool = outs[:-1], outs[-1]
+            if self._scalars is not None:
+                # the totals with this step in them: a handle, not read
+                outs, totals = outs[:-1], outs[-1]
+                self._scalars.note_dispatch(totals, 1, gas)
             (self.params, new_master, self.opt_state, self.loss_scale_state,
              overflow, self._last_grad_norm, loss) = outs
             if self.zero_flat:
@@ -2822,18 +2918,21 @@ class DeepSpeedTpuEngine:
 
         def local(params, master, opt_state, ls_state, hypers_k,
                   normw, gids, live, *batch_ks):
+            # step scalars: the totals follow the K batches and thread
+            # through the K steps like the state (none: no channel)
+            batch_ks, totals = batch_ks[:k], batch_ks[k:]
             h_idx = jnp.int32(0)
             overflows, norms, losses, scales = [], [], [], []
 
             def stepped(operands):
-                p, m, o, ls, hy, ba = operands
-                return single(p, m, o, ls, hy, normw, gids, ba)
+                p, m, o, ls, hy, ba, *totals = operands
+                return single(p, m, o, ls, hy, normw, gids, ba, *totals)
 
             def untaken(operands):
                 # never executed (the predicate is runtime-true); exists
                 # only so each real step body is a cond BRANCH — its own
                 # XLA computation — instead of open graph
-                p, m, o, ls, hy, ba = operands
+                p, m, o, ls, *_ = operands
                 shapes = jax.eval_shape(stepped, operands)
                 zeros = jax.tree_util.tree_map(
                     lambda s: jnp.zeros(s.shape, s.dtype), shapes[4:])
@@ -2850,10 +2949,10 @@ class DeepSpeedTpuEngine:
                 # the fused path's pre-dispatch host copy
                 scales.append(jnp.asarray(ls_state.cur_scale, jnp.float32))
                 (params, master, opt_state, ls_state, overflow,
-                 total_norm, last_loss) = jax.lax.cond(
+                 total_norm, last_loss, *totals) = jax.lax.cond(
                     live > 0, stepped, untaken,
                     (params, master, opt_state, ls_state, hypers,
-                     batch_ks[i]))
+                     batch_ks[i], *totals))
                 overflows.append(jnp.asarray(overflow, jnp.bool_))
                 norms.append(total_norm)
                 losses.append(last_loss)
@@ -2864,17 +2963,19 @@ class DeepSpeedTpuEngine:
                 lambda *ls: jnp.stack(ls), *losses)
             return (params, master, opt_state, ls_state,
                     jnp.stack(overflows), norms[-1], losses[-1],
-                    jnp.stack(norms), losses_k, jnp.stack(scales))
+                    jnp.stack(norms), losses_k, jnp.stack(scales),
+                    *totals)
 
         master_spec, opt_spec, ls_spec = self._step_specs()
         batch_spec = self._checked_batch_specs(batch)
+        totals = (P(),) if self._scalars is not None else ()
         shard_fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._param_specs, master_spec, opt_spec, ls_spec,
                       P(), P(DATA_AXIS), P(DATA_AXIS), P())
-                     + tuple(batch_spec for _ in range(k)),
+                     + tuple(batch_spec for _ in range(k)) + totals,
             out_specs=(self._param_specs, master_spec, opt_spec, ls_spec,
-                       P(), P(), P(), P(), P(), P()),
+                       P(), P(), P(), P(), P(), P()) + totals,
             check_vma=False)
         if self._spool is not None:
             # K ring appends per dispatch — pure consumers of the per-step
@@ -2884,11 +2985,13 @@ class DeepSpeedTpuEngine:
             from deepspeed_tpu.observability import spool as spool_mod
 
             def fn(params, master, opt_state, ls_state, hypers_k, normw,
-                   gids, live, batches, spool_state):
+                   gids, live, batches, *totals_and_spool):
+                *totals, spool_state = totals_and_spool
                 outs = shard_fn(params, master, opt_state, ls_state,
-                                hypers_k, normw, gids, live, *batches)
-                (_, _, _, _, overflows, _, _, norms_k, losses_k,
-                 scales_k) = outs
+                                hypers_k, normw, gids, live, *batches,
+                                *totals)
+                overflows, norms_k, losses_k, scales_k = (
+                    outs[4], outs[7], outs[8], outs[9])
                 for i in range(k):
                     loss_i = jax.tree_util.tree_map(lambda l: l[i],
                                                     losses_k)
@@ -2898,9 +3001,10 @@ class DeepSpeedTpuEngine:
                 return outs + (spool_state,)
         else:
             def fn(params, master, opt_state, ls_state, hypers_k, normw,
-                   gids, live, batches):
+                   gids, live, batches, *totals):
                 return shard_fn(params, master, opt_state, ls_state,
-                                hypers_k, normw, gids, live, *batches)
+                                hypers_k, normw, gids, live, *batches,
+                                *totals)
 
         # donation: the same (params, master, opt_state, ls_state)
         # positions as the fused single-step program, same fp32 guard
@@ -3027,7 +3131,8 @@ class DeepSpeedTpuEngine:
             self._maybe_graph_lint(
                 "spool_drain", "spool",
                 lambda: graph_lint.analyze_jaxpr(
-                    jax.make_jaxpr(spool.drain_program())(spool.state),
+                    jax.make_jaxpr(spool.drain_program())(
+                        *spool.drain_args()),
                     subject="spool_drain"))
             if spool.would_straddle(k):
                 # a stray train_batch on this K>1 engine left the ring
@@ -3052,6 +3157,9 @@ class DeepSpeedTpuEngine:
             outs = self._train_many_fn(*args)
             if spool is not None:
                 outs, new_spool = outs[:-1], outs[-1]
+            if self._scalars is not None:
+                outs, totals = outs[:-1], outs[-1]
+                self._scalars.note_dispatch(totals, k, gas * k)
             (self.params, new_master, self.opt_state, self.loss_scale_state,
              overflows, self._last_grad_norm, loss, _norms_k, _losses_k,
              _scales_k) = outs

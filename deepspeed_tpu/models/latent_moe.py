@@ -35,7 +35,9 @@ head and its cross-entropy run in blocks of ``HEAD_BLOCK_ROWS`` positions
 under ``jax.checkpoint`` (``transformer.blocked_cross_entropy``).  Engine
 protocol: ``init_params``, ``partition_specs``, ``batch_specs``,
 ``zero3_min_dims``, ``validate``, ``apply`` (inside ``shard_map`` on local
-shards), ``step_counts``.
+shards), ``step_counts``, ``step_scalars`` (what the expert layers count on
+the device: ``apply`` returns the loss WITH them,
+``observability.scalars.WithScalars``).
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import moe as M
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.observability import scalars as obs_scalars
 from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
@@ -183,7 +186,8 @@ def layer_partition_specs(kind: str) -> dict:
 def layer_apply(kind: str, cfg: LatentMoEConfig, x, p, depth, shared):
     """One layer of ``kind`` on local shards (``transformer.scan_segment``'s
     layer signature; ``depth`` is not read, ``shared`` holds the rotary
-    tables).  Returns ``(x, balance loss)``, 0 from a dense layer."""
+    tables).  Returns ``(x, (balance loss, step scalars))``: 0 and none
+    from a dense layer, ``moe.held_experts``' counts from an expert layer."""
     eps = cfg.norm_eps
     x = x + L.latent_attention(
         L.rms_norm(x, p["norm1_s"], eps), p, rope=shared["rope"],
@@ -191,12 +195,23 @@ def layer_apply(kind: str, cfg: LatentMoEConfig, x, p, depth, shared):
         latent=cfg.latent_rank, eps=eps)
     u = L.rms_norm(x, p["norm2_s"], eps)
     if kind == "dense":
-        return x + T._gated_mlp(u, p), jnp.zeros((), jnp.float32)
-    y, aux = M.dropless_moe_ffn(
+        return x + T._gated_mlp(u, p), (jnp.zeros((), jnp.float32), {})
+    y, aux, counts = M.dropless_moe_ffn(
         u, p, num_experts=cfg.num_experts, top_k=cfg.experts_per_token,
         held=cfg.experts_held, route_scale=cfg.route_scale,
         balance_alpha=cfg.balance_alpha)
-    return x + y, aux
+    return x + y, (aux, counts)
+
+
+def _balance(outs):
+    """One period's balance loss of its layers' ``(balance loss, step
+    scalars)``."""
+    return sum(aux for aux, _ in outs)
+
+
+def _tally(running, outs):
+    """The step scalars so far with one period's layers' in them."""
+    return obs_scalars.combine([running] + [counts for _, counts in outs])
 
 
 @dataclasses.dataclass
@@ -256,6 +271,15 @@ class LatentMoELM:
             "routed_rows_all": self.routed_rows[1],
         }
 
+    def step_scalars(self) -> dict:
+        """The step scalars ``apply`` returns beside its loss, ``{name:
+        size}`` (observability/scalars.py): the expert layers' counts;
+        nothing from a stack without an expert layer."""
+        if "moe" not in self.config.kinds:
+            return {}
+        return {"moe/overflow_passes": 1, "moe/held_pairs": 1,
+                "moe/max_expert_rows": 1}
+
     # ------------------------------------------------------------------ init
     def init_params(self, rng):
         cfg = self.config
@@ -297,7 +321,10 @@ class LatentMoELM:
     def apply(self, params, tokens, labels):
         """tokens, labels: int32 [B, T]; labels < 0 are ignored.  Returns
         the mean per-token LM loss plus the balance loss of every expert
-        layer held (fp32 scalar, local to the DP shard)."""
+        layer held (fp32 scalar, local to the DP shard) and, from a stack
+        with an expert layer, the layers' step scalars with it
+        (``WithScalars``: ``moe/overflow_passes`` and ``moe/held_pairs``
+        summed, ``moe/max_expert_rows`` the largest, over the layers)."""
         cfg = self.config
         pairs = tokens.size * cfg.experts_per_token
         self.routed_rows = (M.prefix_rows(
@@ -309,17 +336,24 @@ class LatentMoELM:
             x = L.vocab_parallel_embedding(tokens, params["wte"])
         shared = {"rope": L.rotary_tables(tokens.shape[1], cfg.rope_dim,
                                           cfg.rope_theta)}
-        carry, balance = (x, jnp.zeros((), jnp.int32)), 0.0
+        # the layers' step scalars ride the scans' carry beside x and the
+        # depth (int32, like the layers' own: no gradient reads them)
+        counts = {name: jnp.zeros((), jnp.int32)
+                  for name in self.step_scalars()}
+        carry, balance = (x, jnp.zeros((), jnp.int32), counts), 0.0
         for (kinds, _), stacked, z3 in zip(cfg.segments, params["blocks"],
                                            z3_blocks):
             carry, aux = T.scan_segment(
                 [functools.partial(layer_apply, kind, cfg) for kind in kinds],
-                carry, stacked, cfg, shared=shared, collect=sum, z3_dims=z3)
+                carry, stacked, cfg, shared=shared, collect=_balance,
+                tally=_tally, z3_dims=z3)
             balance = balance + jnp.sum(aux)
         with S.scope("head"):
             x = L.rms_norm(carry[0], params["normf_s"], cfg.norm_eps)
             ce = T.blocked_cross_entropy(x, params["head"], labels,
                                          HEAD_BLOCK_ROWS)
-            return L.masked_mean_loss(ce, labels >= 0) + balance
+            loss = L.masked_mean_loss(ce, labels >= 0) + balance
+        counts = carry[2]
+        return obs_scalars.WithScalars(loss, counts) if counts else loss
 
     __call__ = apply

@@ -46,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.observability import scalars as obs_scalars
 from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
@@ -71,10 +72,10 @@ class LoopedConfig:
     # The other policies: transformer.remat_wrap.
     remat_policy: str = "full"
     sp_impl: str = "ring"         # the only one this block wires
-    #: also return, beside the loss, each exit's mean cross-entropy and mean
-    #: exit probability as ``2 * loop_passes`` more scalars (gradient
-    #: stopped): the engine's multi-output path reports every one and
-    #: differentiates their sum, which is the loss's gradient
+    #: also return, WITH the loss, each exit's mean cross-entropy and mean
+    #: exit probability (gradient stopped) as the step scalars
+    #: ``loop/exit_ce`` / ``loop/exit_prob`` (observability/scalars.py):
+    #: ``train_batch`` returns the loss alone, ``read_step_scalars()`` them
     report_exits: bool = False
 
     def validate(self, mp_size: int = 1):
@@ -141,6 +142,15 @@ class LoopedLM:
         return {"loop_passes": cfg.loop_passes, "exits": cfg.loop_passes,
                 "layer_applications": cfg.loop_passes * cfg.num_layers}
 
+    def step_scalars(self) -> dict:
+        """The step scalars ``apply`` returns beside its loss, ``{name:
+        size}`` (observability/scalars.py): one value per exit of each, and
+        only with ``report_exits``."""
+        if not self.config.report_exits:
+            return {}
+        n = self.config.loop_passes
+        return {"loop/exit_ce": n, "loop/exit_prob": n}
+
     # ------------------------------------------------------------------ init
     def init_params(self, rng):
         cfg = self.config
@@ -181,8 +191,10 @@ class LoopedLM:
     def apply(self, params, tokens, labels):
         """tokens, labels: int32 [B, T]; labels < 0 are ignored.  Returns
         the mean training loss over the labelled positions (fp32 scalar,
-        local to the DP shard), or with ``report_exits`` the tuple (loss,
-        CE of exit 1..n, mean probability of exit 1..n)."""
+        local to the DP shard); with ``report_exits`` that loss WITH the
+        step scalars ``loop/exit_ce`` and ``loop/exit_prob``, the mean
+        cross-entropy and the mean probability of exit 1..n
+        (``observability.scalars.WithScalars``; no gradient sees them)."""
         cfg = self.config
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         blocks, z3_dims = params["blocks"], z3_deferred.get("blocks")
@@ -221,7 +233,8 @@ class LoopedLM:
             if not cfg.report_exits:
                 return loss
             mean = lambda v: jax.lax.stop_gradient(
-                L.masked_mean_loss(v, mask))
-            return (loss, *(mean(c) for c in ce), *(mean(q) for q in p))
+                jnp.stack([L.masked_mean_loss(x, mask) for x in v]))
+            return obs_scalars.WithScalars(
+                loss, {"loop/exit_ce": mean(ce), "loop/exit_prob": mean(p)})
 
     __call__ = apply

@@ -454,11 +454,19 @@ def held_experts(flat, p, chosen, gates, first, num_experts):
     e_local)`` of ``num_experts`` (``p``'s ``exp_*_w``): the pairs sorted
     (``sort_share``), then ``routed_part`` on the prefix if the ``n_held``
     pairs that landed here fit it, else on all of them — chosen on the
-    device, both exact (``dropless_moe_ffn``)."""
+    device, both exact (``dropless_moe_ffn``).  Returns it with the
+    layer's step scalars (observability/scalars.py), int32, all of them
+    values the choice already needs: ``moe/held_pairs`` = ``n_held``,
+    ``moe/max_expert_rows`` = the largest of ``sizes``,
+    ``moe/overflow_passes`` = 1 where the worst case ran (0 always from a
+    share with no prefix)."""
     e_local = p["exp_gate_w"].shape[0]
     with S.scope("route"):
         order, pos, sizes = sort_share(chosen, first, e_local)
         n_held = jnp.sum(sizes)
+        counts = {"moe/held_pairs": n_held,
+                  "moe/max_expert_rows": jnp.max(sizes),
+                  "moe/overflow_passes": jnp.zeros((), jnp.int32)}
     # the branches' operands: of ``p`` only what they read
     experts = {name: p[name]
                for name in ("exp_gate_w", "exp_up_w", "exp_down_w")}
@@ -466,7 +474,7 @@ def held_experts(flat, p, chosen, gates, first, num_experts):
     pairs = chosen.size
     prefix = prefix_rows(pairs, e_local, num_experts)
     if prefix == pairs:
-        return routed_part(pairs, *operands)
+        return routed_part(pairs, *operands), counts
     # Each branch under ``jax.checkpoint``: what it hands its backward is
     # its operands alone, which the branches share.  Autodiff of a bare
     # ``cond`` makes every intermediate either backward reads an output of
@@ -475,8 +483,10 @@ def held_experts(flat, p, chosen, gates, first, num_experts):
     # needs 16.57 GB of a v5e's 15.75 (PERF.md, PR 34).  An outer policy
     # still finds the names inside (``selective`` keeps ``ffn1``).
     part = jax.checkpoint(routed_part, static_argnums=0)
-    return jax.lax.cond(n_held <= prefix, functools.partial(part, prefix),
-                        functools.partial(part, pairs), *operands)
+    fits = n_held <= prefix
+    counts["moe/overflow_passes"] = 1 - fits.astype(jnp.int32)
+    return jax.lax.cond(fits, functools.partial(part, prefix),
+                        functools.partial(part, pairs), *operands), counts
 
 
 @S.scoped("moe")
@@ -488,7 +498,9 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
     everywhere), the held experts' ``exp_gate_w`` / ``exp_up_w`` [e, h, f]
     and ``exp_down_w`` [e, f, h], and the shared experts as ONE SwiGLU
     ``gate_w`` / ``up_w`` [h, fs / mp], ``down_w`` [fs / mp, h].  Returns
-    ``(y [B, T, h], balance loss)``:
+    ``(y [B, T, h], balance loss, step scalars)`` — the third is
+    ``held_experts``' counts of this pass on this shard, for the caller to
+    return beside its loss (``observability.scalars.WithScalars``):
 
         ``y_t = sum_{e in K_t, first <= e < first + count} g_e SwiGLU_e(x_t)
         + SwiGLU_shared(x_t)``
@@ -529,8 +541,9 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
             scale=route_scale)
         aux = balance_loss(scores.reshape(B, T_len, num_experts),
                            chosen.reshape(B, T_len, top_k), balance_alpha)
-    routed = held_experts(flat, p, chosen, gates, first, num_experts)
+    routed, counts = held_experts(flat, p, chosen, gates, first,
+                                  num_experts)
     if ep > 1:
         with S.scope("route"):
             routed = jax.lax.psum(routed, MODEL_AXIS)
-    return routed.reshape(B, T_len, h) + T._gated_mlp(x, p), aux
+    return routed.reshape(B, T_len, h) + T._gated_mlp(x, p), aux, counts

@@ -342,7 +342,7 @@ def scan_layers(body, carry, stacked_params, cfg, z3_dims=None):
 
 
 def scan_segment(layers, carry, stacked, cfg, *, shared=None, collect=None,
-                 z3_dims=None):
+                 tally=None, z3_dims=None):
     """One segment ``(kinds, repeats)`` of a stack made of segments:
     ``repeats`` stacked copies of a whole period of layers run by ONE
     ``scan_layers`` whose body applies the period's layers in order, each
@@ -356,17 +356,24 @@ def scan_segment(layers, carry, stacked, cfg, *, shared=None, collect=None,
     here); ``shared``: what every layer may read beside its own parameters,
     closed over as loop invariants.  Returns ``(carry, collected)``:
     ``collect(outs)`` of each period's list of second results, stacked
-    ``[repeats, ...]``; None without ``collect``.  Shared by the hybrid and
-    the latent-attention / expert stacks."""
+    ``[repeats, ...]``; None without ``collect``.  With ``tally``, ``carry``
+    holds a third element, a running value no gradient reads (a model's
+    step scalars), and each period leaves ``tally(running, outs)`` there:
+    carried, not stacked — a stacked output costs the loop a write and two
+    small copies an iteration on a TPU, a carried scalar an add.  Shared by
+    the hybrid and the latent-attention / expert stacks."""
     wrapped = [remat_wrap(layer, cfg) for layer in layers]
 
     def period(carry, lp):
-        x, depth = carry
+        x, depth, *running = carry
         outs = []
         for j, layer in enumerate(wrapped):
             x, out = layer(x, lp[f"l{j}"], depth + j, shared)
             outs.append(out)
-        return (x, depth + len(wrapped)), (collect(outs) if collect else None)
+        if tally:
+            running = [tally(running[0], outs)]
+        return ((x, depth + len(wrapped), *running),
+                (collect(outs) if collect else None))
 
     return scan_layers(period, carry, stacked,
                        dataclasses.replace(cfg, remat=False),

@@ -12,7 +12,11 @@ Four pieces, one facade:
   TraceAnnotation spans, and watchdog-triggered hang capture;
   :mod:`~deepspeed_tpu.observability.scopes` names the device side of the
   same capture (``dstpu/*`` ``named_scope``s inside the compiled step and
-  the instruction-name map a trace reader joins them by).
+  the instruction-name map a trace reader joins them by);
+  :mod:`~deepspeed_tpu.observability.scalars` carries what a scope cannot
+  say — counts the model takes on the device from its data (an expert
+  layer's overflow passes) — out of the step beside the loss, into the
+  window events (``scalars``) and the ``model`` counter group.
 * :mod:`~deepspeed_tpu.observability.registry` — MetricRegistry exporter
   fan-out: engine throughput/goodput, resilience counters and
   compile-cache counters all emit through one path to TensorBoard and a
@@ -170,9 +174,12 @@ class Telemetry:
         self.registry.register("observability",
                                detectors.COUNTERS.as_dict)
         # what the model declares its step is made of (a looped model: the
-        # passes, exits and layer applications behind ``dstpu/loop``);
-        # models that declare nothing have no group
-        if callable(getattr(engine.module, "step_counts", None)):
+        # passes, exits and layer applications behind ``dstpu/loop``) and
+        # what it counts on the device (its step scalars: the host-side
+        # numbers since initialize); models that declare nothing have no
+        # group
+        if (callable(getattr(engine.module, "step_counts", None))
+                or engine._scalars is not None):
             self.registry.register("model", self._model_source)
         # the flat ZeRO boundary's gradient wire: how wide the step program
         # that was built sends (16: the backward's bf16/fp16; 32: an fp32
@@ -184,7 +191,8 @@ class Telemetry:
         self.spool: Optional[MetricSpool] = None
         self._anomaly: Optional[detectors.WindowAnomalyDetector] = None
         if self.window >= 1:
-            self.spool = MetricSpool(self.window, self._on_window)
+            self.spool = MetricSpool(self.window, self._on_window,
+                                     scalars=engine._scalars)
             # pin the fresh ring state to the engine mesh (committed,
             # replicated): as plain jnp.zeros it is UNCOMMITTED, and the
             # fused train_batch's first call would hash a different
@@ -264,15 +272,28 @@ class Telemetry:
         engine = self._engine_ref()
         if engine is None:
             return {}
-        counts = dict(engine.module.step_counts())
-        counts["layer_applications_per_step"] = (
-            counts.pop("layer_applications")
-            * engine.gradient_accumulation_steps())
+        # the gauges of the fused step program the engine runs, where one
+        # was traced (engine._record_step_gauges); the module's live ones
+        # — of whatever program was traced last — before that
+        counts = engine._step_gauges.get("model")
+        if counts is None:
+            declare = getattr(engine.module, "step_counts", None)
+            counts = declare() if callable(declare) else {}
+        counts = dict(counts)
+        if "layer_applications" in counts:
+            counts["layer_applications_per_step"] = (
+                counts.pop("layer_applications")
+                * engine.gradient_accumulation_steps())
+        if engine._scalars is not None:
+            counts.update(engine._scalars.counters())
         return counts
 
     def _boundary_source(self) -> dict:
         engine = self._engine_ref()
-        return dict(engine._boundary_wire) if engine is not None else {}
+        if engine is None:
+            return {}
+        return dict(engine._step_gauges.get("boundary")
+                    or engine._boundary_wire)
 
     def _samples_source(self) -> dict:
         engine = self._engine_ref()
@@ -407,6 +428,12 @@ class Telemetry:
                         (sps / self._n_devices)
                         * float(self.flops_per_sample)
                         / (float(self.peak_tflops) * 1e12))
+        if engine is not None and engine._scalars is not None:
+            # the model's step scalars over THIS window (the drain that
+            # delivered it was handed the device totals and folded them
+            # first); the totals since initialize ride ``counters`` as the
+            # ``model`` group
+            event["scalars"] = engine._scalars.take_window()
         event.update(self._capacity_columns())
         # per-host fleet-report columns (schema v2): host-side pre-dispatch
         # time is THE straggler signal — under lockstep SPMD one slow rank

@@ -60,3 +60,13 @@ def read_arrays(*xs):
     if any(hasattr(x, "block_until_ready") for x in xs):
         count_fence()
     return tuple(np.asarray(x) for x in xs)
+
+
+def host_local_view(x):
+    """This process's single-device view of a (replicated) global array —
+    no transfer, the local shard already lives on an addressable device.
+    Identity for host-local arrays (single-process runs, the split-API
+    spool state)."""
+    if hasattr(x, "is_fully_addressable") and not x.is_fully_addressable:
+        return x.addressable_shards[0].data
+    return x

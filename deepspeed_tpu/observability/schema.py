@@ -11,7 +11,8 @@ Six event schemas share one stream (a rank-0 log interleaves them):
 
 * ``dstpu.telemetry.window``  — one line per drained metric window.
   v1 (PR 7) logs still validate; v2 adds the per-host fleet-report
-  columns (``host_ms``, ``data_wait_ms``, ``anomalies``, ``rank``).
+  columns (``host_ms``, ``data_wait_ms``, ``anomalies``, ``rank``); v3
+  adds ``scalars``, the model's step scalars over the window.
 * ``dstpu.telemetry.startup`` — one line per process start (v2): compile
   / time-to-first-step seconds, restore latency, compile-cache counters —
   the cold-start cost as a recorded number instead of the first window's
@@ -54,9 +55,10 @@ from typing import Optional
 
 #: window event-log schema identifier + current version
 SCHEMA_ID = "dstpu.telemetry.window"
-SCHEMA_VERSION = 2
-#: versions the validator accepts for window events (v1 = PR 7 logs)
-ACCEPTED_VERSIONS = (1, 2)
+SCHEMA_VERSION = 3
+#: versions the validator accepts for window events (v1 = PR 7 logs, v2 =
+#: logs from before the step scalars)
+ACCEPTED_VERSIONS = (1, 2, 3)
 
 #: fleet/startup schemas (introduced at v2 — no v1 ever existed)
 FLEET_SCHEMA_ID = "dstpu.telemetry.fleet"
@@ -125,6 +127,11 @@ FIELDS = {
     "data_wait_ms": (_NUM, False, 2),   # mean data-loader wait ms per
                                         # boundary (starvation signal)
     "anomalies": (list, False, 2),      # per-host detector flags
+    # ---- v3 (step scalars, observability/scalars.py) -------------------
+    "scalars": (dict, False, 3),        # {name: number | [numbers]} the
+                                        # model counted on the device over
+                                        # THIS window (null: it declares
+                                        # none); names of scalars.SCALARS
 }
 
 #: fleet event fields (schema ``dstpu.telemetry.fleet`` v2)
@@ -323,7 +330,7 @@ def _validate_fields(event: dict, table: dict, versions) -> Optional[str]:
 
 
 def validate_event(event: dict) -> Optional[str]:
-    """Validate a WINDOW event (v1 or v2); returns None when valid, else a
+    """Validate a WINDOW event (v1 to v3); returns None when valid, else a
     message naming the first problem.  Unknown extra keys are allowed
     (additive schema evolution)."""
     if not isinstance(event, dict):
@@ -339,6 +346,13 @@ def validate_event(event: dict) -> Optional[str]:
     if not (0 <= event["skipped"] <= event["window_steps"]):
         return (f"skipped ({event['skipped']}) outside "
                 f"[0, window_steps={event['window_steps']}]")
+    for name, val in (event.get("scalars") or {}).items():
+        values = val if isinstance(val, list) else [val]
+        if not isinstance(name, str) or not values or not all(
+                isinstance(v, _NUM) and not isinstance(v, bool)
+                for v in values):
+            return (f"scalars[{name!r}] must map str -> number or a list "
+                    f"of numbers, got {val!r}")
     return _validate_counters(event["counters"])
 
 

@@ -19,6 +19,11 @@ avoid (WALLCLOCK §7).  The spool removes it:
 * ``flush()`` is the only synchronous read — a single counted fence
   (observability/fences.py) used at run end and on a preemption drain so
   the final partial window is never dropped.
+* a model's step scalars (observability/scalars.py) ride the same drain:
+  the one batched callback is handed the device-side totals since the last
+  read beside ``buf`` and ``pos``, and the next step starts from zeros — a
+  window drain IS a read of the scalars, with no fence; ``flush()`` reads
+  them in its one fence.
 
 Trajectory neutrality: the append consumes values the step program
 already computes (loss / norm / scale / overflow are existing outputs);
@@ -73,16 +78,6 @@ def append(state, loss_out, grad_norm, loss_scale, overflow):
             "pos": state["pos"] + 1}
 
 
-def _host_local_view(x):
-    """This process's single-device view of a (replicated) global array —
-    no transfer, the local shard already lives on an addressable device.
-    Identity for host-local arrays (single-process runs, the split-API
-    spool state)."""
-    if hasattr(x, "is_fully_addressable") and not x.is_fully_addressable:
-        return x.addressable_shards[0].data
-    return x
-
-
 class MetricSpool:
     """Host-side spool driver: owns the device state, the append/drain
     programs and the window bookkeeping.
@@ -94,11 +89,16 @@ class MetricSpool:
     """
 
     def __init__(self, window: int,
-                 on_window: Callable[[np.ndarray, int], None]):
+                 on_window: Callable[[np.ndarray, int], None],
+                 scalars=None):
         if window < 1:
             raise ValueError(f"spool window must be >= 1, got {window}")
         self.window = int(window)
         self._on_window = on_window
+        #: the engine's step-scalar channel (``scalars.Channel``) or None:
+        #: every drain hands its totals to the callback and folds them
+        #: into the host-side numbers BEFORE ``on_window`` runs
+        self.scalars = scalars
         self.state = init_state(window)
         self._appended = 0       # host mirror of state["pos"]
         self._drained = 0        # appends already handed to on_window
@@ -161,9 +161,9 @@ class MetricSpool:
         import jax
         from jax.experimental import io_callback
 
-        def _spool_drain_callback(buf, pos):
+        def _spool_drain_callback(buf, pos, *handed):
             try:
-                self._deliver(np.asarray(buf), int(pos))
+                self._deliver(np.asarray(buf), int(pos), handed)
             except Exception as e:  # pragma: no cover - defensive
                 logger.warning("telemetry drain failed: %s", e)
 
@@ -174,12 +174,28 @@ class MetricSpool:
         _spool_drain_callback._dstpu_spool_drain = True
         self.drain_callback = _spool_drain_callback
 
-        def drain(state):
+        def drain(state, *handed):
             io_callback(_spool_drain_callback, None,
-                        state["buf"], state["pos"], ordered=True)
+                        state["buf"], state["pos"], *handed, ordered=True)
             return state["pos"]
 
         return jax.jit(drain)
+
+    def drain_args(self, hand_over: bool = False):
+        """The drain program's call tuple over THIS PROCESS's view of the
+        state (:func:`fences.host_local_view`): the ring and, with a
+        step-scalar channel, ``(totals, steps, micro_steps)`` — taken from
+        the channel with ``hand_over`` (a real drain: the next step then
+        starts from zeros), only looked at without (a lint's trace)."""
+        args = ({k: fences.host_local_view(v)
+                 for k, v in self.state.items()},)
+        if self.scalars is None:
+            return args
+        totals, steps, micro = (self.scalars.hand_over() if hand_over
+                                else (self.scalars.device, 0, 0))
+        return args + (
+            {k: fences.host_local_view(v) for k, v in totals.items()},
+            np.int32(steps), np.int32(micro))
 
     def drain_program(self):
         """The jitted drain program (built lazily; exposed so the engine
@@ -194,21 +210,22 @@ class MetricSpool:
         has produced the window's buffer — the host does NOT wait.
 
         The drain runs over THIS PROCESS's view of the state
-        (:func:`_host_local_view`): a multi-host fused step program
+        (:func:`fences.host_local_view`): a multi-host fused step program
         returns the spool state globally replicated, and jitting the
         drain over a global array runs its ``io_callback`` on ONE process
         only — every other host would never deliver a window (found
         standing up fleet aggregation, PR 9; pinned by the
         ``fleet_straggler_watchdog`` distributed leg)."""
-        self.drain_program()(
-            {k: _host_local_view(v) for k, v in self.state.items()})
+        self.drain_program()(*self.drain_args(hand_over=True))
 
-    def _deliver(self, buf: np.ndarray, pos: int) -> None:
+    def _deliver(self, buf: np.ndarray, pos: int, handed=()) -> None:
         # delivery happens UNDER the lock: the counter update and the
         # on_window call are atomic, so windows reach the sinks exactly
         # once and in append order even when a flush and a late callback
         # race (no re-entry risk — sinks never call back into the spool)
         with self._lock:
+            if handed:
+                self.scalars.fold(*handed)
             n = pos - self._drained
             if n <= 0:
                 return
@@ -241,7 +258,8 @@ class MetricSpool:
             jax.effects_barrier()
         except Exception as e:  # pragma: no cover - defensive
             logger.warning("telemetry flush: effects barrier failed: %s", e)
-        buf, pos = fences.read_arrays(
-            _host_local_view(self.state["buf"]),
-            _host_local_view(self.state["pos"]))
-        self._deliver(buf, int(pos))
+        state, *handed = self.drain_args(hand_over=True)
+        leaves, treedef = jax.tree_util.tree_flatten(handed)
+        buf, pos, *leaves = fences.read_arrays(state["buf"], state["pos"],
+                                               *leaves)
+        self._deliver(buf, int(pos), treedef.unflatten(leaves))

@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 from benchmark import cell as cells
 from deepspeed_tpu.models import LatentMoEConfig, LatentMoELM
 from deepspeed_tpu.models import moe as M
+from deepspeed_tpu.observability import scalars
 from deepspeed_tpu.parallel.topology import make_mesh
 
 SEQ = 128
@@ -57,9 +58,14 @@ def setting(family, held, seed=0):
     return fam, config, model, params, batch
 
 
+def loss_of(model):
+    """``apply``'s loss alone (a stack with an expert layer returns it with
+    its step scalars, ``observability.scalars.WithScalars``)."""
+    return lambda p, t, l: scalars.split(model.apply(p, t, l))[0]
+
+
 def value_and_grads(model, params, batch):
-    return on_one_device(jax.value_and_grad(
-        lambda p, t, l: model.apply(p, t, l)), params, *batch)
+    return on_one_device(jax.value_and_grad(loss_of(model)), params, *batch)
 
 
 def assert_grads_close(got, want, rtol, atol_share):
@@ -152,7 +158,7 @@ def test_selective_saves_the_grouped_matmuls_and_the_latent_projections(
             model.config, remat_policy=policy))
         mesh = make_mesh(devices=jax.devices()[:1])
         text = str(jax.make_jaxpr(jax.shard_map(
-            jax.grad(lambda p, t, l: m.apply(p, t, l)), mesh=mesh,
+            jax.grad(loss_of(m)), mesh=mesh,
             in_specs=(P(),) * 3, out_specs=P(), check_vma=False))(
                 params, *batch))
         return text.count(primitive + "[")
@@ -176,7 +182,7 @@ def test_the_step_holds_no_host_callback(family):
     _, _, model, params, batch = setting(family, (4, 4))
     mesh = make_mesh(devices=jax.devices()[:1])
     text = str(jax.make_jaxpr(jax.shard_map(
-        jax.grad(lambda p, t, l: model.apply(p, t, l)), mesh=mesh,
+        jax.grad(loss_of(model)), mesh=mesh,
         in_specs=(P(),) * 3, out_specs=P(), check_vma=False))(
             params, *batch))
     assert " cond[" in text
@@ -223,6 +229,12 @@ def test_step_counts_and_the_published_sizes():
     counts = cell.step_counts()
     assert (counts["routed_rows_prefix"], counts["routed_rows_all"]) == (
         24576, 98304)
+    # what the expert layers count on the device, and a dense stack nothing
+    assert cell.step_scalars() == {"moe/overflow_passes": 1,
+                                   "moe/held_pairs": 1,
+                                   "moe/max_expert_rows": 1}
+    assert LatentMoELM(LatentMoEConfig(
+        segments=((("dense",), 2),))).step_scalars() == {}
     shapes = jax.eval_shape(
         LatentMoELM.from_size("tiny", experts_held=(4, 4)).init_params,
         jax.random.PRNGKey(0))

@@ -19,7 +19,7 @@ from deepspeed_tpu.models import LoopedConfig, LoopedLM
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models import looped
 from deepspeed_tpu.models import transformer as T
-from deepspeed_tpu.observability import scopes
+from deepspeed_tpu.observability import scalars, scopes
 from deepspeed_tpu.ops import pallas_attention as pattn
 from deepspeed_tpu.parallel.topology import make_mesh
 
@@ -171,9 +171,17 @@ def test_beta_zero_removes_the_entropy_term(setting):
     none = float(on_one_device(tiny(exit_entropy_weight=0.0).apply, params,
                                *batch))
     reported = on_one_device(tiny(report_exits=True).apply, params, *batch)
-    assert len(reported) == 9
-    ce, p = np.asarray(reported[1:5]), np.asarray(reported[5:])
-    assert float(reported[0]) == pytest.approx(full, rel=1e-6)
+    # the loss WITH the exits as step scalars (observability/scalars.py),
+    # one value per pass of each, and not a tuple of nine "losses"
+    assert isinstance(reported, scalars.WithScalars)
+    assert sorted(reported.scalars) == ["loop/exit_ce", "loop/exit_prob"]
+    ce = np.asarray(reported.scalars["loop/exit_ce"])
+    p = np.asarray(reported.scalars["loop/exit_prob"])
+    assert ce.shape == p.shape == (4,)
+    assert tiny(report_exits=True).step_scalars() == {
+        "loop/exit_ce": 4, "loop/exit_prob": 4}
+    assert tiny().step_scalars() == {}
+    assert float(reported.loss) == pytest.approx(full, rel=1e-6)
     assert p.sum() == pytest.approx(1.0, abs=1e-5)
     assert (ce > 0).all()
     # the entropy of the exit distribution only lowers the loss, by at most
@@ -406,26 +414,60 @@ def test_other_models_have_no_model_group():
 
 
 def test_report_exits_rides_the_fused_step():
-    """The per-exit cross-entropies and the mean exit distribution come back
-    from the one fused program as more outputs of the model (the engine's
-    multi-output path), and change no gradient."""
+    """The per-exit cross-entropies and the mean exit distribution leave
+    the one fused program as step scalars (``read_step_scalars()``), not as
+    more losses: ``train_batch`` returns the loss alone, bit for bit the
+    plain model's, and no gradient changes."""
     batch = lm_batch(2)
     runs = {}
     for report in (False, True):
         engine, _, _, _ = deepspeed_tpu.initialize(
             model=tiny(report_exits=report), config=engine_config(2),
             mesh=make_mesh(devices=jax.devices()[:1]))
-        out = engine.train_batch(batch)
+        out = [engine.train_batch(batch) for _ in range(2)]
         runs[report] = (out, jax.tree_util.tree_map(np.asarray,
-                                                    engine.params))
+                                                    engine.params), engine)
     plain, reported = runs[False][0], runs[True][0]
-    assert len(reported) == 9
-    assert float(reported[0]) == pytest.approx(float(plain), rel=1e-6)
-    assert sum(float(p) for p in reported[5:]) == pytest.approx(1.0,
-                                                                abs=1e-3)
+    for a, b in zip(plain, reported):
+        assert np.shape(b) == ()                  # one scalar: the loss
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     for a, b in zip(jax.tree_util.tree_leaves(runs[False][1]),
                     jax.tree_util.tree_leaves(runs[True][1])):
         np.testing.assert_array_equal(a, b)
+    assert runs[False][2].read_step_scalars() is None
+    read = runs[True][2].read_step_scalars()
+    assert (read["steps"], read["micro_steps"]) == (2, 2)
+    ce, p = (np.asarray(read["values"][name]) / read["micro_steps"]
+             for name in ("loop/exit_ce", "loop/exit_prob"))
+    # sums over the two micro-steps: the means are a division at read time
+    assert p.shape == (4,) and p.sum() == pytest.approx(1.0, abs=1e-3)
+    assert ce.shape == (4,) and (ce > 0).all()
+    # the registry's ``model`` group flattens a vector entry
+    group = runs[True][2].telemetry.registry.collect()["model"]
+    assert group["loop/exit_prob.3"] == read["values"]["loop/exit_prob"][3]
+    assert group["loop_passes"] == 4 and group["scalar_steps"] == 2
+
+
+def test_report_exits_leaves_the_spools_loss_column_to_the_loss():
+    """With the metric spool on, the window event's ``loss`` is the loss
+    (before PR 35 the exits' eight values were summed into it) and the
+    exits ride ``scalars``, a list of one value per pass each."""
+    events = []
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=tiny(report_exits=True), mesh=make_mesh(
+            devices=jax.devices()[:1]),
+        config=engine_config(2, observability={"report_window": 2}))
+    engine.telemetry.registry.add_sink(type("Sink", (), {
+        "emit": lambda self, event, sample_count=None: events.append(event),
+        "close": lambda self: None})())
+    losses = [float(engine.train_batch(lm_batch(2))) for _ in range(2)]
+    engine.flush_telemetry()
+    window, = [e for e in events if "window_steps" in e]
+    assert window["loss"] == pytest.approx(losses[-1], rel=1e-6)
+    assert window["loss_mean"] == pytest.approx(np.mean(losses), rel=1e-6)
+    prob = np.asarray(window["scalars"]["loop/exit_prob"]) / 2
+    assert prob.shape == (4,) and prob.sum() == pytest.approx(1.0, abs=1e-3)
+    assert len(window["scalars"]["loop/exit_ce"]) == 4
 
 
 @pytest.mark.parametrize("layout", ["tp2", "sp2", "dp2-zero1", "dp2-zero3"])

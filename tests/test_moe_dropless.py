@@ -57,7 +57,15 @@ def on_one_device(fn, *args):
 
 
 def ffn(x, p, held):
-    return M.dropless_moe_ffn(x, p, held=held, **ROUTING)
+    """``(y, balance loss)`` of the layer (its step scalars: ``counted``)."""
+    return M.dropless_moe_ffn(x, p, held=held, **ROUTING)[:2]
+
+
+def counted(x, p, held):
+    """The layer's step scalars as numpy numbers."""
+    return {name: int(v) for name, v in on_one_device(
+        lambda x, p: M.dropless_moe_ffn(x, p, held=held, **ROUTING)[2],
+        x, p).items()}
 
 
 def layer(x, p, held):
@@ -242,7 +250,7 @@ def test_expert_parallel_shards_add_up_to_the_one_device_layer():
              "up_w": col, "down_w": row}
     with jax.default_matmul_precision("highest"):
         got, aux = jax.jit(jax.shard_map(
-            lambda x, p: M.dropless_moe_ffn(x, p, held=held, **ROUTING),
+            lambda x, p: M.dropless_moe_ffn(x, p, held=held, **ROUTING)[:2],
             mesh=mesh, in_specs=(P(), specs), out_specs=(P(), P()),
             check_vma=False))(x, p)
     same(got, want)
@@ -296,7 +304,7 @@ def test_either_branch_is_the_routed_part_on_all_rows(n_held):
     weight = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
 
     def branched(x, p, gates):
-        return M.held_experts(x, p, chosen, gates, 4, E)
+        return M.held_experts(x, p, chosen, gates, 4, E)[0]
 
     assert int(jnp.sum(M.sort_share(chosen, 4, 4)[2])) == n_held
 
@@ -329,6 +337,44 @@ def test_either_branch_is_the_routed_part_on_all_rows(n_held):
             atol=tol * float(jnp.max(jnp.abs(b))), err_msg=name)
 
 
+@pytest.mark.parametrize("n_held", [60, 256, 257, 384])
+def test_the_layer_counts_what_it_decided(n_held):
+    """The step scalars of ``held_experts`` beside its output: the pairs
+    that landed, the busiest held expert's rows (a numpy count from
+    ``chosen``), and 1 overflow pass exactly where the pairs held do not
+    fit the 256-row prefix."""
+    chosen = hand_chosen(n_held)
+    x = tokens(rows=1, seq=128).reshape(-1, H)
+    gates = jnp.full(chosen.shape, 1.0 / K)
+    _, counts = jax.jit(lambda x, p: M.held_experts(
+        x, p, chosen, gates, 4, E))(x, weights(held=(4, 4)))
+    per_expert = np.bincount(np.asarray(chosen).reshape(-1), minlength=E)
+    assert {name: int(v) for name, v in counts.items()} == {
+        "moe/held_pairs": n_held,
+        "moe/max_expert_rows": int(per_expert[4:8].max()),
+        "moe/overflow_passes": int(n_held > 256)}
+    assert all(v.dtype == jnp.int32 for v in counts.values())
+
+
+def test_the_whole_layer_and_a_biased_share_count_too():
+    """A layer holding every expert has no branch: it counts every pair and
+    never an overflow.  The share (4, 4) under a bias that sends every
+    token to experts 4, 5, 6 counts all 384 pairs, 128 rows on the busiest
+    expert and one overflow pass; with the bias at zero, none."""
+    x = tokens(rows=2, seq=64)
+    whole = counted(x, weights(), (0, E))
+    assert whole["moe/held_pairs"] == 2 * 64 * K
+    assert whole["moe/overflow_passes"] == 0
+    p = weights(held=(4, 4))
+    unbiased = counted(x, p, (4, 4))
+    assert unbiased["moe/overflow_passes"] == 0
+    assert 0 < unbiased["moe/held_pairs"] <= 256
+    p["router_b"] = jnp.zeros((E,)).at[jnp.array([4, 5, 6])].set(10.0)
+    assert counted(x, p, (4, 4)) == {
+        "moe/held_pairs": 384, "moe/max_expert_rows": 128,
+        "moe/overflow_passes": 1}
+
+
 def test_the_whole_layer_has_no_branch_and_a_share_has_one():
     """A layer that holds every expert has no prefix under all its rows:
     its program is ``routed_part`` on all rows, the one there was before
@@ -344,6 +390,9 @@ def test_the_whole_layer_has_no_branch_and_a_share_has_one():
                              chosen.reshape(*x.shape[:2], K), ALPHA)
         order, pos, sizes = M.sort_share(chosen, *held)
         n_held = jnp.sum(sizes)
+        # (since PR 35 also the layer's step scalars, made here and
+        # dropped by ``ffn``: the busiest expert, a constant 0 overflows)
+        jnp.max(sizes), jnp.zeros((), jnp.int32)
         rows = M.dispatch(flat, order, pos, n_held)
         rows = M.grouped_swiglu(rows, p, sizes, n_held)
         routed = M.combine(rows, gates, order, pos, n_held)

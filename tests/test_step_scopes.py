@@ -344,7 +344,19 @@ def test_a_latent_attention_expert_step_holds_its_four_scopes(policy):
     assert {"dstpu/mla", "dstpu/route", "dstpu/experts"} <= replayed
     assert not {"dstpu/ssm", "dstpu/loop", "dstpu/exit"} & {
         s for s, _ in phases}
-    assert engine._telemetry.registry.collect()["model"] == {
+    group = engine._telemetry.registry.collect()["model"]
+    # the step scalars beside the gauges: host-side numbers, so nothing of
+    # the one step dispatched until somebody reads (no fence on this path)
+    counted = {k: group.pop(k) for k in list(group)
+               if k.startswith(("moe/", "scalar_"))}
+    assert counted == {"scalar_steps": 0, "scalar_micro_steps": 0,
+                       "moe/overflow_passes": 0.0, "moe/held_pairs": 0.0,
+                       "moe/max_expert_rows": 0.0}
+    read = engine.read_step_scalars()
+    assert read["steps"] == 1 and read["values"]["moe/overflow_passes"] == 0
+    # about a quarter of 2 layers x 384 pairs landed on 4 of 16 experts
+    assert 100 < read["values"]["moe/held_pairs"] < 2 * 256
+    assert group == {
         "layers_dense": 1, "layers_moe": 2, "experts_total": 16,
         "experts_held": 4, "experts_per_token": 3, "latent_rank": 32,
         "qk_head_dim": 32, "v_head_dim": 16,

@@ -104,7 +104,7 @@ def test_both_branches_of_the_expert_layer_hold_the_kernels(one_chip,
          "exp_down_w": S(e, f, h)}
 
     def loss(flat, p, chosen, gates):
-        out = M.held_experts(flat, p, chosen, gates, 0, E)
+        out = M.held_experts(flat, p, chosen, gates, 0, E)[0]
         return jnp.sum(out.astype(jnp.float32))
 
     text = compiled_text(
