@@ -15,8 +15,12 @@ ends the process non-zero — no leg sits inside a catch):
    ``train_batch`` compile and three steps.
 2. The same model at seq 512 (micro-batch 6, 80 masked positions): the
    streaming Pallas kernel against ``xla_attention`` (forward, fused and
-   split backward) at the BERT and a GPT-2 shape, the attention plan, the
-   Pallas custom call in the lowered step, then steps.
+   split backward) at the BERT and a GPT-2 shape, the backward as
+   ``stream_bwd_plan`` sizes it against the split at the two T 8,192
+   shapes (where the fused call asks Mosaic for more scoped VMEM than its
+   default: a libtpu that refuses the limit fails here, not in a cell),
+   the attention plan, the Pallas custom call in the lowered step, then
+   steps.
 3. With four or more devices: the leg-1 model under ZeRO-1 on the default
    ``make_mesh()`` — one process, every chip — checked against a one-chip
    run of the same global batch and seed.  Both sides use Adam: the engine
@@ -34,6 +38,7 @@ The last line of stdout is one JSON object:
 
 import argparse
 import collections
+import contextlib
 import gc
 import importlib.metadata
 import json
@@ -51,9 +56,19 @@ SEED = 0
 # shapes; TINY exists only for --rehearse-cpu.
 FULL = dict(size="large", micro128=24, micro512=6, gas=4, steps=3,
             parity=[((6, 512, 16, 64), False),     # BERT-large phase 2
-                    ((2, 1024, 12, 64), True)])    # GPT-2 small widths
+                    ((2, 1024, 12, 64), True)],    # GPT-2 small widths
+            # (B, T, query / key / value heads, d, dv, window), causal:
+            # the latent core (2 x 8192, 16 heads of 192 / 128) and the
+            # hybrid stack's full and windowed calls (40 / 20 / 10 heads of
+            # 64 / 128) — too long for an XLA reference, so fused against
+            # split
+            backward=[(2, 8192, (16, 16, 16), 192, 128, None),
+                      (1, 8192, (40, 20, 10), 64, 128, None),
+                      (1, 8192, (40, 20, 10), 64, 128, 512)])
 TINY = dict(size="tiny", micro128=4, micro512=2, gas=4, steps=3,
-            parity=[((1, 512, 2, 64), False), ((1, 512, 2, 64), True)])
+            parity=[((1, 512, 2, 64), False), ((1, 512, 2, 64), True)],
+            backward=[(1, 1024, (2, 2, 2), 192, 128, None),
+                      (1, 1024, (4, 2, 1), 64, 128, 512)])
 
 # Kernel-vs-XLA tolerance, as max|kernel - xla| / max|xla| per tensor.
 # Inputs and outputs are bf16 (8 significand bits: one ulp is 2^-8 = 0.4% of
@@ -240,6 +255,16 @@ def leg1_seq128(sz, device):
             "compile_cache_dir": engine.compile_cache_dir}
 
 
+@contextlib.contextmanager
+def stream_bwd_mode(mode):
+    """DSTPU_STREAM_BWD for the length of one traced call."""
+    os.environ["DSTPU_STREAM_BWD"] = mode
+    try:
+        yield
+    finally:
+        del os.environ["DSTPU_STREAM_BWD"]
+
+
 def kernel_parity(shape, causal, interpret):
     """Streaming kernel vs ``xla_attention``: forward, and the backward
     under both DSTPU_STREAM_BWD modes.  Returns the relative errors."""
@@ -266,12 +291,9 @@ def kernel_parity(shape, causal, interpret):
     want = run(lambda q, k, v: pattn.xla_attention(q, k, v, mask, causal)[0])
     errs = {}
     for mode in ("fused", "split"):
-        os.environ["DSTPU_STREAM_BWD"] = mode
-        try:
+        with stream_bwd_mode(mode):
             got = run(lambda q, k, v: pattn.stream_attention(
                 q, k, v, mask, causal, interpret))
-        finally:
-            del os.environ["DSTPU_STREAM_BWD"]
         for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
             if not np.all(np.isfinite(a)):
                 raise RuntimeError(f"stream kernel {name} ({mode} backward) "
@@ -287,6 +309,52 @@ def kernel_parity(shape, causal, interpret):
     return errs
 
 
+def backward_agreement(B, T, heads, d, dv, window, interpret):
+    """The streaming backward as ``stream_bwd_plan`` sizes it (past
+    Mosaic's default: one fused call under the ``vmem_limit_bytes`` it
+    asks for) against the two-kernel split, causal, bf16.  Both accumulate
+    dQ over kv tiles and dK/dV over query tiles in the same order in fp32:
+    equal to the bit on a v5e (libtpu 0.0.34).  Returns the plan and the
+    relative differences."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import pallas_attention as pattn
+
+    n, n_k, n_v = heads
+    rng = np.random.default_rng(SEED)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(B, T, h, width)),
+                              jnp.bfloat16)
+                  for h, width in ((n, d), (n_k, d), (n_v, dv), (n, dv)))
+    mask = jnp.ones((B, T), jnp.float32)
+
+    def grads(q, k, v):
+        _, pull = jax.vjp(lambda q, k, v: pattn.stream_attention(
+            q, k, v, mask, True, interpret, window), q, k, v)
+        return pull(w)
+
+    got = {}
+    for mode in ("auto", "split"):
+        with stream_bwd_mode(mode):
+            got[mode] = [np.asarray(x, np.float32)
+                         for x in jax.jit(grads)(q, k, v)]
+    kind, limit = pattn.stream_bwd_plan(
+        pattn._stream_gb(B * n), T, d, 2, pattn._kernel_vmem_cap())
+    errs = {"plan": kind, "vmem_limit_mib": limit and limit >> 20}
+    for name, a, b in zip(("dq", "dk", "dv"), got["auto"], got["split"]):
+        if not np.all(np.isfinite(a)):
+            raise RuntimeError(f"stream kernel {name} ({kind} backward) is "
+                               f"not finite at T {T}, heads {heads}")
+        err = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        errs[name] = round(err, 5)
+        if err > PARITY_TOL_BWD:
+            raise RuntimeError(
+                f"stream kernel {name}: the {kind} backward differs from "
+                f"the split by {err:.4f} > {PARITY_TOL_BWD} at T {T}, "
+                f"heads {heads}, window {window}")
+    return errs
+
+
 def leg2_seq512(sz, device, on_tpu):
     from deepspeed_tpu import analysis
     from deepspeed_tpu.models import layers
@@ -297,6 +365,10 @@ def leg2_seq512(sz, device, on_tpu):
         parity[f"{shape} causal={causal}"] = errs
         log(f"  stream kernel vs xla_attention {shape} causal={causal}: "
             f"{errs}")
+    for case in sz["backward"]:
+        errs = backward_agreement(*case, interpret=not on_tpu)
+        parity[f"backward {case}"] = errs
+        log(f"  stream backward as planned vs split {case}: {errs}")
 
     plan = layers.attention_plan(512, 16, 64, False)
     log(f"  attention_plan(512, 16, 64, causal=False) = {plan}")

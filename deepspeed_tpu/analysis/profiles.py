@@ -56,6 +56,12 @@ class BackendProfile:
     #: the conservative defaults in ``layers`` apply
     stream_attn_min_causal: Optional[Tuple[int, int]] = None
     stream_attn_min_noncausal: Optional[Tuple[int, int]] = None
+    #: the most scoped VMEM one Pallas kernel may ask Mosaic for, MiB
+    #: (``vmem_limit_bytes``; ``ops/pallas_attention.py stream_bwd_plan``
+    #: is the one asker).  A share of the core's physical VMEM that leaves
+    #: XLA's own fusions room; None = never tried on this generation, a
+    #: kernel stays inside the compiler's default (16 MiB)
+    kernel_vmem_mib: Optional[int] = None
     #: XLA-CPU lowering quirk: sub-fp32 (fp16/bf16) dot operands are
     #: materialized as fp32 copies because the host has no native
     #: half-precision GEMM.  The memory model must count those copies on
@@ -99,6 +105,11 @@ class BackendProfile:
 #: member (v4: 3 links x ~100 GB/s each is the all-links aggregate; the
 #: per-axis number below is one link pair).  CPU: the tier-1 rig — HBM is
 #: a host-RAM allowance per virtual device, "ICI" is shared memcpy.
+#: v5e: 128 MiB of VMEM a core.  Mosaic compiled the streaming backward
+#: under limits up to all 128 (AOT, libtpu 0.0.34); a kernel may ask for
+#: three quarters (PERF.md §6, PR 36, has what the chip showed)
+_V5E_KERNEL_VMEM_MIB = 96
+
 PROFILES: Dict[str, BackendProfile] = {
     "v4-8": BackendProfile(
         name="v4-8", hbm_gib=30.75, ici_gibps=90.0, dcn_gibps=6.25,
@@ -110,7 +121,8 @@ PROFILES: Dict[str, BackendProfile] = {
         # BENCH_r04/r05 sweeps; not re-measured on the current code).
         # fwd == bwd until a direction-split sweep lands
         stream_attn_min_causal=(512, 512),
-        stream_attn_min_noncausal=(512, 512)),
+        stream_attn_min_noncausal=(512, 512),
+        kernel_vmem_mib=_V5E_KERNEL_VMEM_MIB),
     "v5p-8": BackendProfile(
         name="v5p-8", hbm_gib=93.75, ici_gibps=150.0, dcn_gibps=6.25,
         peak_bf16_tflops=459.0),
@@ -118,6 +130,10 @@ PROFILES: Dict[str, BackendProfile] = {
         name="cpu-8", hbm_gib=4.0, ici_gibps=10.0, dcn_gibps=10.0,
         peak_bf16_tflops=1.0, lowp_dot_f32_copies=True,
         persistent_cache_donation_unsafe=True,
+        # the rig of the v5e: a kernel runs here interpreted (the limit
+        # means nothing) or is compiled ahead for a described v5e, so what
+        # is traced here is the program that chip gets
+        kernel_vmem_mib=_V5E_KERNEL_VMEM_MIB,
         # host == device: no PCIe hop, no device round trip.
         # CALIBRATED from this rig's bench_dispatch.json measured
         # columns (dispatch 3.657 µs, per-leaf 1.835 µs, fence 0.071 µs,

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -237,23 +238,32 @@ fused_attention.defvjp(_fused_fwd, _fused_bwd)
 # dK and dV together — the score recompute (QK^T, exp, dP) runs once per
 # tile pair instead of once in a dK/dV kernel and again in a dQ kernel,
 # and q/k/v/do tiles are DMA'd once instead of twice.  dQ accumulates in a
-# full-sequence fp32 VMEM scratch; ``_fused_bwd_fits`` decides from the
-# shape whether that fits Mosaic's scoped VMEM — shapes that do not take
-# the classic two-pass split (DSTPU_STREAM_BWD=fused|split pins either).
+# full-sequence fp32 VMEM scratch; ``stream_bwd_plan`` sizes that from the
+# shape: inside Mosaic's default scoped VMEM the call asks for nothing,
+# past it the call asks Mosaic for what it needs (``vmem_limit_bytes``) up
+# to the chip generation's cap (``analysis/profiles.py kernel_vmem_mib``),
+# and only past the cap does the backward take the classic two-pass split
+# (DSTPU_STREAM_BWD=fused|split pins either).
 # delta = rowsum(dO ∘ O) is precomputed on the XLA side either way.
 # Layout: [G, T, d] with G = batch * heads folded on the XLA side.
 
 STREAM_TILE = 512      # preferred tile rows per program
 STREAM_TILE_MIN = 256  # fallback when T is not a multiple of 512
-#: Mosaic's scoped-VMEM limit per kernel on v5e (libtpu 0.0.34 names it in
-#: its RESOURCE_EXHAUSTED message)
-VMEM_SCOPED_LIMIT = 16 * 1024 * 1024
+_MIB = 1024 * 1024
+#: Mosaic's DEFAULT scoped-VMEM limit per kernel on v5e (libtpu 0.0.34
+#: names it in its RESOURCE_EXHAUSTED message) — what a call gets that asks
+#: for nothing, not the chip's VMEM (128 MiB)
+VMEM_SCOPED_LIMIT = 16 * _MIB
 #: VMEM the fused backward needs BESIDE its dQ-resident buffers (the
 #: double-buffered q/k/v/do/dk/dv tile blocks, the dK/dV scratch, matmul
-#: temporaries), by input itemsize.  Upper bounds on what Mosaic reported
-#: when compiling for v5e at tile 512, gb 2: 4.0 MiB for bf16 at d=64 and
-#: d=128, 7.0 MiB for fp32 at d=64, 11.7 MiB for fp32 at d=128.
-_FUSED_BWD_WORKING_SET = {2: 8 * 1024 * 1024, 4: 12 * 1024 * 1024}
+#: temporaries) at a key head of up to 128 lanes, by input itemsize; half
+#: of it (the q, k and dK blocks, the dQ tile) grows with the head's lane
+#: tiles.  Upper bounds on the least ``vmem_limit_bytes`` under which
+#: Mosaic compiled the call for a v5e, less the resident buffers, at tile
+#: 512, gb 2, G 32 (libtpu 0.0.34; PERF.md §6, PR 36, has the table): bf16
+#: 7.5 MiB at d=64 and d=128, 10.9 MiB at d=192 (two lane tiles: bound
+#: 12); fp32 7.8 MiB at d=64, 11.8 MiB at d=128, 16.0 MiB at d=192 (18).
+_FUSED_BWD_WORKING_SET = {2: 8 * _MIB, 4: 12 * _MIB}
 
 
 def _stream_tile(T: int) -> int:
@@ -671,20 +681,58 @@ def _stream_bwd_mode() -> str:
     if mode not in ("auto", "fused", "split"):
         raise ValueError(
             f"DSTPU_STREAM_BWD={mode!r} is not a valid mode: use 'auto' "
-            f"(fused single-pass when the dQ scratch fits VMEM), 'fused', "
-            f"or 'split' (classic two-kernel backward)")
+            f"(fused single-pass when the dQ scratch fits the VMEM a "
+            f"kernel may ask for), 'fused', or 'split' (classic two-kernel "
+            f"backward)")
     return mode
 
 
-def _fused_bwd_fits(gb: int, T: int, d: int, itemsize: int) -> bool:
-    """Whether the fused backward's VMEM need stays under Mosaic's scoped
-    limit.  Resident for the whole grid are the fp32 dQ accumulator and
-    the (gb, T, d) dQ out block, which Pallas double-buffers; VMEM tiles
-    pad the lane (last) dim to 128, so d=64 costs what d=128 does."""
-    lanes = -(-d // 128) * 128
-    resident = gb * T * lanes * (4 + 2 * itemsize)
-    return (resident + _FUSED_BWD_WORKING_SET[itemsize]
-            <= VMEM_SCOPED_LIMIT)
+def fused_bwd_vmem(gb: int, T: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM, in bytes, the fused backward needs at this shape.
+    Resident for the whole grid are the fp32 dQ accumulator and the
+    (gb, T, d) dQ out block, which Pallas double-buffers; VMEM tiles pad
+    the lane (last) dim to 128, so d=64 costs what d=128 does and d=192
+    what d=256 does.  Beside them, the working set's bound."""
+    lane_tiles = -(-d // 128)
+    resident = gb * T * lane_tiles * 128 * (4 + 2 * itemsize)
+    working = _FUSED_BWD_WORKING_SET[itemsize] * (lane_tiles + 1) // 2
+    return resident + working
+
+
+def _kernel_vmem_cap() -> Optional[int]:
+    """The most scoped VMEM a kernel may ask for on the backend jax runs
+    on, in bytes (the profile's ``kernel_vmem_mib``); None where the
+    generation declares none."""
+    from deepspeed_tpu.analysis import profiles
+    prof = profiles.default_profile()     # an unknown TPU kind raises
+    if prof is None or prof.kernel_vmem_mib is None:
+        return None
+    return prof.kernel_vmem_mib * _MIB
+
+
+def stream_bwd_plan(gb: int, T: int, d: int, itemsize: int,
+                    vmem_cap: Optional[int],
+                    mode: str = "auto") -> Tuple[str, Optional[int]]:
+    """Which streaming backward a call shape takes, and the
+    ``vmem_limit_bytes`` it asks Mosaic for — all read off the shape:
+
+    - ``("fused", None)``: the need (``fused_bwd_vmem``) is inside
+      Mosaic's default; the call carries no compiler parameters at all;
+    - ``("fused", limit)``: past the default and within ``vmem_cap`` (the
+      chip generation's, in bytes): the need rounded up to a MiB;
+    - ``("split", None)``: past the cap, or no cap declared: two kernels,
+      each score tile visited twice.
+
+    ``mode`` is ``DSTPU_STREAM_BWD``: "split" pins the split, "fused" the
+    fused kernel whatever the cap says (under the limit it needs)."""
+    if mode == "split":
+        return "split", None
+    need = fused_bwd_vmem(gb, T, d, itemsize)
+    if need <= VMEM_SCOPED_LIMIT:
+        return "fused", None
+    if mode == "fused" or (vmem_cap is not None and need <= vmem_cap):
+        return "fused", -(-need // _MIB) * _MIB
+    return "split", None
 
 
 def _sum_shared(dx, like):
@@ -702,9 +750,10 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
                      window=None):
     """Streaming backward on folded operands (q [G, T, d]; k, v with G /
     group heads, v ``dv`` wide) → (dq, dk, dv), same layouts.  Fused single
-    pass where ``_fused_bwd_fits`` says its dQ-resident buffers fit VMEM,
-    the two-kernel split otherwise.  The kernels give dK and dV per query
-    head; ``_sum_shared`` folds the heads that share one."""
+    pass, under the VMEM limit ``stream_bwd_plan`` asks for, where its
+    dQ-resident buffers fit the chip's cap; the two-kernel split otherwise.
+    The kernels give dK and dV per query head; ``_sum_shared`` folds the
+    heads that share one."""
     G, T, d = qg.shape
     dv_ = vg.shape[-1]
     gb = _stream_gb(G)
@@ -742,9 +791,15 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
                   jax.ShapeDtypeStruct((G, T, dv_), vg.dtype))
     dkv_scratch = [pltpu.VMEM((gb, kt, d), jnp.float32),
                    pltpu.VMEM((gb, kt, dv_), jnp.float32)]
-    mode = _stream_bwd_mode()
-    if mode == "fused" or (mode == "auto" and _fused_bwd_fits(
-            gb, T, d, qg.dtype.itemsize)):
+    kind, vmem_limit = stream_bwd_plan(gb, T, d, qg.dtype.itemsize,
+                                       _kernel_vmem_cap(),
+                                       _stream_bwd_mode())
+    if kind == "fused":
+        # a shape inside the default asks for nothing: the program it
+        # always was
+        asked = ({} if vmem_limit is None else {
+            "compiler_params": pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit)})
         dq_spec = pl.BlockSpec((gb, T, d), lambda g_, j, i: (g_, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_stream_bwd_fused_kernel, causal=causal,
@@ -758,6 +813,7 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
             scratch_shapes=[pltpu.VMEM((gb, T, d), jnp.float32),
                             *dkv_scratch],
             interpret=interpret,
+            **asked,
         )(qg, kg, vg, maskg, dog, lse, delta)
         return dq, _sum_shared(dk, kg), _sum_shared(dv, vg)
     dk, dv = pl.pallas_call(

@@ -163,10 +163,14 @@ def test_stream_kernel_at_a_192_wide_key_and_a_128_wide_value(monkeypatch,
 
 def test_the_fused_backward_is_sized_by_rule_at_192():
     """192 pads to two 128-lane tiles: the dQ-resident buffers of the fused
-    backward cost what a 256-wide head costs — inside VMEM at T 1024 in
-    bf16, outside it at T 8192, where the split backward runs."""
-    assert pattn._fused_bwd_fits(2, 1024, 192, 2)
-    assert (pattn._fused_bwd_fits(2, 4096, 192, 2)
-            == pattn._fused_bwd_fits(2, 4096, 256, 2))
-    assert not pattn._fused_bwd_fits(2, 8192, 192, 2)
-    assert pattn._fused_bwd_fits(2, 2048, 128, 2)
+    backward cost what a 256-wide head costs, and half of the working set
+    grows with the lane tiles — inside Mosaic's default at T 1024 in bf16,
+    44 MiB at T 8192, which the call asks for (the v5e's cap is 96)."""
+    plan = lambda *shape: pattn.stream_bwd_plan(*shape,
+                                                pattn._kernel_vmem_cap())
+    assert plan(2, 1024, 192, 2) == ("fused", None)
+    assert plan(2, 4096, 192, 2) == plan(2, 4096, 256, 2)
+    assert plan(2, 8192, 192, 2) == ("fused", 44 * 1024 * 1024)
+    assert (pattn.fused_bwd_vmem(2, 8192, 192, 2)
+            - pattn.fused_bwd_vmem(2, 8192, 128, 2)) == (16 + 4) * 1024 * 1024
+    assert plan(2, 2048, 128, 2) == ("fused", None)

@@ -278,16 +278,118 @@ def test_stream_bwd_mode_validation(monkeypatch):
         pattn._stream_bwd_mode()
     monkeypatch.delenv("DSTPU_STREAM_BWD")
     assert pattn._stream_bwd_mode() == "auto"
-    # the auto gate: the dQ-resident buffers must fit Mosaic's scoped
-    # VMEM.  Boundaries are the shapes an AOT compile for v5e accepted /
-    # rejected (bf16: 4096 compiles, 8192 needs 20 MiB; fp32 d=128: 2048
-    # needs 17.7 MiB); d=64 pads to 128 lanes, so it costs what d=128 does
-    assert pattn._fused_bwd_fits(2, 512, 64, 2)
-    assert pattn._fused_bwd_fits(2, 4096, 64, 2)
-    assert not pattn._fused_bwd_fits(2, 8192, 64, 2)
-    assert pattn._fused_bwd_fits(2, 4096, 128, 2)
-    assert pattn._fused_bwd_fits(2, 1024, 128, 4)
-    assert not pattn._fused_bwd_fits(2, 2048, 128, 4)
+
+
+MIB = 1024 * 1024
+V5E_CAP = 96 * MIB      # analysis/profiles.py: the v5e row's kernel_vmem_mib
+
+# (gb, T, d, itemsize) -> the verdict under the v5e's cap.  The boundaries
+# are what an AOT compile for a v5e accepts (tests/test_tpu_aot_kernels.py
+# compiles the T 8192 calls under the limits asked here): inside Mosaic's
+# 16 MiB default the call asks for nothing; d=64 pads to 128 lanes, so it
+# costs what d=128 does, d=192 what d=256 does
+PLAN_CASES = [
+    ((2, 512, 64, 2), ("fused", None)),
+    ((2, 4096, 64, 2), ("fused", None)),
+    ((2, 8192, 64, 2), ("fused", 24 * MIB)),     # the hybrid stack's calls
+    ((2, 4096, 128, 2), ("fused", None)),        # the looped model's
+    ((2, 2048, 128, 2), ("fused", None)),
+    ((2, 1024, 128, 4), ("fused", None)),
+    ((2, 2048, 128, 4), ("fused", 18 * MIB)),
+    ((2, 1024, 192, 2), ("fused", None)),
+    ((2, 4096, 192, 2), ("fused", 28 * MIB)),
+    ((2, 4096, 256, 2), ("fused", 28 * MIB)),
+    ((2, 8192, 192, 2), ("fused", 44 * MIB)),    # the latent core's
+    ((2, 16384, 192, 2), ("fused", 76 * MIB)),
+    ((2, 32768, 192, 2), ("split", None)),       # 140 MiB: past the cap
+    ((2, 65536, 64, 2), ("split", None)),
+]
+
+
+@pytest.mark.parametrize("shape,want", PLAN_CASES,
+                         ids=["-".join(map(str, c[0])) for c in PLAN_CASES])
+def test_stream_bwd_plan_reads_the_verdict_off_the_shape(shape, want):
+    """Three outcomes: fused with no compiler parameters inside Mosaic's
+    default, fused under the limit it needs up to the generation's cap,
+    the split past it; without a declared cap nothing is asked for, and
+    DSTPU_STREAM_BWD pins either kernel."""
+    assert pattn.stream_bwd_plan(*shape, V5E_CAP) == want
+    need = pattn.fused_bwd_vmem(*shape)
+    if want == ("fused", None):
+        assert need <= pattn.VMEM_SCOPED_LIMIT
+        assert pattn.stream_bwd_plan(*shape, None) == want
+    else:
+        assert need > pattn.VMEM_SCOPED_LIMIT
+        assert pattn.stream_bwd_plan(*shape, None) == ("split", None)
+        limit = pattn.stream_bwd_plan(*shape, V5E_CAP, "fused")[1]
+        assert limit % MIB == 0 and 0 <= limit - need < MIB
+    assert pattn.stream_bwd_plan(*shape, V5E_CAP, "split") == ("split", None)
+    assert pattn.stream_bwd_plan(*shape, V5E_CAP, "fused")[0] == "fused"
+
+
+def test_the_cap_is_the_profiles(monkeypatch):
+    """The cap comes from the backend's row in analysis/profiles.py: the
+    v5e declares one, the CPU rig traces the v5e's program, a generation
+    that declares none stays inside the default."""
+    from deepspeed_tpu.analysis import profiles
+    assert profiles.for_device_kind("TPU v5 lite").kernel_vmem_mib == 96
+    assert profiles.PROFILES["v4-8"].kernel_vmem_mib is None
+    assert pattn._kernel_vmem_cap() == V5E_CAP
+    monkeypatch.setattr(profiles, "default_profile",
+                        lambda: profiles.PROFILES["v4-8"])
+    assert pattn._kernel_vmem_cap() is None
+    monkeypatch.setattr(profiles, "default_profile", lambda: None)
+    assert pattn._kernel_vmem_cap() is None
+
+
+def _bwd_calls(G, T, d, dv, dtype=jnp.bfloat16):
+    """The ``pallas_call`` equations of the streaming backward's jaxpr."""
+    S = lambda *s, dt=dtype: jax.ShapeDtypeStruct(s, dt)
+    args = (S(G, T, d), S(G, T, d), S(G, T, dv), S(G, 1, T, dt=jnp.float32),
+            S(G, T, dv), S(G, 1, T, dt=jnp.float32), S(G, T, dv))
+    jaxpr = jax.make_jaxpr(lambda *a: pattn._stream_bwd_impl(
+        *a, True, True))(*args)
+    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+
+def test_the_traced_call_asks_for_its_vmem_only_past_the_default():
+    """(2, 1024, 64): one call with NO compiler parameters, the program it
+    always was; (2, 4096, 192 / 128), which the 16 MiB rule split: one
+    call carrying ``vmem_limit_bytes``."""
+    (call,) = _bwd_calls(2, 1024, 64, 64)
+    assert dict(call.params["compiler_params"]) == {}
+    (call,) = _bwd_calls(2, 4096, 192, 128)
+    params = call.params["compiler_params"]["mosaic_tpu"]
+    assert params.vmem_limit_bytes == 28 * MIB
+    assert params == type(params)(vmem_limit_bytes=28 * MIB)
+
+
+def test_fused_and_split_agree_at_a_shape_the_old_rule_refused(monkeypatch):
+    """G 2, T 4096, d 192 / dv 128, causal, bf16 (eight tiles a side, the
+    latent core's widths): the fused backward under its limit against the
+    split, same order of accumulation in fp32."""
+    rng = np.random.default_rng(5)
+    T = 4096
+    q, k = (jnp.asarray(rng.normal(size=(1, T, 2, 192)), jnp.bfloat16)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.normal(size=(1, T, 2, 128)), jnp.bfloat16)
+            for _ in range(2))
+    mask = jnp.ones((1, T), jnp.float32)
+    assert pattn.stream_bwd_plan(2, T, 192, 2, V5E_CAP)[1] is not None
+
+    def grads():
+        return jax.grad(lambda q, k, v: jnp.sum(
+            pattn.stream_attention(q, k, v, mask, True, True)
+            .astype(jnp.float32) * w), (0, 1, 2))(q, k, v)
+
+    monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+    g_split = grads()
+    monkeypatch.delenv("DSTPU_STREAM_BWD")
+    g_fused = grads()
+    for a, b in zip(g_fused, g_split):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------- hybrid fwd/bwd dispatch

@@ -1,8 +1,9 @@
 """The kernels of the latent-attention / expert path at their REAL widths,
 compiled here for a described TPU v5e (no chip attached): what interpret
 mode cannot show — whether Mosaic takes a 192-wide query / key head beside
-a 128-wide value head, and whether the grouped matmuls become kernels and
-not dense products.  A compile that passes is not a chip run: nothing here
+a 128-wide value head, whether it grants the streaming backward the scoped
+VMEM ``stream_bwd_plan`` asks for at T 8192, and whether the grouped
+matmuls become kernels and not dense products.  A compile that passes is not a chip run: nothing here
 is a time.  The topology is described inside a fixture (never while a module
 is imported), and every such test lives in this one file, so one worker
 loads the TPU's library."""
@@ -32,24 +33,80 @@ def compiled_text(fn, *shapes):
         lowering_platforms=("tpu",)).compile().as_text()
 
 
+def _stream_loss(window=None):
+    def loss(q, k, v, mask):
+        return jnp.sum(pattn.stream_attention(q, k, v, mask, True, False,
+                                              window).astype(jnp.float32))
+    return loss
+
+
 def test_mosaic_takes_the_streaming_kernel_at_192_and_128(one_chip):
-    """Forward, and the backward (split at T 8192: the fused one's dQ
-    scratch is past VMEM), 2 x 8192 tokens, 16 heads, bf16: three Pallas
+    """Forward, and the backward — ONE fused call at T 8192 under the 44 MiB
+    of scoped VMEM ``stream_bwd_plan`` asks Mosaic for (its dQ scratch is
+    past the 16 MiB default) — 2 x 8192 tokens, 16 heads, bf16: two Pallas
     calls, no padding of 192 to 256 anywhere in the wrapper."""
     B, T, n, d, dv = 2, 8192, 16, 192, 128
     S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
                                                          sharding=one_chip)
     args = (S(B, T, n, d), S(B, T, n, d), S(B, T, n, dv),
             S(B, T, dt=jnp.float32))
-
-    def loss(q, k, v, mask):
-        return jnp.sum(pattn.stream_attention(q, k, v, mask, True)
-                       .astype(jnp.float32))
-
+    assert pattn.stream_bwd_plan(2, T, d, 2, pattn._kernel_vmem_cap()) == (
+        "fused", 44 * 1024 * 1024)
+    loss = _stream_loss()
     assert compiled_text(loss, *args).count("tpu_custom_call") == 1
     text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     assert "bf16[32,8192,256]" not in text
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window512"])
+def test_mosaic_takes_the_hybrid_stacks_backward_in_one_call(one_chip,
+                                                             window):
+    """The hybrid stack's attention calls at T 8192: 40 query heads of 64
+    over 20 key heads and 10 value heads 128 wide, the whole triangle and
+    under a window of 512.  The backward is one fused call under the
+    24 MiB the rule asks (Mosaic's default refuses it: 19.3 MiB of 16)."""
+    B, T, n, d, dv = 1, 8192, 40, 64, 128
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    args = (S(B, T, n, d), S(B, T, n // 2, d), S(B, T, n // 4, dv),
+            S(B, T, dt=jnp.float32))
+    assert pattn.stream_bwd_plan(2, T, d, 2, pattn._kernel_vmem_cap()) == (
+        "fused", 24 * 1024 * 1024)
+    text = compiled_text(jax.grad(_stream_loss(window), argnums=(0, 1, 2)),
+                         *args)
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_the_rule_asks_enough_where_a_plain_bound_would_not(one_chip,
+                                                            monkeypatch):
+    """What the limit is for: the latent core's fused backward is REFUSED
+    under Mosaic's default and under the resident buffers + 8 MiB that a
+    128-lane head needs (42.9 MiB of 40), and compiles under the rule's
+    44; past the cap (T 32,768: 140 MiB) the rule splits and the split
+    compiles with no limit at all."""
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+
+    def backward(T):
+        G, d, dv = 32, 192, 128
+        rows = S(G, 1, T, dt=jnp.float32)
+        return compiled_text(
+            lambda *a: pattn._stream_bwd_impl(*a, True, False),
+            S(G, T, d), S(G, T, d), S(G, T, dv), rows, S(G, T, dv), rows,
+            S(G, T, dv))
+
+    plan = pattn.stream_bwd_plan
+    for limit in (None, 40 * 1024 * 1024):
+        monkeypatch.setattr(pattn, "stream_bwd_plan",
+                            lambda *a, **k: ("fused", limit))
+        with pytest.raises(Exception, match="Scoped allocation"):
+            backward(8192)
+    monkeypatch.setattr(pattn, "stream_bwd_plan", plan)
+    assert backward(8192).count("tpu_custom_call") == 1
+    assert plan(2, 32768, 192, 2, pattn._kernel_vmem_cap()) == (
+        "split", None)
+    assert backward(32768).count("tpu_custom_call") == 2
 
 
 def test_the_grouped_matmuls_compile_to_kernels(one_chip, monkeypatch):
