@@ -19,6 +19,8 @@ from deepspeed_tpu.models.looped import LoopedConfig, LoopedLM, LOOPED_SIZES
 from deepspeed_tpu.models.hybrid import HybridConfig, HybridLM, HYBRID_SIZES
 from deepspeed_tpu.models.latent_moe import (LatentMoEConfig, LatentMoELM,
                                              LATENT_MOE_SIZES)
+from deepspeed_tpu.models.delta_moe import (DeltaMoEConfig, DeltaMoELM,
+                                            DELTA_MOE_SIZES)
 
 __all__ = [
     "TransformerConfig", "init_block_params", "block_partition_specs",
@@ -29,4 +31,5 @@ __all__ = [
     "LoopedConfig", "LoopedLM", "LOOPED_SIZES",
     "HybridConfig", "HybridLM", "HYBRID_SIZES",
     "LatentMoEConfig", "LatentMoELM", "LATENT_MOE_SIZES",
+    "DeltaMoEConfig", "DeltaMoELM", "DELTA_MOE_SIZES",
 ]

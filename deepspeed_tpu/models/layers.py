@@ -343,18 +343,20 @@ def gelu(x):
     return y.astype(x.dtype)
 
 
-def _rms_norm(x, scale, eps):
+def _rms_norm(x, scale, eps, zero_centred=False):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+    scale = scale.astype(jnp.float32)
+    return (y * (1.0 + scale if zero_centred else scale)).astype(x.dtype)
 
 
 @S.scoped("norm")
-def rms_norm(x, scale, eps=1e-6):
+def rms_norm(x, scale, eps=1e-6, zero_centred=False):
     """RMSNorm: ``x / sqrt(mean(x^2) + eps) * scale``, the statistic in
-    fp32 (no mean subtraction, no offset)."""
-    return _rms_norm(x, scale, eps)
+    fp32 (no mean subtraction, no offset); ``zero_centred``: times ``1 +
+    scale``, a scale that starts at zero."""
+    return _rms_norm(x, scale, eps, zero_centred)
 
 
 def silu(x):
@@ -785,6 +787,109 @@ def latent_attention(x, p, *, rope, nope_dim, rope_dim, v_dim, latent, eps):
          jnp.broadcast_to(k_pe, (B, T, kv.shape[2], rope_dim))], axis=-1)
     ctx = core_attention(q, k, kv[..., nope_dim:], causal=True)
     return row_parallel_linear(ctx.reshape(B, T, -1), p["o_w"])
+
+
+# ------------------------------------- gated attention / gated delta rule
+# The two mixers of a linear-attention / attention hybrid
+# (models/delta_moe.py): softmax attention with per-head norms on q and k, a
+# partly rotated head and a sigmoid gate on its context, and the Gated
+# DeltaNet mixer around ``ops/delta_rule.py``.
+
+@S.scoped("attn")
+def gated_attention(x, p, *, rope, head_dim, eps):
+    """Causal softmax attention with an OUTPUT GATE: ``[q | gate] = x W_q``
+    per head (``2 head_dim`` columns a head), ``k = x W_k``, ``v = x W_v`` on
+    fewer heads (consecutive query heads share one); zero-centred RMSNorm
+    over every query and key head (``q_norm_s`` / ``k_norm_s`` [head_dim],
+    shared by the heads); rotary on the FIRST ``rope[0].shape[-1]`` dims of
+    each q and k head, the rest pass; softmax of ``q k^T / sqrt(head_dim)``;
+    ``(context * sigmoid(gate)) W_o``.  The core is the one
+    ``core_attention`` dispatch.
+
+    x [B, T, h] replicated over ``model``; ``q_w`` [h, n 2 d / mp], ``k_w``
+    / ``v_w`` [h, n_kv d / mp] column-parallel, heads contiguous, ``o_w``
+    [n d / mp, h] row-parallel; ``rope`` = ``rotary_tables(T, rotated dims,
+    theta)``."""
+    _no_sequence_shards("gated_attention")
+    B, T, _ = x.shape
+    q, k, v = (checkpoint_name(column_parallel_linear(x, p[w]), QKV)
+               for w in ("q_w", "k_w", "v_w"))
+    q = q.reshape(B, T, -1, 2 * head_dim)
+    q, gate = q[..., :head_dim], q[..., head_dim:]
+    k, v = (t.reshape(B, T, -1, head_dim) for t in (k, v))
+    q = _rms_norm(q, p["q_norm_s"], eps, zero_centred=True)
+    k = _rms_norm(k, p["k_norm_s"], eps, zero_centred=True)
+    rotated = rope[0].shape[-1]
+    q, k = (jnp.concatenate([apply_rotary(t[..., :rotated], rope),
+                             t[..., rotated:]], axis=-1) for t in (q, k))
+    ctx = core_attention(q, k, v, causal=True)
+    gated = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
+    return row_parallel_linear(gated.reshape(B, T, -1), p["o_w"])
+
+
+@S.scoped("gdn")
+def gated_delta_net(x, p, *, key_dim, value_dim, eps):
+    """Gated DeltaNet mixer (arXiv:2412.06464).  ``[q | k | v] = silu(conv(x
+    W_qkv))``, a causal depthwise convolution without bias; ``z = x W_z``,
+    ``b = x W_b``, ``a = x W_a``; in fp32 ``q <- q / |q| / sqrt(key_dim)``,
+    ``k <- k / |k|`` (1e-6 inside the root), ``beta = sigmoid(b)``, ``g =
+    -exp(A_log) softplus(a + dt_bias)``; ``o = gated_delta_rule(q, k, v, g,
+    beta)``; ``out = (RMSNorm(o) norm_s * silu(z)) W_out``, the norm per
+    head over ``value_dim`` with ONE scale [value_dim] for all heads (not
+    zero-centred).
+
+    Sharded over the heads: ``in_qkv_w`` [h, Hk (2 dk + r dv) / mp] holds
+    key head ``i``'s columns together — ``[q_i | k_i | v_(r i) ... v_(r i + r
+    - 1)]``, ``r`` value heads a key head — so a shard has whole key heads
+    with their value heads; ``conv_w`` [K, the same columns]; ``in_z_w`` [h,
+    Hv dv / mp], ``in_b_w`` / ``in_a_w`` [h, Hv / mp] column-parallel,
+    ``A_log`` / ``dt_bias`` [Hv / mp], ``out_w`` [Hv dv / mp, h]
+    row-parallel.  The convolution runs under ``dstpu/conv``, the rule (fp32
+    state, ops/delta_rule.py) under ``dstpu/delta``."""
+    from deepspeed_tpu.ops.delta_rule import gated_delta_rule
+    from deepspeed_tpu.ops.selective_scan import causal_conv1d
+    _no_sequence_shards("gated_delta_net")
+    B, T, _ = x.shape
+    f32 = jnp.float32
+    # named for the "selective" policy, like an attention layer's q, k, v
+    qkv = checkpoint_name(column_parallel_linear(x, p["in_qkv_w"]), MIXER_IN)
+    z = checkpoint_name(column_parallel_linear(x, p["in_z_w"]), MIXER_IN)
+    heads = p["A_log"].shape[0]                  # value heads of this shard
+
+    # A checkpoint of its own inside the layer's: the backward keeps the
+    # projection and makes q, k and v again (the convolution, its
+    # activation, the split and the unit lengths), in place of the
+    # convolution's output and the float32 pieces of what follows it — 0.75
+    # GB at 16,384 x 8,192 (PERF.md, PR 37).
+    @jax.checkpoint
+    def convolved(qkv, w):
+        with S.scope("conv"):
+            qkv = silu(causal_conv1d(qkv, w,
+                                     jnp.zeros(qkv.shape[-1:], qkv.dtype)))
+        key_heads = (qkv.shape[-1] - heads * value_dim) // (2 * key_dim)
+        qkv = qkv.reshape(B, T, key_heads, -1)
+
+        def unit(t):
+            tf = t.astype(f32)
+            return tf * jax.lax.rsqrt(
+                jnp.sum(tf * tf, axis=-1, keepdims=True) + 1e-6)
+
+        return ((unit(qkv[..., :key_dim]) * key_dim ** -0.5).astype(x.dtype),
+                unit(qkv[..., key_dim:2 * key_dim]).astype(x.dtype),
+                qkv[..., 2 * key_dim:].reshape(B, T, heads, value_dim))
+
+    q, k, v = convolved(qkv, p["conv_w"])
+    beta = jax.nn.sigmoid(column_parallel_linear(x, p["in_b_w"]).astype(f32))
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        column_parallel_linear(x, p["in_a_w"]).astype(f32)
+        + p["dt_bias"].astype(f32))
+    with S.scope("delta"):
+        o = gated_delta_rule(q, k, v, g, beta)
+    # as the convolution: the rule's output and z are kept, not the norm's
+    # and the gate's float32 pieces
+    o = jax.checkpoint(lambda o, z, w: _rms_norm(o, w, eps) * silu(z))(
+        o, z.reshape(o.shape), p["norm_s"])
+    return row_parallel_linear(o.reshape(B, T, -1), p["out_w"])
 
 
 # ------------------------------------------------ hybrid (SSM / attention)

@@ -11,10 +11,13 @@ different jobs; do not take one for the other.
    held the one-hot tensors are a gigabyte each and their contractions ten
    times the experts' own work.
 2. ``dropless_moe_ffn``: the dropless layer TOLD WHICH EXPERTS IT HOLDS
-   (DeepSeek-V3's layer; ``models/latent_moe.py`` runs it).  Sigmoid scores
-   over all the published experts, a selection-only correction bias, top-k,
-   gates normalised over the chosen and scaled, bias-free SwiGLU experts,
-   shared experts every token passes through; the (token, choice) pairs
+   (DeepSeek-V3's layer; ``models/latent_moe.py`` and
+   ``models/delta_moe.py`` run it).  Scores over all the published experts
+   by one of two rules — sigmoid with a selection-only correction bias, or
+   a softmax — top-k, gates normalised over the chosen and scaled,
+   bias-free SwiGLU experts, shared experts every token passes through
+   (behind a sigmoid gate of their own where the model has one), a balance
+   loss in one of two forms; the (token, choice) pairs
    that landed on the experts ``[first, first + count)`` held here are
    sorted by expert, run through grouped matmuls over ragged groups
    (``ops/grouped_matmul.py``: Pallas kernels on a TPU) and gathered back
@@ -252,36 +255,71 @@ def moe_stack_apply(x, stacked_params, cfg: MoEConfig, attn_mask=None,
 # choice) pairs, pair ``r = t * k + j``; ``E`` published experts, ``e``
 # held by this shard.
 
-def route_tokens(x, router_w, router_b, *, top_k, scale):
+SCORING = ("sigmoid", "softmax")
+BALANCE = ("sequence", "switch")
+
+
+def route_tokens(x, router_w, router_b, *, top_k, scale, scoring="sigmoid"):
     """Scores, choices and gates of ``x`` [S, h] over ALL the published
-    experts, in fp32 at the highest matmul precision: ``s = sigmoid(x W_g)``
-    [S, E]; the chosen set is the top-``top_k`` of ``s + b`` (``router_b``
-    is DeepSeek-V3's correction bias: it moves the choice and nothing else
-    — the gates read ``s``, so its gradient is identically zero); ``g_e =
-    scale * s_e / (sum of the chosen s + 1e-20)``.  Returns ``(scores [S,
-    E], chosen [S, k] int32, gates [S, k])``."""
+    experts, in fp32 at the highest matmul precision, by one of two rules.
+
+    ``sigmoid`` (DeepSeek-V3): ``s = sigmoid(x W_g)`` [S, E]; the chosen set
+    is the top-``top_k`` of ``s + b`` (``router_b`` is the correction bias:
+    it moves the choice and nothing else — the gates read ``s``, so its
+    gradient is identically zero); ``g_e = scale * s_e / (sum of the chosen
+    s + 1e-20)``.
+
+    ``softmax`` (Qwen's expert layers): ``s = softmax(x W_g)`` over the
+    ``E`` experts; the top-``top_k`` of ``s`` (``router_b`` is None: no
+    bias); ``g_e = scale * s_e / sum of the chosen s`` — the gates
+    renormalised to sum to ``scale``.
+
+    Returns ``(scores [S, E], chosen [S, k] int32, gates [S, k])``."""
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.matmul(
-        x.astype(f32), router_w.astype(f32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + router_b.astype(f32), top_k)
+    logits = jnp.matmul(x.astype(f32), router_w.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + router_b.astype(f32), top_k)
+        floor = 1e-20
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, top_k)
+        floor = 0.0
+    else:
+        raise ValueError(f"scoring {scoring!r}: one of {SCORING}")
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     gates = scale * picked / (jnp.sum(picked, axis=-1, keepdims=True)
-                              + 1e-20)
+                              + floor)
     return scores, chosen.astype(jnp.int32), gates
 
 
-def balance_loss(scores, chosen, alpha):
-    """DeepSeek-V3's sequence-wise balance loss (eqs. 17-20) of ``scores``
-    [B, T, E] and ``chosen`` [B, T, k], per sequence, then the mean over
-    the sequences: ``f_e = E / (k T) sum_t 1[e in K_t]``, ``P_e = 1 / T
-    sum_t s_te / sum_j s_tj``, ``alpha sum_e f_e P_e``."""
-    _, T_len, E = scores.shape
+def balance_loss(scores, chosen, alpha, form="sequence"):
+    """The balance loss of ``scores`` [B, T, E] and ``chosen`` [B, T, k].
+
+    ``sequence``: DeepSeek-V3's sequence-wise loss (eqs. 17-20), per
+    sequence, then the mean over the sequences: ``f_e = E / (k T) sum_t 1[e
+    in K_t]``, ``P_e = 1 / T sum_t s_te / sum_j s_tj``, ``alpha sum_e f_e
+    P_e``.
+
+    ``switch``: the Switch form over ALL the tokens of the micro-batch:
+    ``alpha E sum_e F_e P_e`` with ``F_e`` = (pairs on ``e``) / tokens and
+    ``P_e`` the mean of ``s_te`` (``scores`` as they are: a softmax sums to
+    1).  No gradient through ``f`` / ``F`` in either."""
+    B, T_len, E = scores.shape
     k = chosen.shape[-1]
-    f = (jnp.sum(jax.nn.one_hot(chosen, E, dtype=jnp.float32), axis=(1, 2))
-         * (E / (k * T_len)))
-    P_ = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=1)
-    return alpha * jnp.mean(jnp.sum(jax.lax.stop_gradient(f) * P_, axis=-1))
+    on = jax.nn.one_hot(chosen, E, dtype=jnp.float32)
+    if form == "sequence":
+        f = jnp.sum(on, axis=(1, 2)) * (E / (k * T_len))
+        P_ = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True),
+                      axis=1)
+        return alpha * jnp.mean(
+            jnp.sum(jax.lax.stop_gradient(f) * P_, axis=-1))
+    if form == "switch":
+        F = jnp.sum(on, axis=(0, 1, 2)) / (B * T_len)
+        return alpha * E * jnp.sum(jax.lax.stop_gradient(F)
+                                   * jnp.mean(scores, axis=(0, 1)))
+    raise ValueError(f"balance loss form {form!r}: one of {BALANCE}")
 
 
 def sort_share(chosen, first, count):
@@ -491,7 +529,7 @@ def held_experts(flat, p, chosen, gates, first, num_experts):
 
 @S.scoped("moe")
 def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
-                     balance_alpha):
+                     balance_alpha, scoring="sigmoid", balance="sequence"):
     """The dropless expert layer on local shards, for the experts ``held =
     (first, count)`` of ``num_experts``.  x [B, T, h] model-replicated.
     ``p``: ``router_w`` [h, E] and ``router_b`` [E] (the router is whole
@@ -505,7 +543,11 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
         ``y_t = sum_{e in K_t, first <= e < first + count} g_e SwiGLU_e(x_t)
         + SwiGLU_shared(x_t)``
 
-    with ``K_t`` and ``g`` of ``route_tokens`` over all ``num_experts``.
+    with ``K_t`` and ``g`` of ``route_tokens`` over all ``num_experts`` by
+    the rule ``scoring`` (``softmax`` reads no ``router_b``), the balance
+    loss in the form ``balance`` (``balance_loss``), and — where ``p`` holds
+    ``shared_gate_w`` [h] — the shared experts' output times ``sigmoid(x .
+    shared_gate_w)``, a gate of their own (under ``dstpu/ffn`` with them).
 
     The rows worked on.  The pairs held come first in ``sort_share``'s
     order, so the routed part (``routed_part``) runs on a static prefix of
@@ -537,13 +579,22 @@ def dropless_moe_ffn(x, p, *, num_experts, top_k, held, route_scale,
     flat = x.reshape(B * T_len, h)
     with S.scope("route"):
         scores, chosen, gates = route_tokens(
-            flat, p["router_w"], p["router_b"], top_k=top_k,
-            scale=route_scale)
+            flat, p["router_w"], p.get("router_b"), top_k=top_k,
+            scale=route_scale, scoring=scoring)
         aux = balance_loss(scores.reshape(B, T_len, num_experts),
-                           chosen.reshape(B, T_len, top_k), balance_alpha)
+                           chosen.reshape(B, T_len, top_k), balance_alpha,
+                           form=balance)
     routed, counts = held_experts(flat, p, chosen, gates, first,
                                   num_experts)
     if ep > 1:
         with S.scope("route"):
             routed = jax.lax.psum(routed, MODEL_AXIS)
-    return routed.reshape(B, T_len, h) + T._gated_mlp(x, p), aux, counts
+    routed = routed.reshape(B, T_len, h)
+    shared = T._gated_mlp(x, p)
+    if "shared_gate_w" in p:
+        with S.scope("ffn"):
+            gate = jax.nn.sigmoid(jnp.sum(
+                x.astype(jnp.float32)
+                * p["shared_gate_w"].astype(jnp.float32), axis=-1))
+            shared = shared * gate[..., None].astype(shared.dtype)
+    return routed + shared, aux, counts

@@ -55,6 +55,10 @@ SCOPES = (
     # routing (scores, top-k, gates, sort, the two gathers, balance loss)
     # and its grouped matmuls inside (the shared experts run under ``ffn``)
     "mla", "moe", "route", "experts",
+    # a linear-attention / attention hybrid: the whole Gated DeltaNet mixer
+    # (its convolution runs under ``conv``) and the chunked gated delta
+    # rule inside it; the gated softmax attention is ``attn``
+    "gdn", "delta",
 )
 
 FORWARD, BACKWARD, REPLAY = "forward", "backward", "replay"

@@ -44,6 +44,12 @@ width, ``n`` heads):
                                   boundaries: with ``y``, the scan's own
                                   residuals — saved, the backward does not
                                   run the forward scan a second time
+* ``DELTA_OUT``    rows x Hv x dv the gated delta rule's output ``o``
+* ``DELTA_STATES`` 4 x rows x Hv x dk x dv / chunk   its fp32 matrix states
+                                  at the chunk boundaries (537 MB a layer
+                                  at 16,384 rows, 32 heads of 128 x 128,
+                                  chunk 64): with ``o``, the rule's own
+                                  residuals, as the scan's two
 """
 
 QKV = "qkv"
@@ -54,7 +60,10 @@ POST_LN_SUM = "post_ln_sum"
 MIXER_IN = "mixer_in"
 SCAN_OUT = "scan_out"
 SCAN_STATES = "scan_states"
+DELTA_OUT = "delta_out"
+DELTA_STATES = "delta_states"
 
 FULL_SAVES = (ATTN_OUT, ATTN_LSE)
 SELECTIVE_SAVES = ((QKV, FFN1) + FULL_SAVES
-                   + (POST_LN_SUM, MIXER_IN, SCAN_OUT, SCAN_STATES))
+                   + (POST_LN_SUM, MIXER_IN, SCAN_OUT, SCAN_STATES,
+                      DELTA_OUT, DELTA_STATES))

@@ -234,10 +234,11 @@ def test_the_step_holds_every_scope_of_the_table(scoped_runs, case):
     # state-space mixer, the windowed and cross-decoder cores, the Gated
     # Memory Unit and the hand-over a hybrid stack's, the latent
     # projections and the expert layer's three a latent-attention / expert
-    # stack's (both below)
+    # stack's, the Gated DeltaNet mixer and its delta rule a linear-attention
+    # hybrid's (all below)
     want = {scopes.PREFIX + s for s in scopes.SCOPES} - {
         "dstpu/loop", "dstpu/rope", "dstpu/exit"} - HYBRID_SCOPES - (
-            LATENT_MOE_SCOPES)
+            LATENT_MOE_SCOPES) - DELTA_MOE_SCOPES
     if "zero0" in case:
         # nothing to gather: the cast to the compute dtype is the update's
         # last instruction there, and under its scope
@@ -500,3 +501,39 @@ def test_remembering_takes_tracers_and_host_values(monkeypatch):
     assert scopes.step_scope_map() is not None
     scopes.forget_step()
     assert scopes.step_scope_map() is None
+
+
+DELTA_MOE_SCOPES = {"dstpu/gdn", "dstpu/delta"}
+
+
+def test_a_gated_deltanet_step_holds_its_two_scopes(policy="full"):
+    """A ``DeltaMoELM`` step: the Gated DeltaNet mixer (``gdn``) and the
+    chunked gated delta rule inside it (``delta``) run forward and backward;
+    the convolution keeps ``conv``, the gated attention ``attn`` with its
+    rotation under ``rope``, the expert layer ``moe`` / ``route`` /
+    ``experts`` and the shared expert ``ffn``.  Under ``full`` (the cell's
+    policy) the rule's forward is replayed beside its backward."""
+    from deepspeed_tpu.models import DeltaMoELM
+    engine, _, _, _ = ds.initialize(
+        model=DeltaMoELM.from_size("tiny", experts_held=(4, 4)),
+        mesh=make_mesh(devices=jax.devices()[:1]),
+        config={"train_batch_size": 2, "steps_per_print": 10 ** 9,
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "activation_checkpointing": {"enabled": True,
+                                             "policy": policy}})
+    doc = np.random.default_rng(7).integers(0, 512, size=(2, 129),
+                                            dtype=np.int32)
+    engine.train_batch((doc[:, :-1].copy(), doc[:, 1:].copy()))
+    names = scopes.step_scope_map()
+    phases = {(s, p) for s, p in names.values() if s}
+    for scope in sorted(DELTA_MOE_SCOPES | {"dstpu/conv", "dstpu/attn",
+                                            "dstpu/route", "dstpu/experts"}):
+        assert {(scope, "forward"), (scope, "backward")} <= phases, scope
+    assert {("dstpu/rope", "forward"), ("dstpu/ffn", "forward"),
+            ("dstpu/moe", "forward"), ("dstpu/head", "backward")} <= phases
+    assert DELTA_MOE_SCOPES <= {scopes.PREFIX + s for s in scopes.SCOPES}
+    replayed = {s for s, p in phases if p == "replay"}
+    assert {"dstpu/gdn", "dstpu/delta"} <= replayed
+    assert not {"dstpu/ssm", "dstpu/scan", "dstpu/mla", "dstpu/loop"} & {
+        s for s, _ in phases}

@@ -8,6 +8,9 @@ is a time.  The topology is described inside a fixture (never while a module
 is imported), and every such test lives in this one file, so one worker
 loads the TPU's library."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -144,17 +147,22 @@ def test_the_grouped_matmuls_compile_to_kernels(one_chip, monkeypatch):
     assert f"bf16[{e},{R}," not in text
 
 
-def test_both_branches_of_the_expert_layer_hold_the_kernels(one_chip,
-                                                            monkeypatch):
-    """The held experts' part at the cell's shapes (2 x 8,192 tokens, top-6,
-    8 of 64 experts): a ``conditional`` on the device whose prefix branch
-    runs the grouped-matmul kernels over 24,576 sorted rows and whose
-    overflow branch runs them over all 98,304 — the same nine products in
+@pytest.mark.parametrize("k,f,e,E,prefix", [
+    (6, 1408, 8, 64, 24576), (10, 512, 32, 512, 20480)],
+    ids=["8-of-64-top6", "32-of-512-top10"])
+def test_both_branches_of_the_expert_layer_hold_the_kernels(
+        one_chip, monkeypatch, k, f, e, E, prefix):
+    """The held experts' part at the two expert cells' shapes (16,384 tokens;
+    top-6 of 64 experts 1,408 wide with 8 held; top-10 of 512 experts 512
+    wide with 32 held, ~320 rows a group in 512-row tiles): a ``conditional``
+    on the device whose prefix branch runs the grouped-matmul kernels over
+    the prefix of the sorted rows (24,576; 20,480) and whose overflow branch
+    runs them over all of them (98,304; 163,840) — the same nine products in
     either (each branch recomputes its three and takes six gradients), all
     Pallas kernels under ``dstpu/experts``."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    S_, k, h, f, e, E = 2 * 8192, 6, 2048, 1408, 8, 64
-    assert M.prefix_rows(S_ * k, e, E) == 24576
+    S_, h = 2 * 8192, 2048
+    assert M.prefix_rows(S_ * k, e, E) == prefix
     S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
                                                          sharding=one_chip)
     p = {"exp_gate_w": S(e, h, f), "exp_up_w": S(e, h, f),
@@ -172,4 +180,53 @@ def test_both_branches_of_the_expert_layer_hold_the_kernels(one_chip,
     assert " conditional(" in text and "ragged-dot" not in text
     assert all("dstpu/experts" in line for line in kernels)
     on = lambda rows: sum(f"bf16[{rows}," in line for line in kernels)
-    assert on(24576) == on(98304) == 9 and len(kernels) == 18
+    assert on(prefix) == on(S_ * k) == 9 and len(kernels) == 18
+
+
+# ------------------- the linear-attention / attention hybrid's calls (PR 37)
+
+def test_mosaic_takes_the_256_wide_head_on_two_shared_heads(one_chip):
+    """The gated attention's core at its real shape: 1 x 16,384 tokens, 16
+    query heads of 256 on 2 key/value heads (8 query heads a group), bf16.
+    Forward, and the backward as ONE fused call under the 76 MiB of scoped
+    VMEM ``stream_bwd_plan`` asks for at gb 2, T 16,384, d 256 (64 MiB of
+    resident dQ + 12), inside the v5e's 96 MiB cap."""
+    B, T, n, kv, d = 1, 16384, 16, 2, 256
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    args = (S(B, T, n, d), S(B, T, kv, d), S(B, T, kv, d),
+            S(B, T, dt=jnp.float32))
+    assert pattn.stream_supported(T, d)
+    assert pattn.stream_bwd_plan(2, T, d, 2, pattn._kernel_vmem_cap()) == (
+        "fused", 76 * 1024 * 1024)
+    loss = _stream_loss()
+    assert compiled_text(loss, *args).count("tpu_custom_call") == 1
+    text = compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_the_delta_rule_compiles_with_no_array_of_every_steps_state(one_chip):
+    """The chunked gated delta rule at the cell's sizes (16,384 steps, 16
+    key and 32 value heads of 128, bf16 q / k / v, float32 gates), forward
+    and backward, for the TPU: XLA takes the chunk's inverse by blocks and
+    the two nested loops; the only arrays of 32 x 128 x 128 states are the
+    256 kept at the chunk boundaries (8 segments of 32), never one per
+    step; what the program holds at once stays under 3 GB."""
+    from deepspeed_tpu.ops import delta_rule as dr
+    T = 16384
+    S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
+                                                         sharding=one_chip)
+    args = (S(1, T, 16, 128), S(1, T, 16, 128), S(1, T, 32, 128),
+            S(1, T, 32, dt=jnp.float32), S(1, T, 32, dt=jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(dr.gated_delta_rule(*a).astype(jnp.float32)),
+        argnums=range(5))).trace(*args).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "f32[8,32,1,16,2,128,128]" in text        # the boundary states
+    assert "tpu_custom_call" not in text             # XLA's own, no kernel
+    per_step = [dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
+                if dims.endswith("128,128") and math.prod(
+                    int(n) for n in dims.split(",")) >= T * 32 * 128 * 128]
+    assert not per_step, per_step[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
