@@ -256,6 +256,9 @@ class DeltaMoELM:
     routed_rows: tuple = (0, 0)
     #: (steps a chunk, chunks a sequence) of the delta rule in that program
     delta_chunks: tuple = (0, 0)
+    #: whether that program's rule walks its chunks in the Pallas kernels
+    #: (``delta_rule.kernel_walks``: a TPU and shapes the kernels take)
+    delta_kernel: bool = False
 
     @classmethod
     def from_size(cls, size: str, **overrides) -> "DeltaMoELM":
@@ -299,6 +302,7 @@ class DeltaMoELM:
             "routed_rows_all": self.routed_rows[1],
             "delta_chunk": chunk,
             "delta_chunks_per_sequence": chunks,
+            "delta_kernel": int(self.delta_kernel),
             # the fp32 boundary states one sequence's backward keeps a layer
             "delta_state_bytes_per_layer": (
                 4 * chunks * cfg.value_heads * cfg.key_dim * cfg.value_dim),
@@ -361,6 +365,9 @@ class DeltaMoELM:
             pairs, cfg.experts_held[1] // L.axis_size_or_1(MODEL_AXIS),
             cfg.num_experts), pairs)
         self.delta_chunks = delta_rule.chunk_layout(tokens.shape[1])
+        self.delta_kernel = delta_rule.kernel_walks(
+            cfg.key_dim, cfg.value_dim, tokens.shape[1],
+            params["wte"].dtype)
         params, z3_deferred = T.zero3_enter(params, self.zero3_dims)
         z3_blocks = z3_deferred.get("blocks") or [None] * len(cfg.segments)
         with S.scope("embed"):

@@ -87,6 +87,7 @@ def test_the_step_scalars_reach_the_spool(trained):
             gauges["layers_moe"]) == (3, 1, 4)
     assert (gauges["delta_chunk"], gauges["delta_chunks_per_sequence"],
             gauges["delta_state_bytes_per_layer"]) == (64, 1, 4 * 4 * 8 * 8)
+    assert gauges["delta_kernel"] == 0             # no TPU here: XLA's walk
     assert (gauges["routed_rows_prefix"], gauges["routed_rows_all"]) == (
         256, pairs)
     engine.telemetry.close()                   # the JSONL sink's file

@@ -161,7 +161,7 @@ def test_the_model_counts_what_its_layers_decided(family):
         "layer_applications": 4, "experts_total": 16, "experts_held": 4,
         "experts_per_token": 3, "routed_rows_prefix": 512,
         "routed_rows_all": pairs, "delta_chunk": 64,
-        "delta_chunks_per_sequence": 2,
+        "delta_chunks_per_sequence": 2, "delta_kernel": 0,
         "delta_state_bytes_per_layer": 4 * 2 * 4 * 8 * 8}
 
 

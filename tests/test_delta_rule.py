@@ -4,7 +4,9 @@ nothing of the program) — values and every input's gradient in float32 to
 1e-5, over chunks of 16 and 64, sequences of 2 and of 5 chunks and ones that
 leave a tail, decays near 0 and near 1, value heads that share a key head —
 bf16 inputs at a stated band, the states kept at the chunk boundaries, and
-the shape discipline: nothing of size T x dk x dv in the gradient's jaxpr."""
+the shape discipline: nothing of size T x dk x dv in the gradient's jaxpr.
+The walks as Pallas kernels (interpreted here) against XLA's walks and a
+float64 recurrence, and which shapes and backends take them."""
 
 import functools
 
@@ -20,20 +22,21 @@ ROWS, HK, HV, DK, DV = 2, 2, 4, 8, 8
 NAMES = "q k v g beta".split()
 
 
-def case(T, decay="mixed", seed=0):
+def case(T, decay="mixed", seed=0, dims=(ROWS, HK, HV, DK, DV)):
     """Inputs as the mixer makes them: unit keys, queries of norm 1 /
     sqrt(dk), ``g <= 0``, ``beta`` in (0, 1); and a weight for the output.
     ``decay``: ``near0`` (``exp(g)`` ~ 1e-7: the state is all but wiped
     each step), ``near1`` (``exp(g)`` ~ 0.999: it barely fades), ``mixed``."""
+    rows, hk, hv, dk, dv = dims
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[0], (ROWS, T, HK, DK))) / np.sqrt(DK)
-    k = unit(jax.random.normal(ks[1], (ROWS, T, HK, DK)))
-    v = jax.random.normal(ks[2], (ROWS, T, HV, DV))
+    q = unit(jax.random.normal(ks[0], (rows, T, hk, dk))) / np.sqrt(dk)
+    k = unit(jax.random.normal(ks[1], (rows, T, hk, dk)))
+    v = jax.random.normal(ks[2], (rows, T, hv, dv))
     rate = {"near0": 16.0, "near1": 1e-3, "mixed": 0.5}[decay]
-    g = -rate * jax.nn.softplus(jax.random.normal(ks[3], (ROWS, T, HV)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (ROWS, T, HV)))
-    weight = jax.random.normal(ks[5], (ROWS, T, HV, DV))
+    g = -rate * jax.nn.softplus(jax.random.normal(ks[3], (rows, T, hv)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, T, hv)))
+    weight = jax.random.normal(ks[5], (rows, T, hv, dv))
     return (q, k, v, g, beta), weight
 
 
@@ -196,3 +199,139 @@ def test_no_array_of_the_whole_sequence_times_the_state(monkeypatch):
     big = [s for s in shapes if int(np.prod(s)) >= whole]
     assert not big, big
     assert (8, 4, ROWS, HK, HV // HK, DK, DV) in shapes   # boundary states
+
+
+# --------------------------------------------- the walks as Pallas kernels
+# (interpreted: no TPU here).  The kernels take a state whose two widths
+# are whole lane tiles, so these cases are 128 wide.
+
+WIDE = 128
+
+
+def recurrence64(args, weight):
+    """Value and gradients of the recurrence, one step at a time, in
+    float64 (its own few lines: the reference's is float32 by contract)."""
+    def rule(q, k, v, g, beta):
+        q, k = (jnp.repeat(x, v.shape[2] // x.shape[2], axis=2)
+                for x in (q, k))
+
+        def step(S, x):
+            q_t, k_t, v_t, g_t, b_t = x
+            S = jnp.exp(g_t)[..., None, None] * S
+            seen = jnp.einsum("bhkv,bhk->bhv", S, k_t)
+            S = S + jnp.einsum("bhk,bhv->bhkv", k_t,
+                               b_t[..., None] * (v_t - seen))
+            return S, jnp.einsum("bhkv,bhk->bhv", S, q_t)
+
+        S0 = jnp.zeros((*v.shape[::2], q.shape[-1], v.shape[-1]), v.dtype)
+        _, o = jax.lax.scan(step, S0, tuple(
+            jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+        return jnp.moveaxis(o, 0, 1)
+
+    with jax.enable_x64(True):
+        args, weight = jax.tree_util.tree_map(
+            lambda x: jnp.asarray(np.asarray(x, np.float64)), (args, weight))
+        return rule(*args), jax.grad(
+            lambda *a: jnp.sum(rule(*a) * weight), argnums=range(5))(*args)
+
+
+def walked(chunk, interpret):
+    """``(output, the states at the chunks' starts, the five gradients)`` of
+    the rule: by the kernels where ``interpret``, else by XLA's walk."""
+    def run(args, weight):
+        out, starts = dr._forward(*args, chunk, interpret)
+        return out, starts, jax.grad(lambda *a: jnp.sum(
+            dr.gated_delta_rule(*a, chunk, interpret) * weight),
+            argnums=range(5))(*args)
+    return jax.jit(run)
+
+
+_walked = functools.lru_cache(maxsize=None)(walked)
+
+
+def close(got, want, tol, what):
+    np.testing.assert_allclose(
+        got, want, rtol=0, atol=tol * float(np.max(np.abs(want))),
+        err_msg=what)
+
+
+@pytest.mark.parametrize("tail", [0, 5], ids=["whole", "tail"])
+@pytest.mark.parametrize("hv", [2, 4], ids=["r1", "r2"])
+@pytest.mark.parametrize("decay", ["mixed", "near0", "near1"])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_the_kernels_walk_is_xlas_and_the_recurrence(chunk, decay, hv, tail):
+    """Three chunks, or three and a tail padded with idle steps; one or two
+    value heads a key head: output, the state at every chunk's start and
+    all five gradients of the kernels' walk are XLA's walk's to 1e-6 of
+    their largest entry (the same float32 products in another order), and
+    output and gradients the float64 recurrence's to 1e-5."""
+    args, weight = case(3 * chunk + tail, decay,
+                        dims=(1, 2, hv, WIDE, WIDE))
+    assert dr.kernel_walks(WIDE, WIDE, 3 * chunk + tail, jnp.float32, chunk,
+                           interpret=True)
+    out, starts, grads = _walked(chunk, True)(args, weight)
+    out_x, starts_x, grads_x = _walked(chunk, False)(args, weight)
+    out_64, grads_64 = recurrence64(args, weight)
+    assert starts.shape == starts_x.shape == (
+        1, 3 + bool(tail), 1, 2, hv // 2, WIDE, WIDE)
+    close(out, out_x, 1e-6, "output")
+    close(starts, starts_x, 1e-6, "starts")
+    close(out, out_64, 1e-5, "output, float64")
+    for name, a, b, c in zip(NAMES, grads, grads_x, grads_64):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        close(a, b, 1e-6, name)
+        close(a, c, 1e-5, name + ", float64")
+
+
+def test_the_kernels_hand_the_state_from_one_call_to_the_next(monkeypatch):
+    """7 chunks in segments of 3: three kernel calls a direction (the last
+    chunk of the third is padding), the state leaving one as an output and
+    entering the next — and ``dS`` on the way back."""
+    monkeypatch.setattr(dr, "DELTA_SEGMENT", 3)
+    assert dr._layout(7 * 16, 16) == (16, 3, 3)
+    args, weight = case(7 * 16, seed=7, dims=(1, 2, 4, WIDE, WIDE))
+    out, starts, grads = walked(16, True)(args, weight)
+    out_x, starts_x, grads_x = walked(16, False)(args, weight)
+    assert starts.shape == (3, 3, 1, 2, 2, WIDE, WIDE)
+    assert np.any(np.asarray(starts[1, 0]))        # not a fresh zero state
+    close(out, out_x, 1e-6, "output")
+    close(starts, starts_x, 1e-6, "starts")
+    for name, a, b in zip(NAMES, grads, grads_x):
+        close(a, b, 1e-6, name)
+    out_64, grads_64 = recurrence64(args, weight)
+    close(out, out_64, 1e-5, "output, float64")
+    for name, a, c in zip(NAMES, grads, grads_64):
+        close(a, c, 1e-5, name + ", float64")
+
+
+def test_which_shapes_and_backends_take_the_kernels(monkeypatch):
+    """``supported``: both widths of the state whole lane tiles, the chunk
+    whole sublane tiles of the output's dtype.  ``kernel_walks``: that, on
+    a TPU (or interpreted); XLA's walk — no ``pallas_call`` in the jaxpr,
+    forward or backward — for any other shape or backend."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert dr.supported(128, 128, 64, bf16) and dr.supported(256, 128, 8, f32)
+    assert not dr.supported(64, 128, 64, f32)
+    assert not dr.supported(128, 8, 64, f32)
+    assert not dr.supported(128, 128, 12, f32)
+    assert not dr.supported(128, 128, 8, bf16)      # half a bf16 tile
+
+    def calls(dims, T, interpret=False):
+        args, _ = case(T, dims=dims)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(dr.gated_delta_rule(*a, 16, interpret)),
+            argnums=range(5)))(*args))
+        return text.count("pallas_call")
+
+    wide, narrow = (1, 2, 4, WIDE, WIDE), (ROWS, HK, HV, DK, DV)
+    assert jax.default_backend() == "cpu"
+    assert not dr.kernel_walks(WIDE, WIDE, 32, f32, 16)
+    assert calls(wide, 32) == 0                      # no TPU: XLA's walk
+    assert calls(wide, 32, interpret=True) == 2      # forward and backward
+    assert calls(narrow, 32, interpret=True) == 0    # an 8-wide state
+    # a sequence shorter than a chunk is ONE chunk of its length
+    assert not dr.kernel_walks(WIDE, WIDE, 7, f32, 16, interpret=True)
+    assert calls(wide, 7, interpret=True) == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dr.kernel_walks(128, 128, 16384, bf16)    # the cell's rule
+    assert not dr.kernel_walks(DK, DV, 16384, bf16)

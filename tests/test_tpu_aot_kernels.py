@@ -205,26 +205,57 @@ def test_mosaic_takes_the_256_wide_head_on_two_shared_heads(one_chip):
     assert text.count("tpu_custom_call") == 2
 
 
-def test_the_delta_rule_compiles_with_no_array_of_every_steps_state(one_chip):
+def test_the_delta_rule_compiles_with_no_array_of_every_steps_state(
+        one_chip, monkeypatch):
     """The chunked gated delta rule at the cell's sizes (16,384 steps, 16
     key and 32 value heads of 128, bf16 q / k / v, float32 gates), forward
     and backward, for the TPU: XLA takes the chunk's inverse by blocks and
-    the two nested loops; the only arrays of 32 x 128 x 128 states are the
-    256 kept at the chunk boundaries (8 segments of 32), never one per
-    step; what the program holds at once stays under 3 GB."""
+    the loop over the 8 segments; a segment's 32 chunks are walked inside
+    ONE Pallas call a direction (no loop over the 256 chunks is left).
+    What crosses into a call is q, k, v (and, backward, the output's
+    cotangent) as they came, bf16, and float32 for all the rest: the
+    states and the gates' [64, 64] matrices and rows — nothing [T, H, d]
+    wide in float32; out of the backward call, of that width, only the
+    gradients of q and k.  The only arrays of 32 x 128 x 128 states are
+    the 256 kept at the chunk boundaries, never one per step; what the
+    program holds at once stays under 3 GB."""
     from deepspeed_tpu.ops import delta_rule as dr
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     T = 16384
     S = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt,
                                                          sharding=one_chip)
     args = (S(1, T, 16, 128), S(1, T, 16, 128), S(1, T, 32, 128),
             S(1, T, 32, dt=jnp.float32), S(1, T, 32, dt=jnp.float32))
-    compiled = jax.jit(jax.grad(
+    assert dr.kernel_walks(128, 128, T, jnp.bfloat16)
+    forward = compiled_text(dr.gated_delta_rule, *args)
+    assert forward.count("tpu_custom_call") == forward.count(" while(") == 1
+    lowered = jax.jit(jax.grad(
         lambda *a: jnp.sum(dr.gated_delta_rule(*a).astype(jnp.float32)),
-        argnums=range(5))).trace(*args).lower(
-            lowering_platforms=("tpu",)).compile()
+        argnums=range(5))).trace(*args).lower(lowering_platforms=("tpu",))
+    # by the lowering's types: 32 chunks x 16 key heads (x 2 value heads)
+    calls = [line for line in lowered.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    qk, vo = "32x16x64x128", "32x16x2x64x128"
+    for line, operands, results in zip(calls, (10, 12), (3, 10)):
+        types = re.findall(r"tensor<([0-9x]+)x(f32|bf16)>",
+                           line[line.rindex(" : ("):])
+        assert len(types) == operands + results, line[-400:]
+        assert {dims for dims, dtype in types if dtype == "bf16"} == {
+            qk, vo}
+        assert ("16x2x128x128", "f32") in types          # the state
+        assert ("32x16x2x128x128", "f32") in types       # the chunks' starts
+        # T and D a head and chunk; backward their gradients too
+        assert types.count(("32x16x2x64x64", "f32")) == 2 * (1 + (
+            results == 10))
+        # float32 at q's, k's or v's size: the gradients of q and k alone
+        wide = [t for t in types if t[1] == "f32" and t[0] in (qk, vo)]
+        assert wide == [(qk, "f32")] * 2 * (results == 10)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "f32[8,32,1,16,2,128,128]" in text        # the boundary states
-    assert "tpu_custom_call" not in text             # XLA's own, no kernel
+    # the two walks are kernels, each inside its loop over the segments
+    assert text.count("tpu_custom_call") == text.count(" while(") == 2
     per_step = [dims for dims in re.findall(r"f32\[([0-9,]+)\]", text)
                 if dims.endswith("128,128") and math.prod(
                     int(n) for n in dims.split(",")) >= T * 32 * 128 * 128]
