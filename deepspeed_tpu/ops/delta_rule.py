@@ -46,16 +46,20 @@ axis is the chunk, walked in order, and the state (backward: its gradient)
 of a block of ``DELTA_HEADS`` value heads stays in VMEM from the segment's
 first chunk to its last; it enters and leaves the call as an operand and a
 result, so one segment hands it to the next.  A grid step reads the chunk's
-q, k and v as they came and the gates, and does ``_prepare``'s work and
-``_recur``'s in VMEM: ``U``, ``W``, the scaled q and k and ``(q k^T) * D``
-never exist in HBM.  The backward kernel forms ``V'`` again from the saved
-start and writes the gradients of q and v, of the gates (pulled back through
-``_gates`` to k, g and beta by XLA) and what reaches k past them.
-Everywhere else — the CPU, a narrow state: the tests' oracle — XLA:
-``_prepare`` for a segment at once and ``lax.scan`` over ``_recur`` and over
-its ``jax.vjp`` (``_walk``, ``_walk_back``).  The gates — k's pair products,
-the decays, the inverse by blocks — and the copies into the chunked layout
-are XLA's on either path, under the same scope.
+q, k, v and g as they came and the rows of ``_rows`` (``beta`` and the
+decays of the chunk's steps, XLA's), and does all of ``_gates``' work past
+them in VMEM — ``D`` (``_decay_in_vmem``), k's pair products, ``L``, the
+inverse by blocks (``_inverse_in_vmem``) — then ``_prepare``'s and
+``_recur``'s: ``D``, ``T``, ``U``, ``W``, the scaled q and k and ``(q k^T) *
+D`` never exist in HBM.  The backward kernel forms them and ``V'`` again
+from the same operands and the saved start, pulls ``T``'s gradient back
+through the inverse, ``L`` and ``D`` in VMEM, and writes the gradients of q,
+k and v whole, ``g``'s part through ``D`` and the gradients of ``_rows``'
+results, which XLA pulls back to g and beta.  Everywhere else — the CPU, a
+narrow state: the tests' oracle — XLA: ``_gates`` and ``_prepare`` for a
+segment at once and ``lax.scan`` over ``_recur`` and over its ``jax.vjp``
+(``_walk``, ``_walk_back``).  The rows and the copies into the chunked
+layout are XLA's on either path, under the same scope.
 """
 
 from __future__ import annotations
@@ -76,10 +80,11 @@ from deepspeed_tpu.ops.remat_names import DELTA_OUT, DELTA_STATES
 #: backward runs).  PERF.md, PR 37, has the chip's times.
 DELTA_CHUNK = 64
 #: chunks whose gates are made at once and which one kernel call walks: 32 x
-#: 64 = 2,048 steps, so T and D [64, 64] of 32 heads are 2 x 16.8 MB in
-#: float32 (backward: as much again for their gradients, and 2 x 16.8 MB
-#: for q's and k's), and the state crosses HBM once a segment.  On XLA's
-#: walk U, W and the scaled q and k, 4 x 33.5 MB, are a segment's too.
+#: 64 = 2,048 steps, and the state crosses HBM once a segment (backward: 2 x
+#: 16.8 MB for q's and k's float32 gradients).  On XLA's walk T and D [64,
+#: 64] of 32 heads, 2 x 16.8 MB in float32 (backward: as much again for
+#: their gradients), U, W and the scaled q and k, 4 x 33.5 MB, are a
+#: segment's too.
 DELTA_SEGMENT = 32
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -162,26 +167,31 @@ def _pairs(a, b):
                       preferred_element_type=jnp.float32)
 
 
+def _rows(g, beta):
+    """What a chunk needs of its decays and write strengths that is no wider
+    than its steps, for any number of chunks at once: ``g``/``beta``
+    ``[..., C]`` -> ``(beta [..., C], exp(gamma) [..., C], exp(gamma_C -
+    gamma) [..., C], exp(gamma_C) [...])``, float32."""
+    gf = g.astype(jnp.float32)
+    gamma = jnp.cumsum(gf, axis=-1)
+    # gamma_C - gamma_j as the sum of the steps AFTER j, as _pair_decay's
+    later = jnp.pad(gf[..., 1:], [(0, 0)] * (gf.ndim - 1) + [(0, 1)])
+    after = jnp.flip(jnp.cumsum(jnp.flip(later, -1), axis=-1), -1)
+    return (beta.astype(jnp.float32), jnp.exp(gamma), jnp.exp(after),
+            jnp.exp(gamma[..., -1]))
+
+
 def _gates(k, g, beta):
     """What a chunk needs that neither the state nor q or v enters, for any
     number of chunks at once, none of it wider than a chunk: ``k`` ``[...,
     Hk, 1, C, dk]``, ``g``/``beta`` ``[..., Hk, r, C]`` -> ``(T [..., C, C],
-    D [..., C, C], beta [..., C], exp(gamma) [..., C], exp(gamma_C - gamma)
-    [..., C], exp(gamma_C) [...])``, float32 and per value head ``[..., Hk,
-    r]``."""
-    f32 = jnp.float32
-    gf = g.astype(f32)
-    gamma = jnp.cumsum(gf, axis=-1)
+    D [..., C, C], *_rows)``, float32 and per value head ``[..., Hk, r]``."""
+    decay = _pair_decay(g.astype(jnp.float32))
+    beta, *rest = _rows(g, beta)
     step = jnp.arange(g.shape[-1])
-    decay = _pair_decay(gf)
-    beta = beta.astype(f32)
     lower = jnp.where(step[:, None] > step[None, :],
                       beta[..., None] * _pairs(k, k) * decay, 0.0)
-    # gamma_C - gamma_j as the sum of the steps AFTER j, as _pair_decay's
-    later = jnp.pad(gf[..., 1:], [(0, 0)] * (gf.ndim - 1) + [(0, 1)])
-    after = jnp.flip(jnp.cumsum(jnp.flip(later, -1), axis=-1), -1)
-    return (_unit_lower_inverse(lower), decay, beta, jnp.exp(gamma),
-            jnp.exp(after), jnp.exp(gamma[..., -1]))
+    return (_unit_lower_inverse(lower), decay, beta, *rest)
 
 
 def _prepare(q, k, v, g, beta):
@@ -246,15 +256,21 @@ def _walk_back(dS, starts, d_out, x):
 # turn it round), the state [dk, dv] float32 of each value head of the
 # block in VMEM scratch from the first chunk to the last.  A grid step reads
 # the chunk's q, k and v as they came (a key head's q and k once for its r
-# value heads) and ``_gates``' [C, C] matrices and rows, and does in VMEM
-# what ``_prepare`` does and then what ``_recur`` does: nothing [T, H, d]
-# wide in float32 crosses HBM on its way in, and backward only the gradients
-# of q and k on its way out.  Every product is float32 at HIGHEST, as
-# _prepare's and _recur's.
+# value heads), g and ``_rows``' rows, and does in VMEM what ``_gates`` does
+# past them — ``D``, ``L`` from k's pair products, ``T = (I + L)^-1`` —
+# then what ``_prepare`` does and what ``_recur`` does: nothing [T, H, d]
+# wide in float32 crosses HBM on its way in, no [C, C] matrix ever does, and
+# backward only the gradients of q and k leave at that width.  Every product
+# is float32 at HIGHEST, as _prepare's and _recur's.
 
 #: value heads a grid step (PERF.md, PR 38, has the sweep): one head's
 #: blocks are smaller than what a grid step costs beside them
 DELTA_HEADS = 8
+
+#: rows of the diagonal blocks the kernels invert by substitution before
+#: joining them level by level (PERF.md §5 has the sweep); a chunk that is
+#: no whole number of them takes the largest power of two that divides it
+KERNEL_BLOCK = 16
 
 # products of [heads, a, b] batches, the head in front
 _NN = (((2,), (1,)), ((0,), (0,)))      # a b
@@ -267,9 +283,74 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _strictly_lower(x):
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    return jnp.where(row > col, x, 0.0)
+
+
+def _decay_in_vmem(g):
+    """``D`` of the rows ``g`` ``[heads, 1, C]``, as ``_pair_decay`` takes
+    it — each span summed from its own start, masked before the
+    exponential — with the sums written as one product: ``spans[i, j] =
+    sum_t [t <= i] [j < t] g_t``.  -> ``(D, [t <= i])``, the product's
+    left factor, which the backward's pull takes again."""
+    shape = g.shape[:1] + (g.shape[-1],) * 2
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    upto = jnp.where(row >= col, 1.0, 0.0)
+    spans = _dot(upto, _strictly_lower(jnp.broadcast_to(_column(g), shape)),
+                 _NN)
+    return jnp.exp(jnp.where(row >= col, spans, -jnp.inf)), upto
+
+
+def _system(keys, decay, beta):
+    """``(beta k k^T, L)`` of one value head of each key head of a block:
+    ``keys`` = ``k k^T`` ``[heads, C, C]``, ``decay`` ``D``, ``beta`` a row
+    ``[heads, 1, C]``, going on the ROWS — ``_gates``' ``lower``."""
+    scaled = _column(beta) * keys
+    return scaled, _strictly_lower(scaled * decay)
+
+
+def _inverse_in_vmem(lower):
+    """``(I + lower)^-1`` of strictly lower ``[heads, C, C]``, exactly, as
+    ``_inverse_by_blocks`` takes it: the diagonal blocks of ``KERNEL_BLOCK``
+    rows (or the largest power of two dividing C) by forward substitution,
+    all at once, one column of every block a step; then the blocks joined
+    level by level, ``T_2s = T_s - T_s (L on the corners of the 2s blocks)
+    T_s`` — ``[[A, 0], [B, D]]^-1`` for every pair of blocks in two
+    products."""
+    shape, C = lower.shape, lower.shape[-1]
+    block = math.gcd(C, KERNEL_BLOCK)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    inverse = jnp.where(row == col, 1.0, 0.0)
+    # column j of a row's own diagonal block is the one at offset j
+    offset = col - jnp.bitwise_and(row, -block)
+    for j in range(block - 1):
+        factor = jnp.sum(jnp.where(offset == j, lower, 0.0), axis=-1,
+                         keepdims=True)
+        # row j of each block, final now, down the rows of its block
+        tiles = inverse.reshape(shape[0], C // block, block, C)
+        pivot = jnp.broadcast_to(tiles[:, :, j:j + 1], tiles.shape)
+        inverse = inverse - factor * pivot.reshape(shape)
+    size = block
+    while size < C:
+        corner = ((jnp.bitwise_and(row, -2 * size)
+                   == jnp.bitwise_and(col, -2 * size))
+                  & (jnp.bitwise_and(row, size) > jnp.bitwise_and(col, size)))
+        joined = _dot(jnp.where(corner, lower, 0.0), inverse, _NN)
+        inverse = inverse - _dot(inverse, joined, _NN)
+        size *= 2
+    return inverse
+
+
 def supported(dk, dv, chunk, dtype):
     """Whether the kernels take these shapes: the state's two widths whole
-    lane tiles, a chunk whole sublane tiles of the output's dtype."""
+    lane tiles, a chunk whole sublane tiles of the output's dtype.  Every
+    such chunk suits the inverse in VMEM (its diagonal blocks are the
+    largest power of two up to ``KERNEL_BLOCK`` dividing the chunk, 8 rows
+    or more): no chunk falls back to XLA for it."""
     return (dk % 128 == 0 and dv % 128 == 0
             and chunk % (32 // jnp.dtype(dtype).itemsize) == 0)
 
@@ -290,8 +371,8 @@ def _column(row):
     return row[:, 0, :][:, :, None]
 
 
-def _walk_kernel(s0_ref, inverse_ref, decay_ref, beta_ref, grown_ref,
-                 rest_ref, shrink_ref, q_ref, k_ref, v_ref,
+def _walk_kernel(s0_ref, g_ref, beta_ref, grown_ref, rest_ref,
+                 shrink_ref, q_ref, k_ref, v_ref,
                  o_ref, starts_ref, last_ref, s_ref):
     f32 = jnp.float32
     chunk = pl.program_id(1)
@@ -302,14 +383,16 @@ def _walk_kernel(s0_ref, inverse_ref, decay_ref, beta_ref, grown_ref,
 
     starts_ref[...] = s_ref[...]
     q, k = q_ref[...].astype(f32), k_ref[...].astype(f32)
-    pairs = _dot(q, k, _NT)
+    pairs, keys = _dot(q, k, _NT), _dot(k, k, _NT)
     for i in range(s_ref.shape[1]):          # a key head's value heads
-        S, grown = s_ref[:, i], grown_ref[:, i]
-        solve = inverse_ref[:, i] * beta_ref[:, i]
+        S, grown, beta = s_ref[:, i], grown_ref[:, i], beta_ref[:, i]
+        decay, _ = _decay_in_vmem(g_ref[:, i])
+        _, lower = _system(keys, decay, beta)
+        solve = _inverse_in_vmem(lower) * beta
         fresh = (_dot(solve, v_ref[:, i].astype(f32), _NN)
                  - _dot(_dot(solve * grown, k, _NN), S, _NN))
         o_ref[:, i] = (_column(grown) * _dot(q, S, _NN)
-                       + _dot(pairs * decay_ref[:, i], fresh, _NN)
+                       + _dot(pairs * decay, fresh, _NN)
                        ).astype(o_ref.dtype)
         s_ref[:, i] = (shrink_ref[:, i] * S
                        + _dot(k, _column(rest_ref[:, i]) * fresh, _TN))
@@ -319,12 +402,11 @@ def _walk_kernel(s0_ref, inverse_ref, decay_ref, beta_ref, grown_ref,
         last_ref[...] = s_ref[...]
 
 
-def _walk_back_kernel(ds_in_ref, starts_ref, do_ref, inverse_ref, decay_ref,
-                      beta_ref, grown_ref, rest_ref, shrink_ref, q_ref,
-                      k_ref, v_ref,
-                      d_inverse_ref, d_decay_ref, d_beta_ref, d_grown_ref,
-                      d_rest_ref, d_shrink_ref, dq_ref, dk_ref, dv_ref,
-                      ds_out_ref, ds_ref):
+def _walk_back_kernel(ds_in_ref, starts_ref, do_ref, g_ref, beta_ref,
+                      grown_ref, rest_ref, shrink_ref, q_ref, k_ref, v_ref,
+                      d_g_ref, d_beta_ref, d_grown_ref, d_rest_ref,
+                      d_shrink_ref, dq_ref, dk_ref, dv_ref, ds_out_ref,
+                      ds_ref):
     f32 = jnp.float32
     chunk = pl.program_id(1)
 
@@ -333,15 +415,17 @@ def _walk_back_kernel(ds_in_ref, starts_ref, do_ref, inverse_ref, decay_ref,
         ds_ref[...] = ds_in_ref[...]
 
     q, k = q_ref[...].astype(f32), k_ref[...].astype(f32)
-    pairs = _dot(q, k, _NT)
-    dq, dk, d_pairs = jnp.zeros_like(q), jnp.zeros_like(k), 0.0
+    pairs, keys = _dot(q, k, _NT), _dot(k, k, _NT)
+    dq, dk, d_pairs, d_keys = jnp.zeros_like(q), jnp.zeros_like(k), 0.0, 0.0
     for i in range(ds_ref.shape[1]):         # a key head's value heads
         S, dS = starts_ref[:, i], ds_ref[:, i]
-        inverse, decay = inverse_ref[:, i], decay_ref[:, i]
         beta, grown = beta_ref[:, i], grown_ref[:, i]
+        decay, upto = _decay_in_vmem(g_ref[:, i])
         grown_col, rest = _column(grown), _column(rest_ref[:, i])
         v, d_o = v_ref[:, i].astype(f32), do_ref[:, i].astype(f32)
         # the forward's chunk again, from its saved start
+        scaled, lower = _system(keys, decay, beta)
+        inverse = _inverse_in_vmem(lower)
         solve_v = inverse * beta
         solve_k = solve_v * grown
         W = _dot(solve_k, k, _NN)
@@ -352,17 +436,26 @@ def _walk_back_kernel(ds_in_ref, starts_ref, do_ref, inverse_ref, decay_ref,
         d_scores = _dot(d_o, fresh, _NT)             # of (q k^T) * D
         d_solve_k = _dot(d_W, k, _NT)
         d_solve_v = _dot(d_fresh, v, _NT) + d_solve_k * grown
-        d_inverse_ref[:, i] = d_solve_v * beta
-        d_decay_ref[:, i] = d_scores * pairs
+        # T's gradient pulled back through the inverse, -T^T dT T^T, then
+        # through L: to D, to beta on L's rows and to k k^T
+        d_lower = _strictly_lower(-_dot(
+            _dot(inverse, d_solve_v * beta, _TN), inverse, _NT))
+        d_scaled = d_lower * decay
+        # D's gradient, through the exponential and the span sums to g
+        d_spans = (d_scores * pairs + d_lower * scaled) * decay
+        d_g_ref[:, i] = jnp.sum(_strictly_lower(_dot(upto, d_spans, _TN)),
+                                axis=-1)[:, None, :]
         d_pairs += d_scores * decay
+        d_keys += _column(beta) * d_scaled
         dv_ref[:, i] = _dot(solve_v, d_fresh, _TN).astype(dv_ref.dtype)
         dq += grown_col * do_S
         dk += _dot(rest * fresh, dS, _NT) + _dot(solve_k, d_W, _TN)
         # of the factors on T's columns: sums down the columns, rows as
         # they are; of those on a batch's rows: sums along the rows, laid
         # out as rows
-        d_beta_ref[:, i] = jnp.sum(d_solve_v * inverse, axis=1,
-                                   keepdims=True)
+        d_beta_ref[:, i] = (
+            jnp.sum(d_solve_v * inverse, axis=1, keepdims=True)
+            + jnp.sum(d_scaled * keys, axis=-1)[:, None, :])
         d_grown_ref[:, i] = (
             jnp.sum(d_solve_k * solve_v, axis=1, keepdims=True)
             + jnp.sum(q * do_S, axis=-1)[:, None, :])
@@ -371,7 +464,9 @@ def _walk_back_kernel(ds_in_ref, starts_ref, do_ref, inverse_ref, decay_ref,
         d_shrink_ref[:, i] = jnp.sum(S * dS, axis=1, keepdims=True)
         ds_ref[:, i] = (shrink_ref[:, i] * dS + _dot(q, grown_col * d_o, _TN)
                         - _dot(W, d_fresh, _TN))
-    dq, dk = dq + _dot(d_pairs, k, _NN), dk + _dot(d_pairs, q, _TN)
+    dq = dq + _dot(d_pairs, k, _NN)
+    dk = (dk + _dot(d_pairs, q, _TN) + _dot(d_keys, k, _NN)
+          + _dot(d_keys, k, _TN))
     dq_ref[...], dk_ref[...] = dq, dk
 
     @pl.when(chunk == pl.num_programs(1) - 1)
@@ -383,11 +478,11 @@ _WALK_ORDER = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"))
 
 
-def _kernel_operands(S, x, gates, reverse):
+def _kernel_operands(S, x, rows, reverse):
     """What both kernels read of a segment, head-major, with its block
-    specs: ``_gates``' results and q, k, v ``[chunks, key heads, ...]``, a
-    block of key heads (each with its ``r`` value heads) a grid step."""
-    q, k, v, _, _ = x
+    specs: g, ``_rows``' results and q, k, v ``[chunks, key heads, ...]``,
+    a block of key heads (each with its ``r`` value heads) a grid step."""
+    q, k, v, g, _ = x
     chunks, r, (C, dv), dk = v.shape[0], v.shape[3], v.shape[-2:], q.shape[-1]
     K = math.prod(S.shape[:2])                       # rows x key heads
     kb = math.gcd(K, max(1, DELTA_HEADS // r))
@@ -397,16 +492,16 @@ def _kernel_operands(S, x, gates, reverse):
     per_key_head = pl.BlockSpec((None, kb, C, dk),
                                 lambda h, c: (at(c), h, 0, 0))
     state = pl.BlockSpec((kb, r, dk, dv), lambda h, c: (h, 0, 0, 0))
-    inverse, decay, *rows, shrink = gates
+    *steps, shrink = rows
     heads = lambda x, *dims: x.reshape(chunks, K, r, *dims)
-    operands = [heads(inverse, C, C), heads(decay, C, C),
-                *(heads(row, 1, C) for row in rows),
+    operands = [*(heads(row, 1, C) for row in (g.astype(jnp.float32),
+                                               *steps)),
                 # a scalar a head rides as a row of the state's width
                 heads(jnp.broadcast_to(shrink[..., None], shrink.shape
                                        + (dv,)), 1, dv),
                 q.reshape(chunks, K, C, dk), k.reshape(chunks, K, C, dk),
                 heads(v, C, dv)]
-    specs = [per_head(C, C)] * 2 + [per_head(1, C)] * 3 + [
+    specs = [per_head(1, C)] * 4 + [
         per_head(1, dv), per_key_head, per_key_head, per_head(C, dv)]
     return (operands, specs, per_head, per_key_head, state,
             (chunks, K, kb, r, C, dk, dv))
@@ -414,9 +509,9 @@ def _kernel_operands(S, x, gates, reverse):
 
 def _walk_pallas(S, x, out_dtype, interpret):
     """``_walk`` with the chunks inside one kernel call."""
-    _, k, _, g, beta = x
+    _, _, _, g, beta = x
     operands, specs, per_head, _, state, dims = _kernel_operands(
-        S, x, _gates(k, g, beta), reverse=False)
+        S, x, _rows(g, beta), reverse=False)
     chunks, K, kb, r, C, dk, dv = dims
     f32 = jnp.float32
     out, starts, last = pl.pallas_call(
@@ -436,40 +531,40 @@ def _walk_pallas(S, x, out_dtype, interpret):
 
 
 def _walk_back_pallas(dS, starts, d_out, x, interpret):
-    """``_walk_back`` with the chunks inside one kernel call: it hands out
-    the gradients of q and v, of ``_gates``' results — pulled back to k, g
-    and beta here — and what reaches k past them."""
+    """``_walk_back`` with the chunks inside one kernel call: it forms each
+    chunk's ``D`` and ``T`` again in VMEM and pulls their gradients back
+    through the inverse, ``L`` and the span sums there, and hands out the
+    gradients of q, k and v whole, g's part through ``D``, and those of
+    ``_rows``' results, which XLA pulls back to g and beta."""
     q, k, v, g, beta = x
-    gates, pull_gates = jax.vjp(_gates, k, g, beta)
+    rows, pull_rows = jax.vjp(_rows, g, beta)
     operands, specs, per_head, per_key_head, state, dims = _kernel_operands(
-        dS, x, gates, reverse=True)
+        dS, x, rows, reverse=True)
     chunks, K, kb, r, C, dk, dv = dims
     f32 = jnp.float32
     shaped = lambda *dims: jax.ShapeDtypeStruct((chunks, K, r, *dims), f32)
-    *d_gates, d_shrink, dq, dk_, dv_, dS = pl.pallas_call(
+    d_g, *d_rows, d_shrink, dq, dk_, dv_, dS = pl.pallas_call(
         _walk_back_kernel,
-        out_shape=(shaped(C, C), shaped(C, C), shaped(1, C), shaped(1, C),
-                   shaped(1, C), shaped(1, dv),
+        out_shape=(*[shaped(1, C)] * 4, shaped(1, dv),
                    jax.ShapeDtypeStruct((chunks, K, C, dk), f32),
                    jax.ShapeDtypeStruct((chunks, K, C, dk), f32),
                    jax.ShapeDtypeStruct((chunks, K, r, C, dv), v.dtype),
                    jax.ShapeDtypeStruct((K, r, dk, dv), f32)),
         grid=(K // kb, chunks),
         in_specs=[state, per_head(dk, dv), per_head(C, dv), *specs],
-        out_specs=(per_head(C, C), per_head(C, C), per_head(1, C),
-                   per_head(1, C), per_head(1, C), per_head(1, dv),
+        out_specs=(*[per_head(1, C)] * 4, per_head(1, dv),
                    per_key_head, per_key_head, per_head(C, dv), state),
         scratch_shapes=[pltpu.VMEM((kb, r, dk, dv), f32)],
         compiler_params=_WALK_ORDER,
         interpret=interpret,
     )(dS.reshape(K, r, dk, dv), starts.reshape(chunks, K, r, dk, dv),
       d_out.reshape(chunks, K, r, C, dv), *operands)
-    d_gates = [d.reshape(m.shape) for d, m in zip(d_gates, gates)]
-    d_shrink = jnp.sum(d_shrink, axis=(-2, -1)).reshape(gates[-1].shape)
-    gk, gg, gbeta = pull_gates((*d_gates, d_shrink))
+    d_rows = [d.reshape(m.shape) for d, m in zip(d_rows, rows)]
+    d_shrink = jnp.sum(d_shrink, axis=(-2, -1)).reshape(rows[-1].shape)
+    gg, gbeta = pull_rows((*d_rows, d_shrink))
     return dS.reshape(starts.shape[1:]), (
-        dq.reshape(q.shape), gk.astype(f32) + dk_.reshape(k.shape),
-        dv_.reshape(v.shape), gg, gbeta)
+        dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+        gg + d_g.reshape(g.shape), gbeta)
 
 
 def _layout(T, chunk):

@@ -9,7 +9,7 @@ THE SHARES — the routed parts of four 16-expert shares, with attention and
 the residual counted once, are the uncut reference's whole layer — and the
 pins that keep the older cells' programs: the gradient jaxprs of an Ouro-,
 a Kimi- and a Qwen3-Next-shaped call and of the latent and delta stacks are
-the parent's letter for letter.  (The whole model:
+the pinned ones letter for letter.  (The whole model:
 tests/test_window_moe_model.py; through the engine:
 tests/test_window_moe_engine.py.)"""
 
@@ -408,13 +408,17 @@ def stack(kind):
 
 #: sha256 (first 16 hex digits) of the normalised jaxpr as the PARENT of
 #: PR 39 printed it (jax 0.9.0): a call with its defaults is the program the
-#: ``ouro``, ``kimi`` and ``qwen3-next`` cells were measured on
+#: ``ouro``, ``kimi`` and ``qwen3-next`` cells were measured on.  The delta
+#: stack's is pinned anew since its XLA walk makes the rule's per-step rows
+#: apart from its [C, C] gates (the kernels' path reads the rows alone): the
+#: same operations in another order, its loss and gradients the earlier
+#: program's to the bit on the CPU
 PARENT_JAXPRS = {
     "ouro_call": ("5bd25f59cb61b687", ouro_call),
     "kimi_call": ("f4e94254d5270240", lambda: expert_call("kimi")),
     "qwen_call": ("12dd44c2783a4734", lambda: expert_call("qwen")),
     "latent_stack": ("a96d652d920385cf", lambda: stack("latent")),
-    "delta_stack": ("3f03b8f100b68326", lambda: stack("delta")),
+    "delta_stack": ("df45b1f5a296b19d", lambda: stack("delta")),
 }
 
 
