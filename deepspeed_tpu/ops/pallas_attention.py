@@ -290,6 +290,18 @@ def _tile_mask(s, mask, causal, i, j, qt, kt, window=None):
     return s
 
 
+def _tile_visible(i, j, qt, kt, window=None, n_tiles=None):
+    """Whether some (query, key) pair of the (query tile i, kv tile j) pair
+    of a causal call is visible: not past the diagonal and, under a
+    ``window``, not out of it — where an offset grid's index may also name
+    no tile of the ``n_tiles`` at all.  Python ints or traced scalars."""
+    if window is not None:
+        return ((j >= 0) & (i <= n_tiles - 1)
+                & (j * kt <= (i + 1) * qt - 1)
+                & ((j + 1) * kt - 1 > i * qt - window))
+    return j * kt <= (i + 1) * qt - 1
+
+
 def _window_tiles(window, tile, n_tiles):
     """Length of the kv axis of a windowed call's grid: the kv tiles one
     query tile can see (query and kv tiles of one size), e.g. 2 of the 16 at
@@ -301,17 +313,10 @@ def _window_tiles(window, tile, n_tiles):
 
 def _when_visible(update, causal, window, i, j, qt, kt, n_tiles):
     """Run ``update`` unless the (query tile i, kv tile j) pair is masked
-    whole: past the causal diagonal, out of the window, or — a windowed
-    grid's offset index at the sequence's ends — no tile at all."""
-    if window is not None:
-        pl.when((j >= 0) & (i <= n_tiles - 1)
-                & (j * kt <= (i + 1) * qt - 1)
-                & ((j + 1) * kt - 1 > i * qt - window))(update)
-    elif causal:
-        # a tile whose first kv position is past the last query position is
-        # fully masked: skip its compute entirely (GPT-style models pay for
-        # only the lower-triangular half of the tile grid)
-        pl.when(j * kt <= (i + 1) * qt - 1)(update)
+    whole (``_tile_visible``): GPT-style models pay for only the
+    lower-triangular half of the tile grid."""
+    if causal:
+        pl.when(_tile_visible(i, j, qt, kt, window, n_tiles))(update)
     else:
         update()
 
@@ -586,6 +591,32 @@ def _kv_spec(x, gb, group, kt, tile_of):
                         lambda g, a, b: (g * gb // group, tile_of(a, b), 0))
 
 
+def _kv_tile(causal, window, nk):
+    """``(i, j) -> kv tile`` of a grid whose kv axis (``nk`` long) is
+    innermost under query tile i: the forward's and the dQ kernel's."""
+    if window is not None:
+        # step j of the kv axis is tile i - (nk - 1) + j; before the
+        # sequence's start the first tile stays parked and the kernel skips
+        return lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
+    if causal:
+        # past the diagonal the diagonal tile stays parked: the pipeline
+        # fetches no hidden tile, and the kernel skips it
+        return lambda i, j: jnp.minimum(j, i)
+    return lambda i, j: j
+
+
+def _q_tile(causal, window, n_tiles):
+    """``(j, i) -> query tile`` of a grid whose query axis is innermost
+    under kv tile j: the fused and the dK/dV kernel's."""
+    if window is not None:
+        return lambda j, i: jnp.minimum(j + i, n_tiles - 1)
+    if causal:
+        # before the diagonal the diagonal tile is fetched and stays
+        # parked until it is visited
+        return lambda j, i: jnp.maximum(i, j)
+    return lambda j, i: i
+
+
 def _check_window(window, causal):
     if window is not None and not (causal and window >= 1):
         raise ValueError(f"a sliding window (got {window!r}) is a bound on "
@@ -607,12 +638,7 @@ def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None):
     scale = 1.0 / (d ** 0.5)
     qg, kg, vg = _fold_gtd(q), _fold_gtd(k), _fold_gtd(v)
     maskg = _mask_gtd(attn_mask, B, T, n)
-    if window is None:
-        kv_tile = lambda i, j: j
-    else:
-        # step j of the kv axis is tile i - (nk - 1) + j; before the
-        # sequence's start the first tile stays parked and the kernel skips
-        kv_tile = lambda i, j: jnp.maximum(i - (nk - 1) + j, 0)
+    kv_tile = _kv_tile(causal, window, nk)
     q_spec = pl.BlockSpec((gb, qt, d), lambda g, i, j: (g, i, 0))
     o_spec = pl.BlockSpec((gb, qt, dv), lambda g, i, j: (g, i, 0))
     k_spec = _kv_spec(kg, gb, k_group, kt, kv_tile)
@@ -649,8 +675,9 @@ def stream_attention(q, k, v, attn_mask, causal: bool = False,
     ``gk`` (``gv``) consecutive query heads share a key (value) head and a
     value head may be wider than a key head; attn_mask: [B, T] float (1 =
     attend).  ``window``: key s is visible to query t iff ``t - window < s
-    <= t`` (needs ``causal``); tiles out of the window are neither computed
-    nor fetched.  Returns [B, T, n, dv] context; callers gate on
+    <= t`` (needs ``causal``).  A causal call's tiles the band hides — past
+    the diagonal, out of the window — are neither computed nor fetched.
+    Returns [B, T, n, dv] context; callers gate on
     ``stream_supported(T, d)``."""
     B, T, n, d = q.shape
     o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window)
@@ -774,12 +801,8 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
                     axis=-1)[:, None, :]                    # [G, 1, T]
     windowed = ({} if window is None
                 else {"window": window, "n_tiles": n_tiles})
-    if window is None:
-        q_tile = lambda j, i: i
-        kv_tile = lambda i, j: j
-    else:
-        q_tile = lambda j, i: jnp.minimum(j + i, n_tiles - 1)
-        kv_tile = lambda i, j: jnp.maximum(i - (inner - 1) + j, 0)
+    q_tile = _q_tile(causal, window, n_tiles)
+    kv_tile = _kv_tile(causal, window, inner)
     # grid (G, kv tile, query tile) — query innermost, kv parked
     kv_spec_o = pl.BlockSpec((gb, kt, d), lambda g_, j, i: (g_, j, 0))
     dv_spec_o = pl.BlockSpec((gb, kt, dv_), lambda g_, j, i: (g_, j, 0))

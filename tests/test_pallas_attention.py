@@ -727,6 +727,185 @@ def test_window_and_shared_heads_are_refused_where_not_built():
     assert L.attention_plan(128, 12, 64, True) == ("xla", "xla")
 
 
+# ---------- the causal band: hidden tiles neither computed nor fetched
+
+def _any_pair_seen(i, j, tile, T, window):
+    """Brute force: whether some (query, key) pair of query tile i and kv
+    tile j inside a sequence of T is visible."""
+    t = np.arange(i * tile, (i + 1) * tile)[:, None]
+    s = np.arange(j * tile, (j + 1) * tile)[None, :]
+    seen = (t >= 0) & (t < T) & (s >= 0) & (s < T) & (s <= t)
+    if window is not None:
+        seen &= s > t - window
+    return bool(seen.any())
+
+
+@pytest.mark.parametrize("T_len,tile,window", [
+    (2048, 256, None), (2048, 512, None), (4096, 512, 1024),
+    (4096, 512, 640), (3072, 512, 300), (2048, 256, 1), (2048, 512, 2048)],
+    ids=["causal-256", "causal-512", "window-x2-tiles", "window-640",
+         "window-300", "window-1", "window-whole-seq"])
+def test_tile_visible_agrees_with_every_pair(T_len, tile, window):
+    """``_tile_visible`` against every (query, key) pair: visible where any
+    pair is; under a window also at an offset grid's indexes past either
+    end of the sequence, where no tile is.  A window that is and is not a
+    multiple of the tile."""
+    n = T_len // tile
+    span = range(-2, n + 2) if window is not None else range(n)
+    for i in span:
+        for j in span:
+            assert bool(pattn._tile_visible(i, j, tile, tile, window, n)) \
+                == _any_pair_seen(i, j, tile, T_len, window), (i, j)
+
+
+def _fetches(index_of, visible_at, outer, inner):
+    """Walk a grid (outer, inner) in the pipeline's order: for each block
+    it fetches (a step whose block index differs from the step before),
+    whether a visible step uses that block before the next fetch."""
+    runs, last = [], None
+    for a in range(outer):
+        for b in range(inner):
+            idx = int(index_of(a, b))
+            if idx != last:
+                runs.append(False)
+                last = idx
+            runs[-1] = runs[-1] or bool(visible_at(a, b))
+    return runs
+
+
+@pytest.mark.parametrize("T_len,window,visible", [
+    (16384, None, 528), (16384, 4096, 252), (8192, None, 136),
+    (4096, None, 36), (1024, None, 3), (8192, 512, 31)],
+    ids=["nope-16k", "window4096-16k", "mla-8k", "looped-4k", "gpt2-1k",
+         "window512-8k"])
+def test_the_cells_calls_fetch_no_hidden_tile(T_len, window, visible):
+    """The cells' causal calls in tiles of 512 — the NoPE call sees 528
+    (query tile, kv tile) pairs of its 1,024 grid steps, the window of
+    4,096 252 of 288: the forward's kv walk and the fused backward's query
+    walk fetch a block only where a visible step uses it.  Without the
+    clamp a causal grid fetches the hidden tiles too."""
+    n = T_len // 512
+    nk = pattn._window_tiles(window, 512, n)
+    seen = lambda i, j: pattn._tile_visible(i, j, 512, 512, window, n)
+    if window is None:
+        kv_at, q_at = (lambda i, j: j), (lambda j, i: i)
+    else:
+        kv_at, q_at = (lambda i, j: i - (nk - 1) + j), (lambda j, i: j + i)
+    fwd_seen = lambda i, j: seen(i, kv_at(i, j))
+    bwd_seen = lambda j, i: seen(q_at(j, i), j)
+    assert sum(bool(fwd_seen(i, j)) for i in range(n)
+               for j in range(nk)) == visible
+    assert sum(bool(bwd_seen(j, i)) for j in range(n)
+               for i in range(nk)) == visible
+    assert all(_fetches(pattn._kv_tile(True, window, nk), fwd_seen, n, nk))
+    assert all(_fetches(pattn._q_tile(True, window, n), bwd_seen, n, nk))
+    if window is None and n > 1:
+        assert not all(_fetches(lambda i, j: j, fwd_seen, n, nk))
+        assert not all(_fetches(lambda j, i: i, bwd_seen, n, nk))
+
+
+def _pad_in_a_whole_tile(T_len):
+    """Ones but for keys 100..139: inside kv tile 0, which query tile 1
+    sees whole."""
+    m = np.ones((1, T_len), np.float32)
+    m[:, 100:140] = 0.0
+    return jnp.asarray(m)
+
+
+MASKING_CASES = [(masked, group, window, "auto")
+                 for masked in (False, True) for group in (1, 2, 7)
+                 for window in (None, 640)] + [
+    (True, 2, None, "split"), (False, 7, 640, "split")]
+
+
+@pytest.mark.parametrize(
+    "masked,group,window,mode", MASKING_CASES,
+    ids=["-".join(map(str, c)) for c in MASKING_CASES])
+def test_stream_parity_where_hidden_cut_and_whole_tiles_meet(
+        monkeypatch, masked, group, window, mode):
+    """T 1,024 in tiles of 512 (one hidden tile pair, two the diagonal
+    cuts, one wholly below it; a window of 640 keeps all three visible
+    ones and cuts the last): forward and gradients against the XLA path,
+    with no padding and with a mask whose zeros lie inside the tile below
+    the diagonal; 1, 2 and 7 query heads a shared head."""
+    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    q, k, v, weight, mask = _window_case(1024, 2 * group, 2, 2, 64, 64,
+                                         seed=group)
+    if masked:
+        mask = _pad_in_a_whole_tile(1024)
+    got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
+        q, k, v, mask, True, True, window), q, k, v, weight)
+    want = _value_and_grads(lambda q, k, v: pattn.xla_attention(
+        q, k, v, mask, True, window=window)[0], q, k, v, weight)
+    _assert_same(got, want)
+
+
+#: operands each kernel fetches by a kv tile with its query walk (or the
+#: other way round) innermost: (operand numbers, which way)
+WALKS = {"_stream_fwd_kernel": ((1, 2), "kv"),
+         "_stream_dq_kernel": ((1, 2), "kv"),
+         "_stream_bwd_fused_kernel": ((0, 4, 5, 6), "q"),
+         "_stream_dkv_kernel": ((0, 4, 5, 6), "q")}
+
+
+@pytest.mark.parametrize("mode", ["fused", "split"])
+def test_a_hidden_step_keeps_the_parked_tile(monkeypatch, mode):
+    """Causal, T 2,048 in four tiles: where a step of the grid is
+    hidden the forward's and the dQ kernel's k and v maps stay on the
+    diagonal tile, the fused and dK/dV kernels' q, dO, lse and delta maps
+    on the tile the walk is about to see.  Every block the pipeline
+    fetches is then one a visible step uses: no hidden tile is fetched.
+    The jaxpr digests below print no index map, so this test pins them."""
+    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    n = 4
+    q = jnp.zeros((1, 512 * n, 2, 64), jnp.bfloat16)
+    mask = jnp.ones((1, 512 * n), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(pattn.stream_attention(
+            q, k, v, mask, True, True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q)
+    calls = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    steps = [(a, b) for a in range(n) for b in range(n)]
+    walked = []
+    for eqn in calls:
+        kernel = eqn.params["jaxpr"].debug_info.func_name
+        ops, inner = WALKS[kernel]
+        walked.append(kernel)
+        for op in ops:
+            index_map = eqn.params["grid_mapping"].block_mappings[op] \
+                .index_map_jaxpr
+            # the tile is the second block index of q, k, v and dO, the
+            # third of the [G, 1, T] rows
+            axis = 1 if op in (0, 1, 2, 4) else 2
+            idx = [int(jax.core.eval_jaxpr(index_map.jaxpr, index_map.consts,
+                                           0, a, b)[axis]) for a, b in steps]
+            # (outer tile a, inner step b): the forward's and dQ's outer
+            # axis is the query tile, the backward's the kv tile
+            want = [min(a, b) if inner == "kv" else max(a, b)
+                    for a, b in steps]
+            assert idx == want, (kernel, op, idx)
+            # every block fetched (a run of one index) is used by a visible
+            # step before the next fetch: no hidden tile is fetched
+            visible = [b <= a if inner == "kv" else b >= a for a, b in steps]
+            runs = [[visible[0]]]
+            for k in range(1, len(steps)):
+                if idx[k] != idx[k - 1]:
+                    runs.append([])
+                runs[-1].append(visible[k])
+            assert all(any(run) for run in runs), (kernel, op, idx)
+            assert len(runs) <= n * (n + 1) // 2 < n * n
+    assert sorted(walked) == sorted(
+        ["_stream_fwd_kernel", "_stream_bwd_fused_kernel"] if mode == "fused"
+        else ["_stream_fwd_kernel", "_stream_dkv_kernel",
+              "_stream_dq_kernel"])
+    # no band, nothing parked: every step its own tile
+    assert [pattn._kv_tile(False, None, n)(a, b) for a, b in steps] == \
+        [b for _, b in steps]
+    assert [pattn._q_tile(False, None, n)(a, b) for a, b in steps] == \
+        [b for _, b in steps]
+
+
 def _normalised(jaxpr):
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     return re.sub(r" at [^\s:]+:\d+", " at FILE", text)
