@@ -21,10 +21,9 @@ auto-detected from the device kind when unset), BENCH_SWEEP=1 for a
 batch x remat sweep (rows on stderr, best on stdout), BENCH_OUT=<path> to
 also write the JSON line to a file (committed sweep artifacts),
 BENCH_PP_SWEEP=1 with BENCH_PP_SCHEDULES=gpipe,1f1b for the pipeline
-schedule sweep, BENCH_ATTN_SWEEP=1 for the attention-kernel sweep,
-BENCH_HEAD=1 for the MLM-head sparse-vs-dense microbench (CPU-safe),
-BENCH_SERVE=1 for the serving bench (continuous vs static batching,
-tokens/s/chip + p50/p99 TTFT/ITL -> bench_serve.json),
+schedule sweep, BENCH_HEAD=1 for the MLM-head sparse-vs-dense
+microbench (CPU-safe), BENCH_SERVE=1 for the serving bench (continuous
+vs static batching, tokens/s/chip + p50/p99 TTFT/ITL -> bench_serve.json),
 BENCH_RESUME=1 for the time-to-first-step-after-relaunch bench (serial vs
 parallel streaming restore + cold vs warm persistent compile cache;
 CPU-safe; see bench_resume.json).  Every row names the ``platform``,
@@ -154,7 +153,7 @@ def run_bench_diff(old_path, new_path, threshold=0.10, strict=False):
 def _emit(obj):
     """Print the one-line JSON, stamped with the device it ran on; also
     write it to $BENCH_OUT when set (the committed-artifact path, e.g.
-    bench_attn_sweep.json)."""
+    bench_pp_sweep.json)."""
     import jax
     dev = jax.devices()[0]
     obj = {**obj, "platform": dev.platform, "device_kind": dev.device_kind,
@@ -619,65 +618,6 @@ def run_pipeline_sweep(steps=4, warmup=2):
                     "config)."
                     % (head_ratio, pp_max, pp_max, ratio))}
     _emit(out)
-    return 0
-
-
-def run_attention_sweep(steps=10, warmup=3):
-    """GPT-2 long-sequence throughput with the streaming Pallas attention
-    kernel vs the XLA einsum path (VERDICT r2 #7).  The dispatch env is
-    read at trace time, so each mode builds its own engine.  Rows on
-    stderr, one JSON summary on stdout."""
-    import jax
-
-    import deepspeed_tpu
-    from deepspeed_tpu.models import GPT2
-
-    if jax.default_backend() != "tpu":
-        raise RuntimeError(
-            "BENCH_ATTN_SWEEP needs a TPU backend: the kernel dispatch in "
-            "models/layers.py is TPU-gated, so off-TPU both rows would run "
-            "the XLA path and the reported speedup would be meaningless")
-    T = int(os.environ.get("BENCH_SEQ", "1024"))
-    B = int(os.environ.get("BENCH_BATCH", "8"))
-    rng = np.random.default_rng(0)
-    toks = rng.integers(0, 50304, size=(B, T)).astype(np.int32)
-    labels = np.roll(toks, -1, axis=1)
-    labels[:, -1] = -1
-
-    rows = []
-    # "0" = XLA einsum path, "1" = streaming kernel FORCED (the auto
-    # dispatch would silently fall back to XLA below STREAM_AUTO_MIN and
-    # the "speedup" would compare XLA with itself)
-    for mode in ("0", "1"):
-        os.environ["DSTPU_FUSED_ATTN"] = mode
-        model = GPT2.from_size("tiny", vocab_size=50304, max_seq_len=T,
-                               num_layers=12, hidden_size=768, num_heads=12)
-        engine, _, _, _ = deepspeed_tpu.initialize(
-            config={"train_batch_size": B, "steps_per_print": 10 ** 9,
-                    "optimizer": {"type": "Adam", "params": {"lr": 1e-4}},
-                    "bf16": {"enabled": True},
-                    "activation_checkpointing": {"enabled": True,
-                                                 "policy": "selective"}},
-            model=model,
-            model_parameters=model.init_params(jax.random.PRNGKey(0)))
-        for _ in range(warmup):
-            loss = engine.train_batch((toks, labels))
-        float(loss)
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            loss = engine.train_batch((toks, labels))
-        float(loss)
-        dt = (time.perf_counter() - t0) / steps
-        rows.append({"attn": "xla" if mode == "0" else "stream-pallas",
-                     "ms_per_step": round(dt * 1000, 1),
-                     "samples_per_sec": round(B / dt, 2)})
-        os.environ.pop("DSTPU_FUSED_ATTN", None)
-        print(f"attn={rows[-1]['attn']}: {rows[-1]['ms_per_step']} ms/step",
-              file=sys.stderr)
-    speedup = rows[0]["ms_per_step"] / rows[1]["ms_per_step"]
-    _emit({"metric": f"gpt2_seq{T}_attention_kernel_speedup",
-           "value": round(speedup, 3), "unit": "x vs XLA path",
-           "rows": rows})
     return 0
 
 
@@ -2462,9 +2402,6 @@ def main():
         return run_multistep_bench()
     if os.environ.get("BENCH_DATA", "0") == "1":
         return run_data_bench()
-    if os.environ.get("BENCH_ATTN_SWEEP", "0") == "1":
-        return run_attention_sweep(
-            steps=int(os.environ.get("BENCH_STEPS", "10")))
 
     # the headline is a device metric: no TPU, no number (a CPU run under
     # this metric's name is how a 4.65 samples/s row once sat beside chip

@@ -46,6 +46,7 @@ import math
 import os
 import sys
 import time
+from unittest import mock
 
 import numpy as np
 
@@ -257,17 +258,22 @@ def leg1_seq128(sz, device):
 
 @contextlib.contextmanager
 def stream_bwd_mode(mode):
-    """DSTPU_STREAM_BWD for the length of one traced call."""
-    os.environ["DSTPU_STREAM_BWD"] = mode
-    try:
+    """The streaming backward ``mode`` ("auto" as planned, "fused" under
+    the VMEM limit it needs, "split") for the length of one traced call."""
+    from deepspeed_tpu.ops import pallas_attention as pattn
+
+    plan = pattn.stream_bwd_plan
+    forced = {"auto": plan,
+              "fused": lambda gb, T, d, itemsize, cap: plan(
+                  gb, T, d, itemsize, math.inf),
+              "split": lambda *shape: ("split", None)}[mode]
+    with mock.patch.object(pattn, "stream_bwd_plan", forced):
         yield
-    finally:
-        del os.environ["DSTPU_STREAM_BWD"]
 
 
 def kernel_parity(shape, causal, interpret):
     """Streaming kernel vs ``xla_attention``: forward, and the backward
-    under both DSTPU_STREAM_BWD modes.  Returns the relative errors."""
+    fused and split.  Returns the relative errors."""
     import jax
     import jax.numpy as jnp
 
@@ -288,7 +294,7 @@ def kernel_parity(shape, causal, interpret):
             return (out,) + pull(w)
         return [np.asarray(x, np.float32) for x in jax.jit(fwd_bwd)(q, k, v)]
 
-    want = run(lambda q, k, v: pattn.xla_attention(q, k, v, mask, causal)[0])
+    want = run(lambda q, k, v: pattn.xla_attention(q, k, v, mask, causal))
     errs = {}
     for mode in ("fused", "split"):
         with stream_bwd_mode(mode):
@@ -372,9 +378,9 @@ def leg2_seq512(sz, device, on_tpu):
 
     plan = layers.attention_plan(512, 16, 64, False)
     log(f"  attention_plan(512, 16, 64, causal=False) = {plan}")
-    if on_tpu and plan != ("stream", "stream"):
-        raise RuntimeError(f"seq-512 attention plan is {plan}, expected "
-                           f"('stream', 'stream')")
+    if on_tpu and plan != "stream":
+        raise RuntimeError(f"seq-512 attention plan is {plan!r}, expected "
+                           f"'stream'")
 
     gas, micro = 2, sz["micro512"]
     model, engine = build_engine(sz["size"], 512, micro, gas, [device])
@@ -390,7 +396,7 @@ def leg2_seq512(sz, device, on_tpu):
     log_train_batch("train_batch", fused)
     log(f"  train_batch losses (last micro-batch): {fused['losses']}")
     log(f"  peak_bytes_in_use (process so far): {peak_bytes(device)}")
-    return {"kernel_parity": parity, "attention_plan": list(plan),
+    return {"kernel_parity": parity, "attention_plan": plan,
             "pallas_custom_calls": calls, "train_batch": fused,
             "peak_bytes_in_use": peak_bytes(device)}
 

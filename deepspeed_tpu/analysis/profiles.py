@@ -28,7 +28,7 @@ and a TPU kind with no row is an error, never a default.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,12 +50,6 @@ class BackendProfile:
     #: published peak dense bf16 TFLOP/s per device — ``bench.py``'s MFU
     #: denominator
     peak_bf16_tflops: float
-    #: streaming-attention auto-dispatch thresholds swept on this
-    #: generation, ``(fwd_min, bwd_min)`` tokens per mask kind
-    #: (``models/layers.py stream_auto_min``); None = never swept here,
-    #: the conservative defaults in ``layers`` apply
-    stream_attn_min_causal: Optional[Tuple[int, int]] = None
-    stream_attn_min_noncausal: Optional[Tuple[int, int]] = None
     #: the most scoped VMEM one Pallas kernel may ask Mosaic for, MiB
     #: (``vmem_limit_bytes``; ``ops/pallas_attention.py stream_bwd_plan``
     #: is the one asker).  A share of the core's physical VMEM that leaves
@@ -117,11 +111,6 @@ PROFILES: Dict[str, BackendProfile] = {
     "v5e-8": BackendProfile(
         name="v5e-8", hbm_gib=14.75, ici_gibps=45.0, dcn_gibps=6.25,
         peak_bf16_tflops=197.0,
-        # non-causal: XLA wins at 128, the kernel at 512 (the removed
-        # BENCH_r04/r05 sweeps; not re-measured on the current code).
-        # fwd == bwd until a direction-split sweep lands
-        stream_attn_min_causal=(512, 512),
-        stream_attn_min_noncausal=(512, 512),
         kernel_vmem_mib=_V5E_KERNEL_VMEM_MIB),
     "v5p-8": BackendProfile(
         name="v5p-8", hbm_gib=93.75, ici_gibps=150.0, dcn_gibps=6.25,

@@ -36,122 +36,6 @@ from deepspeed_tpu.observability import scopes as S
 from deepspeed_tpu.ops.remat_names import MIXER_IN, QKV
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, SEQ_AXIS
 
-# Pallas attention dispatch (DSTPU_FUSED_ATTN = "auto" | "1" | "0").
-# Measured on a v5e chip, END-TO-END training step (12-layer model, under
-# a selective remat that replayed the kernel's forward in the backward
-# pass — today's keeps its output, PERF.md PR 27, so these ratios are
-# upper bounds; bench_attn_sweep.json r4/r5):
-#   GPT-2 causal:   kernel 1.127x @128 (whole-tile — streaming needs a
-#                   256 tile), 1.18x @512, 1.87x @1024, 2.44x @2048,
-#                   3.21x @4096
-#   BERT-large 128: whole-tile kernel 0.92x (375.6 vs 409.2 samples/s,
-#                   non-causal, 16 heads) -> XLA below the threshold
-# "auto" (default) picks per DIRECTION and per KIND: the streaming
-# online-softmax kernel from the calibrated threshold up, the whole-tile
-# kernel for causal shapes from BLOCK_AUTO_MIN_CAUSAL (the seq-128 causal
-# sweep row the old threshold left on the table — VERDICT r5 weak #3),
-# XLA otherwise; "1" forces a kernel wherever one supports the shape; "0"
-# disables both.  Causal thresholds are lower: both kernels skip (or never
-# compute) fully-masked KV tiles, which the XLA einsum path cannot.
-# Forward and backward resolve INDEPENDENTLY (ops/pallas_attention.py
-# dispatch_attention): the backward runs ~2.5x the forward's matmul passes
-# per tile pair, so its kernel crossover sits lower on DMA-bound shapes.
-#
-# The crossover is chip-generation dependent.  Resolution order per
-# (kind, direction):
-#   1. DSTPU_STREAM_ATTN_MIN_CAUSAL_FWD / _BWD (most specific)
-#   2. DSTPU_STREAM_ATTN_MIN_CAUSAL (causal, both directions — what
-#      calibrate() prints, since it measures the causal crossover)
-#   3. DSTPU_STREAM_ATTN_MIN_FWD / _BWD (both kinds, one direction)
-#   4. DSTPU_STREAM_ATTN_MIN (applies everywhere; a causal-measured value
-#      here would force the kernel on non-causal shapes where XLA wins —
-#      prefer the causal-scoped pin)
-#   5. the attached chip's row in analysis/profiles.py
-#      (BackendProfile.stream_attn_min_*; extend as sweeps run on new
-#      generations: BENCH_ATTN_SWEEP=1 BENCH_SEQ=<n> python bench.py)
-#   6. the defaults below, for a chip whose row carries no sweep
-# `ops.pallas_attention.calibrate_stream_threshold()` measures the
-# crossover on the attached chip and prints the env pin to persist.
-STREAM_AUTO_MIN = 1024            # non-causal default (conservative)
-STREAM_AUTO_MIN_CAUSAL = 512      # causal default (v5e end-to-end sweep)
-
-#: whole-tile kernel auto-dispatch BELOW the streaming threshold, causal
-#: only: the committed causal seq-128 sweep row (bench_attn_sweep.json,
-#: 1.127x end-to-end — under force mode seq 128 selects the whole-tile
-#: kernel since streaming needs a 256-token tile) was previously
-#: unreachable in auto mode.  Non-causal short sequences keep XLA (0.92x
-#: measured, BERT-large 128).  Env pin: DSTPU_BLOCK_ATTN_MIN_CAUSAL
-#: (0 disables the whole-tile auto path).
-BLOCK_AUTO_MIN_CAUSAL = 128
-
-
-def _env_int(name):
-    env = os.environ.get(name)
-    if not env:
-        return None
-    try:
-        v = int(env)
-    except ValueError:
-        raise ValueError(
-            f"{name}={env!r} is not an integer token count") from None
-    if v < 0:
-        raise ValueError(f"{name}={env!r} must be a non-negative count")
-    return v
-
-
-def stream_auto_min(causal: bool = False, direction: str = "fwd") -> int:
-    """The streaming auto-dispatch threshold for the CURRENT backend and
-    the given pass direction ("fwd" | "bwd"); see the resolution order
-    above."""
-    if direction not in ("fwd", "bwd"):
-        raise ValueError(f"direction must be 'fwd' or 'bwd', "
-                         f"got {direction!r}")
-    suff = direction.upper()
-    names = ((f"DSTPU_STREAM_ATTN_MIN_CAUSAL_{suff}",
-              "DSTPU_STREAM_ATTN_MIN_CAUSAL",
-              f"DSTPU_STREAM_ATTN_MIN_{suff}",
-              "DSTPU_STREAM_ATTN_MIN") if causal else
-             (f"DSTPU_STREAM_ATTN_MIN_{suff}", "DSTPU_STREAM_ATTN_MIN"))
-    for name in names:
-        v = _env_int(name)
-        if v is None:
-            continue
-        if v == 0:
-            raise ValueError(
-                f"{name}=0 is not a valid token count (use "
-                f"DSTPU_FUSED_ATTN=0 to disable kernels)")
-        return v
-    from deepspeed_tpu.analysis import profiles
-    prof = profiles.default_profile()     # an unknown TPU kind raises
-    pair = None if prof is None else (
-        prof.stream_attn_min_causal if causal
-        else prof.stream_attn_min_noncausal)
-    if pair is None:
-        return STREAM_AUTO_MIN_CAUSAL if causal else STREAM_AUTO_MIN
-    return pair[0] if direction == "fwd" else pair[1]
-
-
-def block_auto_min_causal():
-    """Whole-tile kernel auto threshold for causal shapes; None disables
-    (env pin 0)."""
-    v = _env_int("DSTPU_BLOCK_ATTN_MIN_CAUSAL")
-    if v is None:
-        v = BLOCK_AUTO_MIN_CAUSAL
-    return None if v == 0 else v
-
-
-def _attn_mode() -> str:
-    mode = os.environ.get("DSTPU_FUSED_ATTN", "auto")
-    if mode not in ("auto", "1", "0"):
-        # fail loudly, not open: "off"/"false"/"" must not silently enable
-        # the kernel the operator meant to disable
-        raise ValueError(
-            f"DSTPU_FUSED_ATTN={mode!r} is not a valid mode: use 'auto' "
-            f"(streaming kernel from the calibrated threshold, "
-            f"DSTPU_STREAM_ATTN_MIN), '1' (force a kernel), or '0' "
-            f"(XLA only)")
-    return mode
-
 
 def axis_size_or_1(axis) -> int:
     """Static size of a mesh axis, or 1 when the axis isn't bound (allows
@@ -587,47 +471,29 @@ def extend_multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local,
         k_pool, v_pool
 
 
+#: The least sequence length that takes the streaming kernel on a TPU, for
+#: both mask kinds: at 512 tokens and above eight of the benchmark's nine
+#: cells run it; at 128 non-causal tokens ``bert-large.seq128`` runs XLA.
+STREAM_MIN_SEQ = 512
+
+
 def attention_plan(T, n, d, causal, window=None, kv_heads=None):
-    """(fwd_impl, bwd_impl), each in {"xla", "block", "stream"}, for the
-    current backend/mode — the per-direction dispatch table.  Forward and
-    backward resolve independently under "auto" (their crossovers differ);
-    "1" forces one kernel for both, "0" / non-TPU yields ("xla", "xla").
-    A sliding ``window`` or fewer k/v heads than the ``n`` query heads
-    (``kv_heads``, the smaller count of the two) rule the whole-tile kernel
-    out: the streaming kernel and the XLA path take them."""
-    mode = _attn_mode()
-    if mode == "0" or jax.default_backend() != "tpu":
-        return "xla", "xla"
+    """The implementation ``core_attention`` takes at this call shape:
+    "stream" (the streaming Pallas kernel, forward and backward) on a TPU
+    from ``STREAM_MIN_SEQ`` tokens where the kernel takes the length and
+    head width, "xla" otherwise.  ``n``, ``causal``, ``window`` and
+    ``kv_heads`` describe the call and choose nothing."""
     from deepspeed_tpu.ops import pallas_attention as pattn
-    stream_ok = pattn.stream_supported(T, d)
-    block_ok = (pattn.supported(T, n, d) and window is None
-                and kv_heads in (None, n))
-    if mode == "1":
-        impl = "stream" if stream_ok else ("block" if block_ok else "xla")
-        return impl, impl
-
-    def pick(direction):
-        if stream_ok and T >= stream_auto_min(causal, direction):
-            return "stream"
-        bmin = block_auto_min_causal()
-        if block_ok and causal and bmin is not None and T >= bmin:
-            return "block"
-        return "xla"
-
-    fwd, bwd = pick("fwd"), pick("bwd")
-    if bwd == "stream" and fwd == "block":
-        # a streaming backward needs the forward's logsumexp, which the
-        # whole-tile kernel doesn't emit
-        bwd = "block"
-    return fwd, bwd
+    if (jax.default_backend() == "tpu" and pattn.stream_supported(T, d)
+            and T >= STREAM_MIN_SEQ):
+        return "stream"
+    return "xla"
 
 
 def core_attention(q, k, v, *, causal, attn_mask=None, window=None):
-    """Single-device attention on [B, T, n, d] q/k/v with the per-direction
-    kernel dispatch table (``attention_plan``): streaming Pallas kernel from
-    the calibrated threshold, whole-tile kernel for short causal shapes (or
-    under force mode), XLA einsum otherwise — forward and backward chosen
-    independently.  ``attn_mask``: optional [B, T] float/int, 1 = attend.
+    """Single-device attention on [B, T, n, d] q/k/v: the streaming Pallas
+    kernel or the XLA einsum path, as ``attention_plan`` says for the
+    shape.  ``attn_mask``: optional [B, T] float/int, 1 = attend.
     ``k``/``v`` may hold fewer heads than ``q`` (consecutive query heads
     share one) and ``v`` a wider head; ``window``: causal sliding window,
     key s visible to query t iff ``t - window < s <= t``
@@ -636,23 +502,13 @@ def core_attention(q, k, v, *, causal, attn_mask=None, window=None):
     calls it on the all-to-all'd full-sequence view — so long-context
     kernels and sequence sharding compose)."""
     B, T, n, d = q.shape
-    fwd_impl, bwd_impl = attention_plan(
-        T, n, d, causal, window=window,
-        kv_heads=min(k.shape[2], v.shape[2]))
     from deepspeed_tpu.ops import pallas_attention as pattn
     mvec = (jnp.ones((B, T), jnp.float32) if attn_mask is None
             else attn_mask.astype(jnp.float32))
-    if fwd_impl == bwd_impl == "stream":
+    if attention_plan(T, n, d, causal, window=window,
+                      kv_heads=min(k.shape[2], v.shape[2])) == "stream":
         return pattn.stream_attention(q, k, v, mvec, causal, window=window)
-    if fwd_impl == bwd_impl == "block":
-        return pattn.fused_attention(q, k, v, mvec, causal)
-    if (fwd_impl, bwd_impl) == ("xla", "xla"):
-        # single source of the reference einsum math (fp32 MXU
-        # accumulation, masked softmax) — also the hybrid paths' "xla"
-        # side, so the threshold branches can never drift numerically
-        return pattn.xla_attention(q, k, v, mvec, causal, window=window)[0]
-    return pattn.dispatch_attention(q, k, v, mvec, causal,
-                                    fwd_impl, bwd_impl, window=window)
+    return pattn.xla_attention(q, k, v, mvec, causal, window=window)
 
 
 @S.scoped("attn")

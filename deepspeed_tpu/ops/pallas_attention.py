@@ -1,40 +1,29 @@
-"""Fused multi-head attention kernels (Pallas, TPU).
+"""Multi-head attention: the streaming Pallas kernel and the XLA path.
 
-The XLA path in ``models/layers.py`` materialises the [B, n, T, T] fp32
-score tensor in HBM twice per layer (scores write + softmax read) and again
-in the backward replay — at BERT-large/seq128/batch96 that is ~300 MB of HBM
-traffic per layer that never needed to leave the chip.  Two kernels:
-
-* ``fused_attention`` — whole-tile: QK^T → mask → softmax → ·V entirely in
-  VMEM, one program per (batch row, head block), custom-VJP backward
-  recomputing probabilities in VMEM.  For shapes where the full [hb, T, T]
-  score tile fits on chip (short sequences).
-* ``stream_attention`` — flash-attention-style ONLINE-SOFTMAX streaming
-  over KV tiles for long sequences (gate: ``stream_supported``).  Measured
-  on a v5e chip END-TO-END (GPT-2 training step, causal bf16;
-  bench_attn_sweep.json): 1.14x at seq 512, 1.86x at 1024, 2.44x at 2048
-  — under the ``selective`` policy of that time, which ran the forward
-  kernel again in the backward pass.  Its output and log-sum-exp now carry
-  checkpoint names (``_name_stream_residuals``) and ``selective`` and
-  ``full`` keep them, so a layer costs two kernel calls, not three, and
-  the ratios above overstate today's.  ``models/layers.py``
-  auto-dispatches from ``stream_auto_min(causal)`` tokens (512 causal /
-  1024 non-causal on v5e).
+``stream_attention`` is flash-attention-style ONLINE-SOFTMAX streaming over
+KV tiles (gate: ``stream_supported``): scores and probabilities stay in
+VMEM a tile pair at a time, a causal call neither computes nor fetches the
+tiles the band hides, and its output and log-sum-exp carry checkpoint
+names (``_name_stream_residuals``) so that no recomputation policy runs
+the forward kernel twice.  ``xla_attention`` is the plain einsum path (it
+materialises the [B, n, T, T] fp32 scores in HBM): short sequences and
+other backends run it, and the kernel's tests hold the kernel to it.
+``models/layers.py attention_plan`` picks one per call shape; what each
+cell runs, and what it costs there, is in the benchmark ledger's rows
+(PERF.md §3 and §5).
 
 Numerics: scores and probabilities are fp32 (max-subtracted softmax); the
 probability·V contraction runs in the input dtype (bf16 on TPU) with fp32
-accumulation — the same contract as the XLA path.
+accumulation — the same contract on both paths.
 
-Use ``fused_attention(q, k, v, attn_mask, causal)`` with
-``q/k/v: [B, T, n, d]`` and ``attn_mask: [B, T]`` float (1 = attend; pass
-ones for none); callers gate on ``supported(...)``.  ``interpret=True`` runs
-anywhere (CPU tests).
+Both take ``q: [B, T, n, d]``, ``k`` and ``v`` with as many or fewer
+heads, and ``attn_mask: [B, T]`` float (1 = attend; pass ones for none).
+``interpret=True`` runs the kernel anywhere (CPU tests).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -45,193 +34,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.remat_names import ATTN_LSE, ATTN_OUT
 
-# fp32 score-tile budget per program; several such tiles are live in the
-# backward kernel, so keep a healthy margin under the ~16 MB VMEM
-SCORE_TILE_BUDGET = 2 * 1024 * 1024
-
-
-def _head_block(n_heads: int) -> int:
-    # blocks are [bb, hb, T, d]: Mosaic needs every block dim divisible by
-    # (or equal to) the array dim; hb=8 keeps the score tile bounded for
-    # many-head models
-    return 8 if n_heads % 8 == 0 else n_heads
-
-
-def _batch_block(B: int, T: int, hb: int, budget: int) -> int:
-    # enough rows per program to amortise grid/DMA overhead (tiny per-head
-    # programs are latency-bound), bounded by the score-tile budget
-    for bb in (8, 4, 2, 1):
-        if B % bb == 0 and bb * hb * T * T * 4 <= budget:
-            return bb
-    return 1
-
-
-def supported(seq_len: int, n_heads: int, head_dim: int) -> bool:
-    hb = _head_block(n_heads)
-    # gate on the BACKWARD budget (half the forward's): even at bb=1 the
-    # backward keeps p/dP/dS score tiles live, so a shape that only fits the
-    # forward would exhaust VMEM on the grad pass
-    return (seq_len % 8 == 0 and head_dim % 8 == 0
-            and hb * seq_len * seq_len * 4 <= SCORE_TILE_BUDGET // 2)
-
-
-def _fold(ref):
-    """[bb, hb, T, d] block -> [bb*hb, T, d] (leading-dim reshape is free;
-    Mosaic's matmul supports a single batch dim)."""
-    bb, hb, T, d = ref.shape
-    return ref[...].reshape(bb * hb, T, d)
-
-
-def _scores(q, k, mask, causal, scale):
-    """[bb*hb,T,d] x [bb*hb,T,d] (native dtype) -> masked fp32 [bb*hb,T,T]
-    logits; ``mask`` is already expanded to [bb*hb, T]."""
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale
-    T = q.shape[1]
-    if causal:
-        row = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-        s = jnp.where((col <= row)[None], s, -1e9)
-    s = jnp.where(mask[:, None, :] != 0, s, -1e9)
-    return s
-
-
-def _expand_mask(mask_ref, hb):
-    """[bb, 1, T] mask block -> [bb*hb, T] row mask."""
-    bb, _, T = mask_ref.shape
-    m = jnp.broadcast_to(mask_ref[...], (bb, hb, T))
-    return m.reshape(bb * hb, T)
-
-
-def _softmax(s):
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    return e / jnp.sum(e, axis=-1, keepdims=True)
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, causal, scale):
-    # blocks are [1, hb, T, d] in the heads-first layout: the batched dots
-    # need NO in-VMEM transposes, and inputs stay in their native dtype —
-    # the MXU accumulates in fp32 via preferred_element_type; an explicit
-    # fp32 upcast would quarter the matmul rate
-    bb, hb, T, d = q_ref.shape
-    q = _fold(q_ref)
-    k = _fold(k_ref)
-    v = _fold(v_ref)
-    p = _softmax(_scores(q, k, _expand_mask(mask_ref, hb), causal, scale))
-    o = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)            # [bb*hb, T, d]
-    o_ref[...] = o.reshape(bb, hb, T, d).astype(o_ref.dtype)
-
-
-def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, *, causal, scale):
-    bb, hb, T, d = q_ref.shape
-    q = _fold(q_ref)
-    k = _fold(k_ref)
-    v = _fold(v_ref)
-    do = _fold(do_ref)
-    cdt = q.dtype
-    p = _softmax(_scores(q, k, _expand_mask(mask_ref, hb), causal, scale))
-    pc = p.astype(cdt)
-    bdims = ((0,), (0,))
-    # dV = P^T dO   (contract over the query axis, batched)
-    dv = jax.lax.dot_general(pc, do, (((1,), (1,)), bdims),
-                             preferred_element_type=jnp.float32)
-    # dP = dO V^T
-    dp = jax.lax.dot_general(do, v, (((2,), (2,)), bdims),
-                             preferred_element_type=jnp.float32)
-    # dS = P ∘ (dP − rowsum(dP ∘ P)) ; the scale folds into dQ/dK
-    ds = (p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))).astype(cdt)
-    dq = jax.lax.dot_general(ds, k, (((2,), (1,)), bdims),
-                             preferred_element_type=jnp.float32) * scale
-    dk = jax.lax.dot_general(ds, q, (((1,), (1,)), bdims),
-                             preferred_element_type=jnp.float32) * scale
-    dq_ref[...] = dq.reshape(bb, hb, T, d).astype(dq_ref.dtype)
-    dk_ref[...] = dk.reshape(bb, hb, T, d).astype(dk_ref.dtype)
-    dv_ref[...] = dv.reshape(bb, hb, T, d).astype(dv_ref.dtype)
-
-
-def _specs(B, T, n, d, bwd=False):
-    hb = _head_block(n)
-    # the backward keeps ~2x more score-sized tiles live (p, dP, dS)
-    bb = _batch_block(B, T, hb,
-                      SCORE_TILE_BUDGET // (2 if bwd else 1))
-    # kernel layout is heads-first [B, n, T, d] (the public API transposes
-    # on the XLA side, where the copy fuses with the qkv slice)
-    qkv = pl.BlockSpec((bb, hb, T, d), lambda i, j: (i, j, 0, 0))
-    # mask rides as [B, 1, T] so the trailing block dims are (1, T)
-    mask = pl.BlockSpec((bb, 1, T), lambda i, j: (i, 0, 0))
-    return qkv, mask, (B // bb, n // hb)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def fused_attention(q, k, v, attn_mask, causal: bool = False,
-                    interpret: bool = False):
-    """q/k/v: [B, T, n, d]; attn_mask: [B, T] float (1 = attend) — pass
-    ``jnp.ones`` for none.  Returns [B, T, n, d] context."""
-    return _fwd(q, k, v, attn_mask, causal, interpret)
-
-
-def _hf(x):
-    """public [B, T, n, d] -> kernel [B, n, T, d] (XLA-side transpose)."""
-    return jnp.moveaxis(x, 2, 1)
-
-
-def _fwd(q, k, v, attn_mask, causal, interpret):
-    B, T, n, d = q.shape
-    qkv_spec, mask_spec, grid = _specs(B, T, n, d)
-    scale = 1.0 / (d ** 0.5)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((B, n, T, d), q.dtype),
-        grid=grid,
-        in_specs=[qkv_spec, qkv_spec, qkv_spec, mask_spec],
-        out_specs=qkv_spec,
-        interpret=interpret,
-    )(_hf(q), _hf(k), _hf(v), attn_mask[:, None, :])
-    return jnp.moveaxis(out, 1, 2)
-
-
-def _fused_fwd(q, k, v, attn_mask, causal, interpret):
-    return _fwd(q, k, v, attn_mask, causal, interpret), (q, k, v, attn_mask)
-
-
-def _block_bwd_impl(q, k, v, attn_mask, g, causal, interpret):
-    """Whole-tile backward on public-layout operands → (dq, dk, dv)."""
-    B, T, n, d = q.shape
-    qkv_spec, mask_spec, grid = _specs(B, T, n, d, bwd=True)
-    scale = 1.0 / (d ** 0.5)
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, causal=causal, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((B, n, T, d), q.dtype),
-                   jax.ShapeDtypeStruct((B, n, T, d), k.dtype),
-                   jax.ShapeDtypeStruct((B, n, T, d), v.dtype)),
-        grid=grid,
-        in_specs=[qkv_spec, qkv_spec, qkv_spec, mask_spec, qkv_spec],
-        out_specs=(qkv_spec, qkv_spec, qkv_spec),
-        interpret=interpret,
-    )(_hf(q), _hf(k), _hf(v), attn_mask[:, None, :], _hf(g))
-    return (jnp.moveaxis(dq, 1, 2), jnp.moveaxis(dk, 1, 2),
-            jnp.moveaxis(dv, 1, 2))
-
-
-def _fused_bwd(causal, interpret, res, g):
-    q, k, v, attn_mask = res
-    dq, dk, dv = _block_bwd_impl(q, k, v, attn_mask, g, causal, interpret)
-    # mask is a float selector, not a trainable input
-    return dq, dk, dv, jnp.zeros_like(attn_mask)
-
-
-fused_attention.defvjp(_fused_fwd, _fused_bwd)
-
-
 # ==================================================================== stream
-# Flash-attention-style ONLINE-SOFTMAX streaming over KV tiles for long
-# sequences (seq >= 512, where the whole-score-tile kernel above exceeds
-# VMEM).  Standard algebra: the forward keeps a running (row max, denom,
+# Flash-attention-style ONLINE-SOFTMAX streaming over KV tiles.  Standard
+# algebra: the forward keeps a running (row max, denom,
 # accumulator) per query tile and emits the logsumexp; the backward
 # recomputes probabilities from the logsumexp block-wise.  Default backward
 # is a SINGLE fused pass over the (kv tile, query tile) grid producing dQ,
@@ -242,8 +47,7 @@ fused_attention.defvjp(_fused_fwd, _fused_bwd)
 # shape: inside Mosaic's default scoped VMEM the call asks for nothing,
 # past it the call asks Mosaic for what it needs (``vmem_limit_bytes``) up
 # to the chip generation's cap (``analysis/profiles.py kernel_vmem_mib``),
-# and only past the cap does the backward take the classic two-pass split
-# (DSTPU_STREAM_BWD=fused|split pins either).
+# and only past the cap does the backward take the classic two-pass split.
 # delta = rowsum(dO ∘ O) is precomputed on the XLA side either way.
 # Layout: [G, T, d] with G = batch * heads folded on the XLA side.
 
@@ -566,6 +370,12 @@ def _unfold_gtd(x, B, n):
     return jnp.moveaxis(x.reshape(B, n, T, d), 1, 2)
 
 
+def _mask_gtd(attn_mask, B, T, n):
+    return jnp.broadcast_to(
+        attn_mask.astype(jnp.float32)[:, None, :], (B, n, T)
+    ).reshape(B * n, 1, T)
+
+
 def _head_group(n_q: int, n_kv: int, gb: int) -> int:
     """Query heads per k (or v) head, checked against the ``gb`` query heads
     a program takes: a kv block is those ``gb`` heads' own (group 1) or the
@@ -708,17 +518,6 @@ def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret, window=None):
     return out, (qg, kg, vg, maskg, _fold_gtd(out), lse, B, n)
 
 
-def _stream_bwd_mode() -> str:
-    mode = os.environ.get("DSTPU_STREAM_BWD", "auto")
-    if mode not in ("auto", "fused", "split"):
-        raise ValueError(
-            f"DSTPU_STREAM_BWD={mode!r} is not a valid mode: use 'auto' "
-            f"(fused single-pass when the dQ scratch fits the VMEM a "
-            f"kernel may ask for), 'fused', or 'split' (classic two-kernel "
-            f"backward)")
-    return mode
-
-
 def fused_bwd_vmem(gb: int, T: int, d: int, itemsize: int) -> int:
     """Scoped VMEM, in bytes, the fused backward needs at this shape.
     Resident for the whole grid are the fp32 dQ accumulator and the
@@ -743,8 +542,7 @@ def _kernel_vmem_cap() -> Optional[int]:
 
 
 def stream_bwd_plan(gb: int, T: int, d: int, itemsize: int,
-                    vmem_cap: Optional[int],
-                    mode: str = "auto") -> Tuple[str, Optional[int]]:
+                    vmem_cap: Optional[int]) -> Tuple[str, Optional[int]]:
     """Which streaming backward a call shape takes, and the
     ``vmem_limit_bytes`` it asks Mosaic for — all read off the shape:
 
@@ -753,16 +551,11 @@ def stream_bwd_plan(gb: int, T: int, d: int, itemsize: int,
     - ``("fused", limit)``: past the default and within ``vmem_cap`` (the
       chip generation's, in bytes): the need rounded up to a MiB;
     - ``("split", None)``: past the cap, or no cap declared: two kernels,
-      each score tile visited twice.
-
-    ``mode`` is ``DSTPU_STREAM_BWD``: "split" pins the split, "fused" the
-    fused kernel whatever the cap says (under the limit it needs)."""
-    if mode == "split":
-        return "split", None
+      each score tile visited twice."""
     need = fused_bwd_vmem(gb, T, d, itemsize)
     if need <= VMEM_SCOPED_LIMIT:
         return "fused", None
-    if mode == "fused" or (vmem_cap is not None and need <= vmem_cap):
+    if vmem_cap is not None and need <= vmem_cap:
         return "fused", -(-need // _MIB) * _MIB
     return "split", None
 
@@ -820,8 +613,7 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
     dkv_scratch = [pltpu.VMEM((gb, kt, d), jnp.float32),
                    pltpu.VMEM((gb, kt, dv_), jnp.float32)]
     kind, vmem_limit = stream_bwd_plan(gb, T, d, qg.dtype.itemsize,
-                                       _kernel_vmem_cap(),
-                                       _stream_bwd_mode())
+                                       _kernel_vmem_cap())
     if kind == "fused":
         # a shape inside the default asks for nothing: the program it
         # always was
@@ -891,29 +683,7 @@ def _stream_vjp_bwd(causal, interpret, window, res, g):
 stream_attention.defvjp(_stream_vjp_fwd, _stream_vjp_bwd)
 
 
-# ==================================================================== hybrid
-# Forward and backward chosen INDEPENDENTLY per (seq, kind): the end-to-end
-# sweeps (bench_attn_sweep.json) measure fwd+bwd together, but the two
-# passes have different crossovers — the backward streams 5 matmul passes
-# per tile pair against the forward's 2, so the kernel's DMA savings pay
-# off earlier there.  ``dispatch_attention`` is the custom-VJP shell that
-# lets models/layers.py pick {"xla", "block", "stream"} per direction; the
-# single-impl cases degenerate to the kernels above.
-
-ATTN_IMPLS = ("xla", "block", "stream")
-
-
-def _check_impls(fwd_impl: str, bwd_impl: str) -> None:
-    if fwd_impl not in ATTN_IMPLS or bwd_impl not in ATTN_IMPLS:
-        raise ValueError(
-            f"attention impls must be one of {ATTN_IMPLS}, got "
-            f"fwd={fwd_impl!r} bwd={bwd_impl!r}")
-    if bwd_impl == "stream" and fwd_impl == "block":
-        raise ValueError(
-            "bwd_impl='stream' needs the forward logsumexp, which the "
-            "whole-tile kernel does not emit — use fwd_impl 'stream' or "
-            "'xla'")
-
+# ======================================================================= xla
 
 @jax.custom_vjp
 def _qk_scores(q, k):
@@ -955,13 +725,12 @@ def _repeat_heads(x, n):
     return x if x.shape[2] == n else jnp.repeat(x, n // x.shape[2], axis=2)
 
 
-def xla_attention(q, k, v, attn_mask, causal, with_lse=False, window=None):
-    """Plain-XLA attention (the models/layers.py einsum path), optionally
-    emitting the logsumexp in the streaming kernels' [G, 1, T] layout so a
-    streaming backward can follow an XLA forward.  Shared k/v heads, a wider
-    value head and ``window`` as in ``stream_attention``."""
+def xla_attention(q, k, v, attn_mask, causal, window=None):
+    """Plain-XLA attention (the models/layers.py einsum path) → [B, T, n,
+    dv] context.  Shared k/v heads, a wider value head and ``window`` as in
+    ``stream_attention``."""
     _check_window(window, causal)
-    B, T, n, d = q.shape
+    _, T, n, d = q.shape
     k, v = _repeat_heads(k, n), _repeat_heads(v, n)
     scores = _qk_scores(q, k)
     scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
@@ -973,182 +742,4 @@ def xla_attention(q, k, v, attn_mask, causal, with_lse=False, window=None):
     scores = jnp.where(attn_mask[:, None, None, :].astype(jnp.bool_),
                        scores, -1e9)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bnts,bsnd->btnd", probs, v)
-    if not with_lse:
-        return out, None
-    lse = jax.scipy.special.logsumexp(scores, axis=-1)      # [B, n, T]
-    return out, lse.reshape(B * n, 1, T)
-
-
-def _mask_gtd(attn_mask, B, T, n):
-    return jnp.broadcast_to(
-        attn_mask.astype(jnp.float32)[:, None, :], (B, n, T)
-    ).reshape(B * n, 1, T)
-
-
-def _check_block(fwd_impl, bwd_impl, q, k, v, window):
-    if "block" in (fwd_impl, bwd_impl) and (
-            window is not None or k.shape != q.shape or v.shape != q.shape):
-        raise ValueError("the whole-tile kernel takes neither a window nor "
-                         "shared or wider k/v heads")
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def dispatch_attention(q, k, v, attn_mask, causal: bool = False,
-                       fwd_impl: str = "xla", bwd_impl: str = "xla",
-                       interpret: bool = False, window=None):
-    """Attention with independently chosen forward/backward kernels.
-
-    q/k/v: [B, T, n, d] (k/v heads shared, v wider and ``window`` as in
-    ``stream_attention``, on "xla" and "stream"); attn_mask: [B, T] float
-    (1 = attend).  The impls are {"xla", "block", "stream"}; bwd "stream"
-    after fwd "block" is rejected (no logsumexp).  Callers gate shapes via
-    ``supported`` / ``stream_supported`` per impl."""
-    _check_impls(fwd_impl, bwd_impl)
-    _check_block(fwd_impl, bwd_impl, q, k, v, window)
-    B, _, n, _ = q.shape
-    if fwd_impl == "stream":
-        o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret,
-                                   window)
-        return _unfold_gtd(o, B, n)
-    if fwd_impl == "block":
-        return _fwd(q, k, v, attn_mask, causal, interpret)
-    return xla_attention(q, k, v, attn_mask, causal, window=window)[0]
-
-
-def _dispatch_vjp_fwd(q, k, v, attn_mask, causal, fwd_impl, bwd_impl,
-                      interpret, window=None):
-    _check_impls(fwd_impl, bwd_impl)
-    _check_block(fwd_impl, bwd_impl, q, k, v, window)
-    B, T, n, d = q.shape
-    need_stream_res = bwd_impl == "stream"
-    lse = None
-    if fwd_impl == "stream":
-        o, lse, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret,
-                                     window)
-        out = _unfold_gtd(o, B, n)
-    elif fwd_impl == "block":
-        out = _fwd(q, k, v, attn_mask, causal, interpret)
-    else:
-        out, lse = xla_attention(q, k, v, attn_mask, causal,
-                                 with_lse=need_stream_res, window=window)
-    if need_stream_res:
-        out, lse = _name_stream_residuals(out, lse)
-    return out, (q, k, v, attn_mask,
-                 (out, lse) if need_stream_res else None)
-
-
-def _dispatch_vjp_bwd(causal, fwd_impl, bwd_impl, interpret, window, res,
-                      g):
-    q, k, v, attn_mask, extra = res
-    B, T, n, d = q.shape
-    if bwd_impl == "stream":
-        out, lse = extra
-        dq, dk, dv = _stream_bwd_impl(
-            _fold_gtd(q), _fold_gtd(k), _fold_gtd(v),
-            _mask_gtd(attn_mask, B, T, n), _fold_gtd(out), lse,
-            _fold_gtd(g), causal, interpret, window)
-        dq, dk, dv = (_unfold_gtd(x, B, like.shape[2])
-                      for x, like in ((dq, q), (dk, k), (dv, v)))
-    elif bwd_impl == "block":
-        dq, dk, dv = _block_bwd_impl(q, k, v, attn_mask, g, causal,
-                                     interpret)
-    else:
-        # XLA backward: recompute-and-differentiate the einsum forward
-        # (the same work a remat'd XLA attention does in the replay)
-        _, pull = jax.vjp(
-            lambda q_, k_, v_: xla_attention(q_, k_, v_, attn_mask, causal,
-                                             window=window)[0],
-            q, k, v)
-        dq, dk, dv = pull(g)
-    return dq, dk, dv, jnp.zeros_like(attn_mask)
-
-
-dispatch_attention.defvjp(_dispatch_vjp_fwd, _dispatch_vjp_bwd)
-
-
-def calibrate_stream_threshold(seq_lens=(256, 512, 1024, 2048),
-                               batch=8, n_heads=12, head_dim=64,
-                               steps=6, verbose=True):
-    """Measure the streaming-kernel vs XLA crossover on the ATTACHED chip
-    and return the smallest winning sequence length.
-
-    The shipped auto-dispatch threshold encodes the v5e sweep
-    (analysis/profiles.py ``stream_attn_min_*``); other chip generations
-    shift the crossover.  This times fwd+bwd of both paths at each length and
-    returns the first where the kernel is >= 5% faster (falling back to
-    the table default when none wins).  Persist the result with::
-
-        export DSTPU_STREAM_ATTN_MIN_CAUSAL=<returned value>
-
-    (causal-scoped: the calibration loss is causal, and a both-axes pin
-    would force the kernel on non-causal shapes where XLA wins)
-
-    Host-side utility; requires a TPU backend.
-    """
-    import time
-
-    import numpy as np
-
-    if jax.default_backend() != "tpu":
-        raise RuntimeError(
-            "calibrate_stream_threshold needs a TPU backend (the kernel "
-            "never dispatches off-TPU)")
-
-    def time_path(T, use_kernel):
-        rng = np.random.default_rng(0)
-        q, k, v = (jnp.asarray(rng.normal(size=(batch, T, n_heads,
-                                                head_dim)),
-                               jnp.bfloat16) for _ in range(3))
-        mask = jnp.ones((batch, T), jnp.float32)
-
-        def xla_attn(q, k, v):
-            s = jnp.einsum("btnd,bsnd->bnts", q, k,
-                           preferred_element_type=jnp.float32)
-            s = s / jnp.sqrt(jnp.asarray(head_dim, jnp.float32))
-            cm = jnp.tril(jnp.ones((T, T), jnp.bool_))
-            s = jnp.where(cm[None, None], s, -1e9)
-            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            return jnp.einsum("bnts,bsnd->btnd", p, v)
-
-        def loss(q, k, v):
-            o = (stream_attention(q, k, v, mask, True) if use_kernel
-                 else xla_attn(q, k, v))
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        fn = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        fn(q, k, v)[0].block_until_ready()           # compile + warmup
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            out = fn(q, k, v)
-        jax.tree_util.tree_leaves(out)[0].block_until_ready()
-        return (time.perf_counter() - t0) / steps
-
-    from deepspeed_tpu.models import layers as _L
-
-    threshold = None
-    for T in sorted(seq_lens):
-        if not stream_supported(T, head_dim):
-            continue
-        t_xla = time_path(T, use_kernel=False)
-        t_ker = time_path(T, use_kernel=True)
-        ratio = t_xla / t_ker
-        if verbose:
-            print(f"seq {T}: xla {t_xla * 1e3:.2f} ms, "
-                  f"kernel {t_ker * 1e3:.2f} ms, {ratio:.2f}x")
-        if threshold is None and ratio >= 1.05:
-            threshold = T
-    if threshold is None:
-        # deliberately IGNORE any existing env pin here: this measurement
-        # just showed the kernel losing, so fall back to the chip's
-        # profile row / default (the calibration loss is causal, so read
-        # the causal column)
-        from deepspeed_tpu.analysis import profiles
-        pair = profiles.default_profile().stream_attn_min_causal
-        threshold = min(pair) if pair else _L.STREAM_AUTO_MIN_CAUSAL
-        if verbose:
-            print(f"kernel never won >=1.05x; keeping {threshold}")
-    elif verbose:
-        print(f"crossover at seq {threshold}: "
-              f"export DSTPU_STREAM_ATTN_MIN_CAUSAL={threshold}")
-    return threshold
+    return jnp.einsum("bnts,bsnd->btnd", probs, v)

@@ -247,7 +247,7 @@ def test_xla_attention_backward_stays_lowp():
     mask = jnp.ones((2, 64), jnp.float32)
 
     def loss(q, k, v):
-        out, _ = pattn.xla_attention(q, k, v, mask, causal=True)
+        out = pattn.xla_attention(q, k, v, mask, causal=True)
         return jnp.sum(out.astype(jnp.float32))
 
     jx = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
@@ -265,7 +265,7 @@ def test_xla_attention_fp32_grads_unchanged():
     mask = jnp.ones((2, 16), jnp.float32)
 
     def loss_custom(q, k, v):
-        return jnp.sum(pattn.xla_attention(q, k, v, mask, True)[0])
+        return jnp.sum(pattn.xla_attention(q, k, v, mask, True))
 
     def loss_plain(q, k, v):
         scores = jnp.einsum("btnd,bsnd->bnts", q, k,

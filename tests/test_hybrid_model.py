@@ -229,8 +229,7 @@ def test_the_streaming_plan_repeats_no_key_or_value_head(monkeypatch):
     ``full``) no call is replayed: 3 layers x (forward + fused backward)."""
     import functools
     from deepspeed_tpu.ops import pallas_attention as pattn
-    monkeypatch.setattr(L, "attention_plan",
-                        lambda *a, **kw: ("stream", "stream"))
+    monkeypatch.setattr(L, "attention_plan", lambda *a, **kw: "stream")
     monkeypatch.setattr(pattn, "stream_attention", functools.partial(
         pattn.stream_attention, interpret=True))
     model = tiny(window=64, remat_policy="selective",

@@ -15,6 +15,7 @@ from benchmark.reference import kimi_moe as reference
 from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.ops import pallas_attention as pattn
 from deepspeed_tpu.parallel.topology import MODEL_AXIS, make_mesh
+from tests.test_pallas_attention import force_backward
 
 H, N, LATENT, NOPE, ROPE, V = 64, 2, 32, 24, 8, 16
 THETA, EPS = 800000.0, 1e-5
@@ -136,13 +137,13 @@ def test_stream_kernel_at_a_192_wide_key_and_a_128_wide_value(monkeypatch,
                                                               mode):
     """Outputs and dq, dk, dv against ``xla_attention``, the split and the
     fused backward, two tiles of 512."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     q, k, v, weight, mask = _case(1024, 2, 192, 128)
     assert pattn.stream_supported(1024, 192)
     got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
         q, k, v, mask, True, True), q, k, v, weight)
     want = _value_and_grads(lambda q, k, v: pattn.xla_attention(
-        q, k, v, mask, True)[0], q, k, v, weight)
+        q, k, v, mask, True), q, k, v, weight)
     assert got[0].shape == () and got[1][2].shape == v.shape
     assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5, abs=1e-4)
     for name, a, b in zip("qkv", got[1], want[1]):

@@ -325,8 +325,7 @@ def test_no_policy_replays_the_streaming_kernel(monkeypatch, policy):
     output and log-sum-exp are the values a replay would produce, so the
     loss is that of recomputation off, bit for bit, and the gradients its
     gradients to test_selective_remat.py's tolerance."""
-    monkeypatch.setattr(L, "attention_plan",
-                        lambda *shape, **kw: ("stream", "stream"))
+    monkeypatch.setattr(L, "attention_plan", lambda *shape, **kw: "stream")
     monkeypatch.setattr(pattn, "stream_attention", functools.partial(
         pattn.stream_attention, interpret=True))
     toks = np.random.default_rng(3).integers(

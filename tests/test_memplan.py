@@ -416,19 +416,15 @@ def test_device_kind_resolves_through_one_table(monkeypatch):
     prof = profiles.default_profile()
     assert prof.name == "v5e-8" and prof.peak_bf16_tflops == 197.0
     assert not prof.persistent_cache_donation_unsafe
-    # the attention thresholds come from the same row
+    # the attention plan reads the backend, not the row
     from deepspeed_tpu.models import layers
-    for name in ("DSTPU_STREAM_ATTN_MIN", "DSTPU_STREAM_ATTN_MIN_FWD",
-                 "DSTPU_STREAM_ATTN_MIN_BWD", "DSTPU_FUSED_ATTN"):
-        monkeypatch.delenv(name, raising=False)
-    assert layers.attention_plan(512, 16, 64, False) == ("stream", "stream")
-    assert layers.attention_plan(128, 16, 64, False) == ("xla", "xla")
+    assert layers.attention_plan(512, 16, 64, False) == "stream"
+    assert layers.attention_plan(128, 16, 64, False) == "xla"
 
     FakeChip.device_kind = "TPU v9 mega"
     with pytest.raises(KeyError, match="no backend profile"):
         profiles.default_profile()
-    with pytest.raises(KeyError, match="no backend profile"):
-        layers.attention_plan(512, 16, 64, False)
+    assert layers.attention_plan(512, 16, 64, False) == "stream"
 
 
 def test_memplan_walks_jit_equations_as_calls():

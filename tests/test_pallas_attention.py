@@ -1,107 +1,40 @@
-"""Fused Pallas attention vs the XLA reference math (interpret mode).
+"""The streaming Pallas attention kernel vs the XLA reference math
+(interpret mode), and the plan that picks one of the two per call shape.
 
-The kernel computes QK^T -> mask -> softmax -> .V (and the flash-style
-backward) entirely in VMEM; these tests pin forward and gradient parity
-against a plain-JAX reference for every mask mode, plus the shape gate.
+The kernel streams QK^T -> mask -> online softmax -> .V over KV tiles in
+VMEM (and the flash-style backward, fused or split); these tests pin
+forward and gradient parity against a plain-JAX reference for every mask
+mode, the gates, the backward's plan and the programs it traces.
 """
 
-import dataclasses
 import functools
+import math
 import re
 import types
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models import layers as L
 from deepspeed_tpu.models.transformer import remat_wrap
 from deepspeed_tpu.ops import pallas_attention as pattn
 
-B, T, N, D = 4, 32, 2, 16
+
+PLAN = pattn.stream_bwd_plan
 
 
-def reference(q, k, v, mask, causal):
-    scores = jnp.einsum("btnd,bsnd->bnts", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    if causal:
-        cmask = jnp.tril(jnp.ones((T, T), jnp.bool_))
-        scores = jnp.where(cmask[None, None], scores, -1e9)
-    if mask is not None:
-        scores = jnp.where(mask[:, None, None, :].astype(jnp.bool_),
-                           scores, -1e9)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bnts,bsnd->btnd", probs, v)
-
-
-def rand_qkv(seed=0, dtype=jnp.float32):
-    rng = np.random.default_rng(seed)
-    mk = lambda: jnp.asarray(
-        rng.normal(size=(B, T, N, D)).astype(np.float32), dtype)
-    return mk(), mk(), mk()
-
-
-def pad_mask():
-    m = np.ones((B, T), np.float32)
-    m[:, T - 5:] = 0.0
-    return jnp.asarray(m)
-
-
-@pytest.mark.parametrize("causal,masked", [
-    (False, False), (True, False), (False, True), (True, True)])
-def test_forward_parity(causal, masked):
-    q, k, v = rand_qkv()
-    mask = pad_mask() if masked else jnp.ones((B, T), jnp.float32)
-    got = pattn.fused_attention(q, k, v, mask, causal, True)
-    want = reference(q, k, v, mask if masked else None, causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("causal,masked", [
-    (False, False), (True, True)])
-def test_gradient_parity(causal, masked):
-    q, k, v = rand_qkv(seed=1)
-    mask = pad_mask() if masked else jnp.ones((B, T), jnp.float32)
-
-    def loss_fused(q, k, v):
-        out = pattn.fused_attention(q, k, v, mask, causal, True)
-        return jnp.sum(out * jnp.cos(out))   # nontrivial cotangent
-
-    def loss_ref(q, k, v):
-        out = reference(q, k, v, mask if masked else None, causal)
-        return jnp.sum(out * jnp.cos(out))
-
-    g1 = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-5)
-
-
-def test_masked_rows_fully_padded_are_finite():
-    """A row whose mask is all zeros must not produce NaNs (softmax over
-    all -1e9 logits)."""
-    q, k, v = rand_qkv(seed=2)
-    m = np.ones((B, T), np.float32)
-    m[0, :] = 0.0
-    out = pattn.fused_attention(q, k, v, jnp.asarray(m), False, True)
-    assert np.all(np.isfinite(np.asarray(out)))
-
-
-def test_supported_gate():
-    assert pattn.supported(128, 16, 64)
-    # the gate is the BACKWARD budget (ADVICE r2): 8-head block x 256^2 x 4 B
-    # = 2 MB score tile exceeds the bwd half-budget even at bb=1
-    assert not pattn.supported(256, 16, 64)
-    assert not pattn.supported(1024, 16, 64)  # score tile too big
-    assert not pattn.supported(100, 16, 64)   # unaligned seq
-    assert not pattn.supported(128, 16, 63)   # unaligned head dim
-    # odd head counts use the full head dim as the block
-    assert pattn.supported(128, 12, 64)
-    assert pattn._head_block(12) == 12
-    assert pattn._head_block(16) == 8
+def force_backward(monkeypatch, kind):
+    """Make every streaming backward traced under ``monkeypatch`` take
+    ``kind``: "split", or "fused" under the VMEM limit it needs whatever
+    the chip's cap; "auto" is ``stream_bwd_plan`` as it is."""
+    forced = {"auto": PLAN,
+              "fused": lambda gb, T, d, itemsize, cap: PLAN(
+                  gb, T, d, itemsize, math.inf),
+              "split": lambda *shape: ("split", None)}[kind]
+    monkeypatch.setattr(pattn, "stream_bwd_plan", forced)
 
 
 # ------------------------------------------------------- streaming kernel
@@ -143,11 +76,13 @@ def test_stream_forward_parity(causal, masked):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("causal", [False, True])
-def test_stream_gradient_parity(causal):
+def test_stream_gradient_parity(causal, masked):
     q, k, v = stream_qkv(seed=3)
     mask = np.ones((2, ST), np.float32)
-    mask[:, ST - 19:] = 0.0
+    if masked:
+        mask[:, ST - 19:] = 0.0
     mask = jnp.asarray(mask)
 
     def loss_s(q, k, v):
@@ -162,6 +97,26 @@ def test_stream_gradient_parity(causal):
     for a, b in zip(gs, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("mode", ["fused", "split"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_stream_fully_padded_row_is_finite(monkeypatch, causal, mode):
+    """A batch row whose mask is all zeros (every logit -1e9) must give
+    finite outputs and gradients, through either backward; the other row
+    keeps the reference's values."""
+    force_backward(monkeypatch, mode)
+    q, k, v = stream_qkv(seed=2)
+    mask = jnp.asarray(np.array([[0.0] * ST, [1.0] * ST], np.float32))
+    out = pattn.stream_attention(q, k, v, mask, causal, True)
+    grads = jax.grad(lambda q, k, v: jnp.sum(jnp.sin(pattn.stream_attention(
+        q, k, v, mask, causal, True))), (0, 1, 2))(q, k, v)
+    for x in (out, *grads):
+        assert np.all(np.isfinite(np.asarray(x)))
+    np.testing.assert_allclose(
+        np.asarray(out[1]),
+        np.asarray(stream_reference(q, k, v, mask, causal)[1]),
+        rtol=2e-5, atol=2e-5)
 
 
 def test_stream_supported_gate():
@@ -192,62 +147,6 @@ def test_stream_bf16_dtype_contract():
         assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
 
 
-def test_stream_threshold_resolution(monkeypatch):
-    """The auto-dispatch threshold resolves env pin > the chip's profile
-    row > v5e default (the crossover is chip dependent and must be
-    re-pinnable without a code change)."""
-    from deepspeed_tpu.analysis import profiles
-    from deepspeed_tpu.models import layers as L
-
-    for name in ("DSTPU_STREAM_ATTN_MIN", "DSTPU_STREAM_ATTN_MIN_CAUSAL",
-                 "DSTPU_STREAM_ATTN_MIN_BWD",
-                 "DSTPU_STREAM_ATTN_MIN_CAUSAL_BWD"):
-        monkeypatch.delenv(name, raising=False)
-    # CPU test rig: its profile row carries no sweep -> the defaults,
-    # causal-aware (causal crossover is lower: the streaming kernel skips
-    # fully-masked KV tiles)
-    assert L.stream_auto_min() == L.STREAM_AUTO_MIN
-    assert L.stream_auto_min(causal=True) == L.STREAM_AUTO_MIN_CAUSAL
-
-    monkeypatch.setitem(
-        profiles.PROFILES, "cpu-8", dataclasses.replace(
-            profiles.PROFILES["cpu-8"], stream_attn_min_causal=(256, 128),
-            stream_attn_min_noncausal=(512, 384)))
-    assert L.stream_auto_min(causal=True) == 256   # table wins default
-    assert L.stream_auto_min() == 512
-    # forward and backward resolve independently from the table
-    assert L.stream_auto_min(causal=True, direction="bwd") == 128
-    assert L.stream_auto_min(direction="bwd") == 384
-
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN", "2048")
-    assert L.stream_auto_min() == 2048         # env pin wins everything
-    assert L.stream_auto_min(causal=True) == 2048
-    assert L.stream_auto_min(causal=True, direction="bwd") == 2048
-
-    # the causal-scoped pin (what calibrate() prints) never leaks into
-    # non-causal dispatch — a causal-measured crossover would force the
-    # kernel on non-causal shapes where XLA wins
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN_CAUSAL", "256")
-    assert L.stream_auto_min(causal=True) == 256
-    assert L.stream_auto_min() == 2048
-
-    # direction-scoped pins beat the direction-blind ones for their
-    # direction only
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN_CAUSAL_BWD", "128")
-    assert L.stream_auto_min(causal=True, direction="bwd") == 128
-    assert L.stream_auto_min(causal=True) == 256
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN_BWD", "512")
-    assert L.stream_auto_min(direction="bwd") == 512
-    assert L.stream_auto_min() == 2048
-
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN", "-3")
-    with pytest.raises(ValueError, match="non-negative"):
-        L.stream_auto_min()
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN", "2048")
-    with pytest.raises(ValueError, match="'fwd' or 'bwd'"):
-        L.stream_auto_min(direction="sideways")
-
-
 @pytest.mark.parametrize("causal", [False, True])
 def test_stream_backward_fused_matches_split(monkeypatch, causal):
     """The single-pass fused backward (dQ/dK/dV in one kernel) must match
@@ -263,21 +162,13 @@ def test_stream_backward_fused_matches_split(monkeypatch, causal):
             pattn.stream_attention(q, k, v, mask, causal, True))),
             (0, 1, 2))(q, k, v)
 
-    monkeypatch.setenv("DSTPU_STREAM_BWD", "fused")
+    force_backward(monkeypatch, "fused")
     g_fused = grads()
-    monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+    force_backward(monkeypatch, "split")
     g_split = grads()
     for a, b in zip(g_fused, g_split):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
-
-
-def test_stream_bwd_mode_validation(monkeypatch):
-    monkeypatch.setenv("DSTPU_STREAM_BWD", "sideways")
-    with pytest.raises(ValueError, match="DSTPU_STREAM_BWD"):
-        pattn._stream_bwd_mode()
-    monkeypatch.delenv("DSTPU_STREAM_BWD")
-    assert pattn._stream_bwd_mode() == "auto"
 
 
 MIB = 1024 * 1024
@@ -312,7 +203,7 @@ def test_stream_bwd_plan_reads_the_verdict_off_the_shape(shape, want):
     """Three outcomes: fused with no compiler parameters inside Mosaic's
     default, fused under the limit it needs up to the generation's cap,
     the split past it; without a declared cap nothing is asked for, and
-    DSTPU_STREAM_BWD pins either kernel."""
+    under a cap that holds the need the limit is the need in whole MiB."""
     assert pattn.stream_bwd_plan(*shape, V5E_CAP) == want
     need = pattn.fused_bwd_vmem(*shape)
     if want == ("fused", None):
@@ -321,10 +212,10 @@ def test_stream_bwd_plan_reads_the_verdict_off_the_shape(shape, want):
     else:
         assert need > pattn.VMEM_SCOPED_LIMIT
         assert pattn.stream_bwd_plan(*shape, None) == ("split", None)
-        limit = pattn.stream_bwd_plan(*shape, V5E_CAP, "fused")[1]
-        assert limit % MIB == 0 and 0 <= limit - need < MIB
-    assert pattn.stream_bwd_plan(*shape, V5E_CAP, "split") == ("split", None)
-    assert pattn.stream_bwd_plan(*shape, V5E_CAP, "fused")[0] == "fused"
+        kind, limit = pattn.stream_bwd_plan(*shape, need)
+        assert kind == "fused" and limit % MIB == 0
+        assert 0 <= limit - need < MIB
+        assert pattn.stream_bwd_plan(*shape, need - 1) == ("split", None)
 
 
 def test_the_cap_is_the_profiles(monkeypatch):
@@ -382,9 +273,9 @@ def test_fused_and_split_agree_at_a_shape_the_old_rule_refused(monkeypatch):
             pattn.stream_attention(q, k, v, mask, True, True)
             .astype(jnp.float32) * w), (0, 1, 2))(q, k, v)
 
-    monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+    force_backward(monkeypatch, "split")
     g_split = grads()
-    monkeypatch.delenv("DSTPU_STREAM_BWD")
+    force_backward(monkeypatch, "auto")
     g_fused = grads()
     for a, b in zip(g_fused, g_split):
         np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -392,74 +283,46 @@ def test_fused_and_split_agree_at_a_shape_the_old_rule_refused(monkeypatch):
                                    rtol=1e-5, atol=1e-6)
 
 
-# ------------------------------------------------- hybrid fwd/bwd dispatch
+# ------------------------------------------------- core_attention's two paths
 
-STREAM_COMBOS = [("stream", "stream"), ("stream", "xla"), ("xla", "stream")]
-BLOCK_COMBOS = [("block", "block"), ("block", "xla"), ("xla", "block")]
+def core_on(plan, causal=True, attn_mask=None):
+    """``layers.core_attention`` on the ``plan`` given, whatever the
+    backend, the streaming kernel interpreted."""
+    def attention(q, k, v):
+        with mock.patch.object(L, "attention_plan",
+                               lambda *shape, **kw: plan), \
+                mock.patch.object(pattn, "stream_attention",
+                                  functools.partial(pattn.stream_attention,
+                                                    interpret=True)):
+            return L.core_attention(q, k, v, causal=causal,
+                                    attn_mask=attn_mask)
+    return attention
 
 
+@pytest.mark.parametrize("mode", ["fused", "split"])
+@pytest.mark.parametrize("masked", [False, True])
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("fwd_impl,bwd_impl", STREAM_COMBOS)
-def test_dispatch_stream_combos_parity(causal, fwd_impl, bwd_impl):
-    """Mixed forward/backward kernel choices (the per-direction dispatch
-    table) agree with the all-XLA reference at seq 512, fwd AND grad."""
+def test_dispatch_stream_combos_parity(monkeypatch, causal, masked, mode):
+    """``core_attention`` on the streaming plan agrees with
+    ``xla_attention`` at seq 512, forward AND gradients, with and without
+    a padding mask, through the fused and the split backward."""
+    force_backward(monkeypatch, mode)
     q, k, v = stream_qkv(seed=5)
     mask = np.ones((2, ST), np.float32)
-    mask[:, ST - 23:] = 0.0
+    if masked:
+        mask[:, ST - 23:] = 0.0
     mask = jnp.asarray(mask)
-
-    def loss_d(q, k, v):
-        return jnp.sum(jnp.sin(pattn.dispatch_attention(
-            q, k, v, mask, causal, fwd_impl, bwd_impl, True)))
-
-    def loss_r(q, k, v):
-        return jnp.sum(jnp.sin(stream_reference(q, k, v, mask, causal)))
-
-    np.testing.assert_allclose(
-        np.asarray(pattn.dispatch_attention(q, k, v, mask, causal,
-                                            fwd_impl, bwd_impl, True)),
-        np.asarray(stream_reference(q, k, v, mask, causal)),
-        rtol=2e-5, atol=2e-5)
-    gd = jax.grad(loss_d, (0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, (0, 1, 2))(q, k, v)
+    core = core_on("stream", causal, mask if masked else None)
+    xla = lambda q, k, v: pattn.xla_attention(q, k, v, mask, causal)
+    np.testing.assert_allclose(np.asarray(core(q, k, v)),
+                               np.asarray(xla(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    gd = jax.grad(loss(core), (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(xla), (0, 1, 2))(q, k, v)
     for a, b in zip(gd, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-4, atol=3e-4)
-
-
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("fwd_impl,bwd_impl", BLOCK_COMBOS)
-def test_dispatch_block_combos_parity(causal, fwd_impl, bwd_impl):
-    q, k, v = rand_qkv(seed=6)
-    mask = pad_mask()
-
-    def loss_d(q, k, v):
-        return jnp.sum(jnp.cos(pattn.dispatch_attention(
-            q, k, v, mask, causal, fwd_impl, bwd_impl, True)))
-
-    def loss_r(q, k, v):
-        return jnp.sum(jnp.cos(reference(q, k, v, mask, causal)))
-
-    np.testing.assert_allclose(
-        np.asarray(pattn.dispatch_attention(q, k, v, mask, causal,
-                                            fwd_impl, bwd_impl, True)),
-        np.asarray(reference(q, k, v, mask, causal)),
-        rtol=1e-5, atol=1e-5)
-    gd = jax.grad(loss_d, (0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, (0, 1, 2))(q, k, v)
-    for a, b in zip(gd, gr):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-5)
-
-
-def test_dispatch_rejects_block_then_stream():
-    q, k, v = rand_qkv()
-    mask = jnp.ones((B, T), jnp.float32)
-    with pytest.raises(ValueError, match="logsumexp"):
-        pattn.dispatch_attention(q, k, v, mask, False, "block", "stream",
-                                 True)
-    with pytest.raises(ValueError, match="impls must be one of"):
-        pattn.dispatch_attention(q, k, v, mask, False, "nope", "xla", True)
 
 
 # ------------------------------ residuals named for the recomputation policies
@@ -492,14 +355,12 @@ ONES = jnp.ones((2, ST), jnp.float32)
 NAMED_KERNELS = {
     "stream_attention": lambda q, k, v: pattn.stream_attention(
         q, k, v, ONES, True, True),
-    "dispatch-stream-stream": lambda q, k, v: pattn.dispatch_attention(
-        q, k, v, ONES, True, "stream", "stream", True),
+    "core_attention-stream": core_on("stream"),
 }
 XLA_FORWARD_AND_BACKWARD = {
-    "dispatch-xla-xla": lambda q, k, v: pattn.dispatch_attention(
-        q, k, v, ONES, True, "xla", "xla", True),
+    "core_attention-xla": core_on("xla"),
     "xla_attention": lambda q, k, v: pattn.xla_attention(
-        q, k, v, ONES, True)[0],
+        q, k, v, ONES, True),
 }
 
 
@@ -536,57 +397,36 @@ def test_full_remat_on_the_xla_plan_is_checkpoint_with_no_policy(attention):
     assert strip(full) == strip(grad_jaxpr_text(jax.checkpoint, fn))
 
 
-def test_remat_keeps_an_xla_forward_for_a_stream_backward():
-    """Mixed plan ("xla", "stream"): the same two names save the einsum
-    forward's output and log-sum-exp (the backward kernel's residuals), so
-    under ``selective`` and ``full`` its two matmuls are not replayed."""
-    mixed = lambda q, k, v: pattn.dispatch_attention(
-        q, k, v, ONES, True, "xla", "stream", True)
-    sel, full = (grad_jaxpr_under(p, mixed) for p in ("selective", "full"))
-    none = counter(grad_jaxpr_text(jax.checkpoint, mixed))
-    assert sel("pallas_call") == full("pallas_call") == 1
-    assert sel("dot_general") == full("dot_general")
-    assert none("dot_general") - sel("dot_general") == 2
+#: (T, n, d, causal, window, kv_heads) -> the plan on a TPU
+PLAN_TABLE = [
+    ((128, 16, 64, True, None, None), "xla"),       # under a tile
+    ((128, 16, 64, False, None, None), "xla"),      # bert-large.seq128
+    ((256, 12, 64, True, None, None), "xla"),       # a tile, under 512
+    ((512, 12, 64, True, None, None), "stream"),
+    ((512, 16, 64, False, None, None), "stream"),   # bert-large.seq512
+    ((4096, 28, 128, True, 4096, 4), "stream"),     # a sliding window
+    ((8192, 16, 192, True, None, 16), "stream"),    # MLA's wide head
+    ((8192, 32, 128, True, None, 8), "stream"),     # grouped query heads
+    ((1280, 12, 64, True, None, None), "stream"),   # tiles of 256
+    ((1000, 12, 64, True, None, None), "xla"),      # no tile multiple
+    ((1024, 12, 60, True, None, None), "xla"),      # head not 8-aligned
+]
 
 
-def test_attention_plan_directions(monkeypatch):
-    """The auto plan resolves forward and backward independently, uses the
-    whole-tile kernel for short causal shapes (the committed seq-128 causal
-    sweep row), and keeps XLA for short non-causal shapes."""
-    from deepspeed_tpu.models import layers as L
-
-    for name in ("DSTPU_STREAM_ATTN_MIN", "DSTPU_STREAM_ATTN_MIN_CAUSAL",
-                 "DSTPU_STREAM_ATTN_MIN_BWD", "DSTPU_FUSED_ATTN",
-                 "DSTPU_STREAM_ATTN_MIN_CAUSAL_BWD",
-                 "DSTPU_BLOCK_ATTN_MIN_CAUSAL"):
-        monkeypatch.delenv(name, raising=False)
+@pytest.mark.parametrize("shape,want", PLAN_TABLE,
+                         ids=["-".join(map(str, c[0])) for c in PLAN_TABLE])
+def test_attention_plan_is_one_rule_per_call_shape(monkeypatch, shape,
+                                                   want):
+    """On a TPU the streaming kernel from ``STREAM_MIN_SEQ`` tokens where
+    it takes the length and the head width, XLA otherwise — whatever the
+    mask kind, window or number of k/v heads; off a TPU always XLA."""
+    T, n, d, causal, window, kv_heads = shape
+    plan = lambda: L.attention_plan(T, n, d, causal, window=window,
+                                    kv_heads=kv_heads)
+    assert plan() == "xla"                       # the CPU rig
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN_CAUSAL", "1024")
-    monkeypatch.setenv("DSTPU_STREAM_ATTN_MIN_CAUSAL_BWD", "512")
-    # seq 512 causal, 12 heads d64: stream supported; only the backward
-    # threshold admits it; whole-tile kernel doesn't fit 512 -> fwd XLA
-    assert L.attention_plan(512, 12, 64, causal=True) == ("xla", "stream")
-    # seq 128 causal: below both stream tiles -> the whole-tile kernel
-    # from the sweep (1.127x) both directions
-    assert L.attention_plan(128, 12, 64, causal=True) == ("block", "block")
-    monkeypatch.setenv("DSTPU_BLOCK_ATTN_MIN_CAUSAL", "0")
-    assert L.attention_plan(128, 12, 64, causal=True) == ("xla", "xla")
-    # non-causal short: XLA (0.92x measured) regardless of block support
-    assert L.attention_plan(128, 16, 64, causal=False) == ("xla", "xla")
-    # force mode: one kernel, both directions
-    monkeypatch.setenv("DSTPU_FUSED_ATTN", "1")
-    assert L.attention_plan(512, 12, 64, causal=True) == ("stream", "stream")
-    assert L.attention_plan(128, 12, 64, causal=False) == ("block", "block")
-    monkeypatch.setenv("DSTPU_FUSED_ATTN", "0")
-    assert L.attention_plan(2048, 12, 64, causal=True) == ("xla", "xla")
-
-
-def test_calibrate_requires_tpu(monkeypatch):
-    # force a non-TPU answer so the guard path runs everywhere (on a real
-    # chip the unguarded call would execute the full sweep instead)
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    with pytest.raises(RuntimeError, match="TPU backend"):
-        pattn.calibrate_stream_threshold()
+    assert plan() == want
+    assert L.attention_plan(T, n, d, causal) == want
 
 
 # ------------------------------ window, shared k/v heads, a wider value head
@@ -623,12 +463,12 @@ def _assert_same(got, want):
 def test_stream_window_parity(monkeypatch, T_len, window, mode):
     """Forward and the three gradients under a sliding window, fused and
     split backward; 640 spans three kv tiles of 512."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     q, k, v, weight, mask = _window_case(T_len, 2, 2, 2, 64, 64, seed=window)
     got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
         q, k, v, mask, True, True, window), q, k, v, weight)
     want = _value_and_grads(lambda q, k, v: pattn.xla_attention(
-        q, k, v, mask, True, window=window)[0], q, k, v, weight)
+        q, k, v, mask, True, window=window), q, k, v, weight)
     _assert_same(got, want)
     # and the window does what it says: a dense reference
     pos = jnp.arange(T_len)
@@ -648,7 +488,7 @@ def test_stream_shared_heads_and_a_wider_value_head(monkeypatch, window,
     """8 query heads over 4 key heads (group 2) and 2 value heads of twice
     the width (group 4) — differential attention's call — against the
     same call on materialised repeats."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     q, k, v, weight, mask = _window_case(1024, 8, 4, 2, 64, 128)
     got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
         q, k, v, mask, True, True, window), q, k, v, weight)
@@ -658,7 +498,7 @@ def test_stream_shared_heads_and_a_wider_value_head(monkeypatch, window,
     _assert_same(got, want)
     assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
     _assert_same(got, _value_and_grads(lambda q, k, v: pattn.xla_attention(
-        q, k, v, mask, True, window=window)[0], q, k, v, weight))
+        q, k, v, mask, True, window=window), q, k, v, weight))
 
 
 @pytest.mark.parametrize("window,mode", [(None, "fused"), (512, "fused"),
@@ -667,14 +507,14 @@ def test_stream_seven_query_heads_a_shared_head(monkeypatch, window, mode):
     """14 query heads over 2 key/value heads — a group of 7, SmallThinker's
     28 over 4: a pair of query heads would straddle two shared heads, so a
     program takes one (``_stream_gb``) — against the XLA path."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     assert pattn._stream_gb(14, 7, 7) == 1
     assert pattn._stream_gb(28, 1, 1) == pattn._stream_gb(8, 2, 4) == 2
     q, k, v, weight, mask = _window_case(1024, 14, 2, 2, 64, 64)
     got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
         q, k, v, mask, True, True, window), q, k, v, weight)
     _assert_same(got, _value_and_grads(lambda q, k, v: pattn.xla_attention(
-        q, k, v, mask, True, window=window)[0], q, k, v, weight))
+        q, k, v, mask, True, window=window), q, k, v, weight))
     assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
 
 
@@ -695,7 +535,7 @@ def test_a_windowed_call_walks_only_the_tiles_in_its_window(monkeypatch):
     """T 8192, window 512, tiles of 512: the kv axis of the forward's grid
     (and the inner axis of both backward kernels') is 2 long, not 16 — 31 of
     the causal triangle's 136 tile pairs are visited, none fetched beyond."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+    force_backward(monkeypatch, "split")
     q = jnp.zeros((1, 8192, 4, 64), jnp.bfloat16)
     k = jnp.zeros((1, 8192, 2, 64), jnp.bfloat16)
     v = jnp.zeros((1, 8192, 1, 128), jnp.bfloat16)
@@ -718,13 +558,9 @@ def test_window_and_shared_heads_are_refused_where_not_built():
     q, k, v, _, mask = _window_case(256, 4, 4, 4, 16, 16)
     with pytest.raises(ValueError, match="causal mask"):
         pattn.stream_attention(q, k, v, mask, False, True, 64)
-    with pytest.raises(ValueError, match="whole-tile kernel"):
-        pattn.dispatch_attention(q, k, v, mask, True, "block", "block",
-                                 True, 64)
     with pytest.raises(ValueError, match="whole groups"):
         pattn.stream_attention(q, k[:, :, :3], v, mask, True, True)
-    from deepspeed_tpu.models import layers as L
-    assert L.attention_plan(128, 12, 64, True) == ("xla", "xla")
+    assert L.attention_plan(128, 12, 64, True) == "xla"
 
 
 # ---------- the causal band: hidden tiles neither computed nor fetched
@@ -828,7 +664,7 @@ def test_stream_parity_where_hidden_cut_and_whole_tiles_meet(
     ones and cuts the last): forward and gradients against the XLA path,
     with no padding and with a mask whose zeros lie inside the tile below
     the diagonal; 1, 2 and 7 query heads a shared head."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     q, k, v, weight, mask = _window_case(1024, 2 * group, 2, 2, 64, 64,
                                          seed=group)
     if masked:
@@ -836,7 +672,7 @@ def test_stream_parity_where_hidden_cut_and_whole_tiles_meet(
     got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
         q, k, v, mask, True, True, window), q, k, v, weight)
     want = _value_and_grads(lambda q, k, v: pattn.xla_attention(
-        q, k, v, mask, True, window=window)[0], q, k, v, weight)
+        q, k, v, mask, True, window=window), q, k, v, weight)
     _assert_same(got, want)
 
 
@@ -856,7 +692,7 @@ def test_a_hidden_step_keeps_the_parked_tile(monkeypatch, mode):
     on the tile the walk is about to see.  Every block the pipeline
     fetches is then one a visible step uses: no hidden tile is fetched.
     The jaxpr digests below print no index map, so this test pins them."""
-    monkeypatch.setenv("DSTPU_STREAM_BWD", mode)
+    force_backward(monkeypatch, mode)
     n = 4
     q = jnp.zeros((1, 512 * n, 2, 64), jnp.bfloat16)
     mask = jnp.ones((1, 512 * n), jnp.float32)
@@ -920,7 +756,9 @@ PARENT_JAXPRS = {
     "stream_noncausal": "9a760c4912ccbf2f",
     "stream_causal_split": "083caf3254788b3d",
     "xla": "784faf65c0c932fc",
-    "dispatch_xla_stream": "328cc465be1355ec",
+    # ``core_attention`` adds nothing to the program of the path it takes
+    "core_attention_stream": "fd5abd514cfcc926",
+    "core_attention_xla": "784faf65c0c932fc",
 }
 
 
@@ -930,7 +768,7 @@ def test_a_plain_call_is_the_parents_program(monkeypatch, name):
     q = jnp.zeros((2, 1024, 4, 64), jnp.bfloat16)
     mask = jnp.ones((2, 1024))
     if name == "stream_causal_split":
-        monkeypatch.setenv("DSTPU_STREAM_BWD", "split")
+        force_backward(monkeypatch, "split")
         q, mask = jnp.zeros((1, 4096, 2, 128), jnp.bfloat16), jnp.ones(
             (1, 4096))
     call = {
@@ -940,10 +778,15 @@ def test_a_plain_call_is_the_parents_program(monkeypatch, name):
             q, k, v, mask, False),
         "stream_causal_split": lambda q, k, v: pattn.stream_attention(
             q, k, v, mask, True),
-        "xla": lambda q, k, v: pattn.xla_attention(q, k, v, mask, True)[0],
-        "dispatch_xla_stream": lambda q, k, v: pattn.dispatch_attention(
-            q, k, v, mask, True, "xla", "stream"),
+        "xla": lambda q, k, v: pattn.xla_attention(q, k, v, mask, True),
+        "core_attention_stream": lambda q, k, v: L.core_attention(
+            q, k, v, causal=True, attn_mask=mask),
+        "core_attention_xla": lambda q, k, v: L.core_attention(
+            q, k, v, causal=True, attn_mask=mask),
     }[name]
+    if name.startswith("core_attention"):
+        monkeypatch.setattr(L, "attention_plan",
+                            lambda *shape, **kw: name.split("_")[-1])
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(call(q, k, v).astype(jnp.float32)),
         argnums=(0, 1, 2)))(q, q, q)
