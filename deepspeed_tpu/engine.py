@@ -2701,6 +2701,7 @@ class DeepSpeedTpuEngine:
         counts = getattr(self.module, "step_counts", None)
         if callable(counts):
             self._step_gauges["model"] = dict(counts())
+            obs_scalars.remember_gauges(self._step_gauges["model"])
             if self._scalars is not None:
                 self._scalars.gauges = self._step_gauges["model"]
         if self._boundary_wire:

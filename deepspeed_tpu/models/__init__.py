@@ -23,6 +23,9 @@ from deepspeed_tpu.models.delta_moe import (DeltaMoEConfig, DeltaMoELM,
                                             DELTA_MOE_SIZES)
 from deepspeed_tpu.models.window_moe import (WindowMoEConfig, WindowMoELM,
                                              WINDOW_MOE_SIZES)
+from deepspeed_tpu.models.ssd_hybrid import (Mamba2HybridConfig,
+                                             Mamba2HybridLM,
+                                             MAMBA2_HYBRID_SIZES)
 
 __all__ = [
     "TransformerConfig", "init_block_params", "block_partition_specs",
@@ -35,4 +38,5 @@ __all__ = [
     "LatentMoEConfig", "LatentMoELM", "LATENT_MOE_SIZES",
     "DeltaMoEConfig", "DeltaMoELM", "DELTA_MOE_SIZES",
     "WindowMoEConfig", "WindowMoELM", "WINDOW_MOE_SIZES",
+    "Mamba2HybridConfig", "Mamba2HybridLM", "MAMBA2_HYBRID_SIZES",
 ]

@@ -490,14 +490,16 @@ def attention_plan(T, n, d, causal, window=None, kv_heads=None):
     return "xla"
 
 
-def core_attention(q, k, v, *, causal, attn_mask=None, window=None):
+def core_attention(q, k, v, *, causal, attn_mask=None, window=None,
+                   scale=None):
     """Single-device attention on [B, T, n, d] q/k/v: the streaming Pallas
     kernel or the XLA einsum path, as ``attention_plan`` says for the
     shape.  ``attn_mask``: optional [B, T] float/int, 1 = attend.
     ``k``/``v`` may hold fewer heads than ``q`` (consecutive query heads
     share one) and ``v`` a wider head; ``window``: causal sliding window,
     key s visible to query t iff ``t - window < s <= t``
-    (``ops.pallas_attention.stream_attention``).
+    (``ops.pallas_attention.stream_attention``); ``scale``: the scores'
+    factor, ``1 / sqrt(d)`` where None.
     Shared by the plain path and Ulysses sequence parallelism (which
     calls it on the all-to-all'd full-sequence view — so long-context
     kernels and sequence sharding compose)."""
@@ -507,8 +509,10 @@ def core_attention(q, k, v, *, causal, attn_mask=None, window=None):
             else attn_mask.astype(jnp.float32))
     if attention_plan(T, n, d, causal, window=window,
                       kv_heads=min(k.shape[2], v.shape[2])) == "stream":
-        return pattn.stream_attention(q, k, v, mvec, causal, window=window)
-    return pattn.xla_attention(q, k, v, mvec, causal, window=window)
+        return pattn.stream_attention(q, k, v, mvec, causal, window=window,
+                                      scale=scale)
+    return pattn.xla_attention(q, k, v, mvec, causal, window=window,
+                               scale=scale)
 
 
 @S.scoped("attn")
@@ -569,7 +573,7 @@ def multihead_attention(x, qkv_w_local, qkv_b_local, proj_w_local, proj_b,
 @S.scoped("attn")
 def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
                                rope, *, head_dim, causal, attn_mask=None,
-                               axis=MODEL_AXIS, window=None):
+                               axis=MODEL_AXIS, window=None, scale=None):
     """Tensor-parallel multi-head attention with separate, bias-free
     projections and rotary positions on q and k.
 
@@ -583,6 +587,8 @@ def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
     window:   a causal sliding window, ``core_attention``'s (key s visible
               to query t iff ``t - window < s <= t``); the core then runs
               under ``dstpu/swa``
+    scale:    the scores' factor, ``core_attention``'s (``1 / sqrt(d)``
+              where None)
 
     Same ``core_attention`` dispatch as ``multihead_attention``.  Under
     context parallelism the rotated k/v go round the ring (positions are
@@ -597,13 +603,16 @@ def rotary_multihead_attention(x, wq_local, wk_local, wv_local, wo_local,
     if axis_size_or_1(SEQ_AXIS) > 1:
         if window is not None:
             _no_sequence_shards("a windowed rotary_multihead_attention")
+        if scale is not None:
+            raise ValueError("ring attention scales the scores by "
+                             "1 / sqrt(d) and by nothing else")
         from deepspeed_tpu.models.ring_attention import ring_attention
         ctx = ring_attention(q, k, v, causal=causal, kv_mask=attn_mask)
     else:
         with (S.scope("swa") if window is not None
               else contextlib.nullcontext()):
             ctx = core_attention(q, k, v, causal=causal, attn_mask=attn_mask,
-                                 window=window)
+                                 window=window, scale=scale)
     return row_parallel_linear(ctx.reshape(B, T, -1), wo_local, axis=axis)
 
 
@@ -891,3 +900,52 @@ def mamba_mixer(x, p, *, state, dt_rank):
         y = selective_scan(u, delta, -jnp.exp(p["A_log"].astype(jnp.float32)),
                            b, c, p["D"])
     return row_parallel_linear(y * silu(z), p["out_w"]), y
+
+
+# ------------------------------------------------------ Mamba-2 (SSD) mixer
+
+@S.scoped("mamba2")
+def mamba2_mixer(x, p, *, heads, head_dim, state, groups, eps):
+    """Mamba-2 mixer (arXiv:2405.21060; ``transformers``' ``Mamba2Mixer``).
+    ``[z | xBC | dt] = x W_in`` (widths ``E = heads x head_dim``, ``E + 2
+    groups x state``, ``heads``); ``xBC <- silu(conv(xBC) + b)``, a causal
+    depthwise convolution over x, B and C together; ``[x | B | C] = xBC``,
+    x in ``heads`` heads, B and C in ``groups`` groups; in fp32 ``dt =
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)``, one of each a head; ``y
+    = state_space_duality(x, dt, A, B, C, D)`` (ops/ssd.py: fp32 state, the
+    ``D x`` skip on the undiscretised x); ``out = (norm_s * RMSNorm(y *
+    silu(z))) W_out``, the norm taken AFTER the gate, in fp32, over each
+    group's ``E / groups`` channels.
+
+    Not sharded over ``model``: the groups' B and C are read by every head
+    of their group and the norm runs over all of a group's channels
+    (``Mamba2HybridLM.validate`` refuses ``mp > 1``).  The convolution runs
+    under ``dstpu/conv``, the state-space duality under ``dstpu/ssd``."""
+    from deepspeed_tpu.ops.selective_scan import causal_conv1d
+    from deepspeed_tpu.ops.ssd import state_space_duality
+    _no_sequence_shards("mamba2_mixer")
+    B, T, _ = x.shape
+    f32, E = jnp.float32, heads * head_dim
+    # named for the "selective" policy, like an attention layer's q, k, v
+    proj = checkpoint_name(column_parallel_linear(x, p["in_w"]), MIXER_IN)
+    z, xbc, dt = proj[..., :E], proj[..., E:-heads], proj[..., -heads:]
+    with S.scope("conv"):
+        xbc = silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+    bc = xbc[..., E:].reshape(B, T, 2, groups, state)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+    with S.scope("ssd"):
+        y = state_space_duality(
+            xbc[..., :E].reshape(B, T, heads, head_dim), dt,
+            -jnp.exp(p["A_log"].astype(f32)), bc[:, :, 0], bc[:, :, 1],
+            p["D"])
+
+    # as the Gated DeltaNet's: the op's output and z are kept, not the
+    # gate's and the norm's float32 pieces
+    @jax.checkpoint
+    def gated_norm(y, z, w):
+        gated = (y.reshape(B, T, E).astype(f32)
+                 * jax.nn.silu(z.astype(f32))).reshape(B, T, groups, -1)
+        return _rms_norm(gated, w.reshape(groups, -1), eps).reshape(
+            B, T, E).astype(x.dtype)
+
+    return row_parallel_linear(gated_norm(y, z, p["norm_s"]), p["out_w"])

@@ -378,6 +378,25 @@ def remember_channel(channel) -> None:
     _last_channel = weakref.ref(channel) if channel is not None else None
 
 
+#: the ``model`` gauges of the fused step program an engine traced last
+_last_gauges = None
+
+
+def remember_gauges(gauges) -> None:
+    """Called by every engine as it traces its fused step program: a copy of
+    the ``model`` gauges the module wrote while it was traced."""
+    global _last_gauges
+    _last_gauges = dict(gauges)
+
+
+def step_gauges():
+    """The ``model`` gauges of the fused step program an engine traced last
+    (``layers_mamba2`` ...), for a trace reader with no engine in hand, also
+    where that engine declared no step scalar; None before any was traced.
+    No fence: they are host-side numbers."""
+    return None if _last_gauges is None else dict(_last_gauges)
+
+
 def snapshot():
     """:meth:`Channel.read` of the engine built last, for a trace reader
     with no engine in hand (one counted fence, after the capture); None

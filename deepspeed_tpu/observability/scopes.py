@@ -59,6 +59,10 @@ SCOPES = (
     # (its convolution runs under ``conv``) and the chunked gated delta
     # rule inside it; the gated softmax attention is ``attn``
     "gdn", "delta",
+    # a Mamba-2 / attention hybrid: the whole Mamba-2 mixer (its
+    # convolution runs under ``conv``) and the chunked state-space duality
+    # inside it
+    "mamba2", "ssd",
 )
 
 FORWARD, BACKWARD, REPLAY = "forward", "backward", "replay"

@@ -434,7 +434,8 @@ def _check_window(window, causal):
                          f"t - window < s <= t")
 
 
-def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None):
+def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None,
+                     scale=None):
     _check_window(window, causal)
     B, T, n, d = q.shape
     dv = v.shape[-1]
@@ -445,7 +446,7 @@ def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None):
     qt = kt = _stream_tile(T)
     nq, n_tiles = T // qt, T // kt
     nk = _window_tiles(window, kt, n_tiles)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     qg, kg, vg = _fold_gtd(q), _fold_gtd(k), _fold_gtd(v)
     maskg = _mask_gtd(attn_mask, B, T, n)
     kv_tile = _kv_tile(causal, window, nk)
@@ -476,9 +477,9 @@ def _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window=None):
     return o, lse, (qg, kg, vg, maskg)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def stream_attention(q, k, v, attn_mask, causal: bool = False,
-                     interpret: bool = False, window=None):
+                     interpret: bool = False, window=None, scale=None):
     """Streaming (online-softmax) attention for long sequences.
 
     q: [B, T, n, d]; k: [B, T, n / gk, d] and v: [B, T, n / gv, dv], where
@@ -487,10 +488,12 @@ def stream_attention(q, k, v, attn_mask, causal: bool = False,
     attend).  ``window``: key s is visible to query t iff ``t - window < s
     <= t`` (needs ``causal``).  A causal call's tiles the band hides — past
     the diagonal, out of the window — are neither computed nor fetched.
+    ``scale``: the scores' factor, ``1 / sqrt(d)`` where None.
     Returns [B, T, n, dv] context; callers gate on
     ``stream_supported(T, d)``."""
     B, T, n, d = q.shape
-    o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window)
+    o, _, _ = _stream_fwd_impl(q, k, v, attn_mask, causal, interpret, window,
+                               scale)
     return _unfold_gtd(o, B, n)
 
 
@@ -510,10 +513,12 @@ def _name_stream_residuals(out, lse):
     return checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
 
 
-def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret, window=None):
+def _stream_vjp_fwd(q, k, v, attn_mask, causal, interpret, window=None,
+                    scale=None):
     B, T, n, d = q.shape
     o, lse, (qg, kg, vg, maskg) = _stream_fwd_impl(q, k, v, attn_mask,
-                                                   causal, interpret, window)
+                                                   causal, interpret, window,
+                                                   scale)
     out, lse = _name_stream_residuals(_unfold_gtd(o, B, n), lse)
     return out, (qg, kg, vg, maskg, _fold_gtd(out), lse, B, n)
 
@@ -572,7 +577,7 @@ def _sum_shared(dx, like):
 
 
 def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
-                     window=None):
+                     window=None, scale=None):
     """Streaming backward on folded operands (q [G, T, d]; k, v with G /
     group heads, v ``dv`` wide) → (dq, dk, dv), same layouts.  Fused single
     pass, under the VMEM limit ``stream_bwd_plan`` asks for, where its
@@ -589,7 +594,7 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
     # a window shortens the INNER axis of each grid to the tiles that can
     # see each other; the outer axis walks the whole sequence
     inner = _window_tiles(window, kt, n_tiles)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     delta = jnp.sum(dog.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                    # [G, 1, T]
     windowed = ({} if window is None
@@ -669,10 +674,10 @@ def _stream_bwd_impl(qg, kg, vg, maskg, o, lse, dog, causal, interpret,
     return dq, _sum_shared(dk, kg), _sum_shared(dv, vg)
 
 
-def _stream_vjp_bwd(causal, interpret, window, res, g):
+def _stream_vjp_bwd(causal, interpret, window, scale, res, g):
     qg, kg, vg, maskg, o, lse, B, n = res
     dq, dk, dv = _stream_bwd_impl(qg, kg, vg, maskg, o, lse, _fold_gtd(g),
-                                  causal, interpret, window)
+                                  causal, interpret, window, scale)
     T = qg.shape[1]
     # the mask is a float selector, not a trainable input
     return (_unfold_gtd(dq, B, n), _unfold_gtd(dk, B, kg.shape[0] // B),
@@ -725,15 +730,16 @@ def _repeat_heads(x, n):
     return x if x.shape[2] == n else jnp.repeat(x, n // x.shape[2], axis=2)
 
 
-def xla_attention(q, k, v, attn_mask, causal, window=None):
+def xla_attention(q, k, v, attn_mask, causal, window=None, scale=None):
     """Plain-XLA attention (the models/layers.py einsum path) → [B, T, n,
-    dv] context.  Shared k/v heads, a wider value head and ``window`` as in
-    ``stream_attention``."""
+    dv] context.  Shared k/v heads, a wider value head, ``window`` and
+    ``scale`` as in ``stream_attention``."""
     _check_window(window, causal)
     _, T, n, d = q.shape
     k, v = _repeat_heads(k, n), _repeat_heads(v, n)
     scores = _qk_scores(q, k)
-    scores = scores / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    scores = (scores / jnp.sqrt(jnp.asarray(d, jnp.float32)) if scale is None
+              else scores * scale)
     if causal:
         cmask = jnp.tril(jnp.ones((T, T), jnp.bool_))
         if window is not None:

@@ -50,6 +50,10 @@ width, ``n`` heads):
                                   at 16,384 rows, 32 heads of 128 x 128,
                                   chunk 64): with ``o``, the rule's own
                                   residuals, as the scan's two
+* ``SSD_OUT``      rows x H x P   the state-space duality's output ``y``
+* ``SSD_STATES``   4 x G x N x H/G x P a segment of up to 32 chunks: its
+                                  fp32 states at the segment boundaries;
+                                  with ``y``, the op's own residuals
 """
 
 QKV = "qkv"
@@ -62,8 +66,10 @@ SCAN_OUT = "scan_out"
 SCAN_STATES = "scan_states"
 DELTA_OUT = "delta_out"
 DELTA_STATES = "delta_states"
+SSD_OUT = "ssd_out"
+SSD_STATES = "ssd_states"
 
 FULL_SAVES = (ATTN_OUT, ATTN_LSE)
 SELECTIVE_SAVES = ((QKV, FFN1) + FULL_SAVES
                    + (POST_LN_SUM, MIXER_IN, SCAN_OUT, SCAN_STATES,
-                      DELTA_OUT, DELTA_STATES))
+                      DELTA_OUT, DELTA_STATES, SSD_OUT, SSD_STATES))

@@ -518,6 +518,23 @@ def test_stream_seven_query_heads_a_shared_head(monkeypatch, window, mode):
     assert got[1][1].shape == k.shape and got[1][2].shape == v.shape
 
 
+@pytest.mark.parametrize("mode", ["fused", "split"])
+def test_a_scale_of_the_scores_reaches_both_directions(monkeypatch, mode):
+    """``scale=`` in place of ``1 / sqrt(d)`` — Granite's ``1 / 64`` at d 64,
+    8 query heads on 2 key/value heads (its 32 on 8) — in the forward and
+    backward kernels alike, against the XLA path at the same scale; and the
+    default is not that scale."""
+    force_backward(monkeypatch, mode)
+    q, k, v, weight, mask = _window_case(1024, 8, 2, 2, 64, 64, seed=4)
+    got = _value_and_grads(lambda q, k, v: pattn.stream_attention(
+        q, k, v, mask, True, True, scale=1 / 64), q, k, v, weight)
+    _assert_same(got, _value_and_grads(lambda q, k, v: pattn.xla_attention(
+        q, k, v, mask, True, scale=1 / 64), q, k, v, weight))
+    plain = _value_and_grads(lambda q, k, v: pattn.xla_attention(
+        q, k, v, mask, True), q, k, v, weight)
+    assert abs(float(plain[0]) - float(got[0])) > 1e-2 * abs(float(got[0]))
+
+
 def _pallas_grids(fn, *args):
     """The grids of the ``pallas_call``s in ``fn``'s gradient, in order."""
     jaxpr = jax.make_jaxpr(jax.grad(

@@ -235,10 +235,11 @@ def test_the_step_holds_every_scope_of_the_table(scoped_runs, case):
     # Memory Unit and the hand-over a hybrid stack's, the latent
     # projections and the expert layer's three a latent-attention / expert
     # stack's, the Gated DeltaNet mixer and its delta rule a linear-attention
+    # hybrid's, the Mamba-2 mixer and its state-space duality a Mamba-2
     # hybrid's (all below)
     want = {scopes.PREFIX + s for s in scopes.SCOPES} - {
         "dstpu/loop", "dstpu/rope", "dstpu/exit"} - HYBRID_SCOPES - (
-            LATENT_MOE_SCOPES) - DELTA_MOE_SCOPES
+            LATENT_MOE_SCOPES) - DELTA_MOE_SCOPES - SSD_HYBRID_SCOPES
     if "zero0" in case:
         # nothing to gather: the cast to the compute dtype is the update's
         # last instruction there, and under its scope
@@ -537,3 +538,36 @@ def test_a_gated_deltanet_step_holds_its_two_scopes(policy="full"):
     assert {"dstpu/gdn", "dstpu/delta"} <= replayed
     assert not {"dstpu/ssm", "dstpu/scan", "dstpu/mla", "dstpu/loop"} & {
         s for s, _ in phases}
+
+
+SSD_HYBRID_SCOPES = {"dstpu/mamba2", "dstpu/ssd"}
+
+
+def test_a_mamba2_step_holds_its_two_scopes(policy="full"):
+    """A ``Mamba2HybridLM`` step: the Mamba-2 mixer (``mamba2``) and the
+    chunked state-space duality inside it (``ssd``) run forward and
+    backward, and under ``full`` (the cell's policy) forward again beside
+    the backward; the convolution keeps ``conv``, the position-free
+    attention ``attn`` (no ``rope``), the MLPs ``ffn``."""
+    from deepspeed_tpu.models import Mamba2HybridLM
+    engine, _, _, _ = ds.initialize(
+        model=Mamba2HybridLM.from_size("tiny"),
+        mesh=make_mesh(devices=jax.devices()[:1]),
+        config={"train_batch_size": 2, "steps_per_print": 10 ** 9,
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+                "activation_checkpointing": {"enabled": True,
+                                             "policy": policy}})
+    doc = np.random.default_rng(7).integers(0, 512, size=(2, 129),
+                                            dtype=np.int32)
+    engine.train_batch((doc[:, :-1].copy(), doc[:, 1:].copy()))
+    names = scopes.step_scope_map()
+    phases = {(s, p) for s, p in names.values() if s}
+    for scope in sorted(SSD_HYBRID_SCOPES | {"dstpu/conv", "dstpu/attn",
+                                             "dstpu/ffn"}):
+        assert {(scope, "forward"), (scope, "replay"),
+                (scope, "backward")} <= phases, scope
+    assert ("dstpu/head", "backward") in phases
+    assert SSD_HYBRID_SCOPES <= {scopes.PREFIX + s for s in scopes.SCOPES}
+    assert not {"dstpu/rope", "dstpu/ssm", "dstpu/scan", "dstpu/gdn",
+                "dstpu/delta"} & {s for s, _ in phases}
